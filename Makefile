@@ -5,7 +5,7 @@ PYTEST ?= python -m pytest
 presubmit: verify test kernel-smoke perf-gate  ## everything a PR needs to pass
 
 verify: chaos soak  ## static checks + the chaos and soak gates: bytecode-compile, kcanalyze (all analysis passes, baseline-aware), build the native library
-	python -m compileall -q karpenter_core_tpu tests bench.py __graft_entry__.py
+	python -m compileall -q karpenter_core_tpu tests bench.py chip_smoke.py __graft_entry__.py
 	python tools/kcanalyze.py --strict
 	$(MAKE) -C native
 
@@ -30,10 +30,13 @@ perf: perf-gate  ## performance-gated tests (reference: //go:build test_performa
 perf-gate:  ## round-over-round drift check: bench vs last same-platform BENCH_r*.json (advisory; KC_PERF_GATE_STRICT=1 to enforce)
 	python tools/perfgate.py
 
-bench:  ## headline benchmark on the available accelerator
+bench:  ## headline benchmark on the device JAX finds (exits non-zero when a phase failed)
 	python bench.py
+
+chip-smoke:  ## the served solve path end to end on the local TPU, chip-or-fail (refuses to run where JAX finds no TPU)
+	python chip_smoke.py
 
 graft-check:  ## driver contract: compile check + multi-chip dry run
 	python __graft_entry__.py
 
-.PHONY: presubmit verify chaos soak test test-all kernel-smoke perf perf-gate bench graft-check
+.PHONY: presubmit verify chaos soak test test-all kernel-smoke perf perf-gate bench chip-smoke graft-check

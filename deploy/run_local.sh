@@ -13,7 +13,7 @@ export PYTHONPATH="$REPO${PYTHONPATH:+:$PYTHONPATH}"
 export KC_SOLVER_LISTEN="${KC_SOLVER_LISTEN:-127.0.0.1:8980}"
 export KC_LEASE_ENDPOINT="${KC_LEASE_ENDPOINT:-$KC_SOLVER_LISTEN}"
 # per-run lease state: a stale lease from a killed previous run would make
-# every bring-up wait out the 15 s staleness window (and leak into ~/.cache)
+# every bring-up wait out the 15 s staleness window
 export KC_LEASE_STATE="${KC_LEASE_STATE:-$(mktemp -d)/leases.json}"
 export LEADER_ELECT="${LEADER_ELECT:-true}"
 KC_REPLICAS="${KC_REPLICAS:-2}"
@@ -25,6 +25,11 @@ trap cleanup EXIT
 
 python -m karpenter_core_tpu.cmd.solver &
 PIDS+=($!)
+
+# one process per chip: the operators ship their device solves to the sidecar
+# (deploy/manifests/deployment.yaml does the same), so the sidecar is the only
+# process that initializes the accelerator backend
+export KC_SOLVER_ADDRESS="${KC_SOLVER_ADDRESS:-$KC_SOLVER_LISTEN}"
 
 METRICS_PORTS=()
 for i in $(seq 0 $((KC_REPLICAS - 1))); do

@@ -225,8 +225,10 @@ def find_jit_sites(module: SourceModule) -> List[JitSite]:
 
 def _shard_map_kwargs(site: JitSite, call: ast.Call) -> None:
     """Record shard_map's config expressions (mesh/in_specs/out_specs/
-    check_rep) on the site.  ``mesh`` may also arrive positionally (arg 1 of
-    the direct-call spelling)."""
+    check_vma) on the site.  ``jax.shard_map`` takes them by keyword only;
+    the positional ``mesh`` (arg 1) is the retired
+    ``jax.experimental.shard_map`` spelling, still recognized so old code
+    is analyzed rather than skipped."""
     for kw in call.keywords:
         if kw.arg:
             site.kwargs[kw.arg] = kw.value

@@ -10,7 +10,7 @@ carry baseline entries with reasons, like every other pass.
 
 What counts as a registration (package-wide — families are registered where
 they are used: tenant.py, journal.py, retry.py, watchdog.py, chaos.py,
-backendprobe.py, compilecache.py, pipeline.py, the controllers — not just
+compilecache.py, pipeline.py, the controllers — not just
 metrics/registry.py):
 
   REGISTRY.counter("karpenter_...", ...)        # any attr base, any of the

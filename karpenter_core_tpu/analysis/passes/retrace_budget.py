@@ -298,7 +298,7 @@ def _fn_index(module: SourceModule) -> Dict[str, ast.AST]:
 
 def _static_key_names(expr: ast.expr) -> Set[str]:
     """Parameter names the cache key STATICALLY keys on.  Names inside
-    helper calls other than ``tuple(...)`` are excluded: ``_leaf_sig(cls)``
+    helper calls other than ``tuple(...)`` are excluded: ``leaf_sig(cls)``
     keys on shapes/dtypes — those stay runtime (traced) arguments, only the
     directly-embedded config values are static."""
     out: Set[str] = set()

@@ -3,8 +3,8 @@
 The watchdog (utils/watchdog.py) is only hang-proof if every blocking
 device interaction actually routes through it — one raw
 ``jax.device_get`` / ``block_until_ready`` / deferred-handle ``.result()``
-on the solve path reintroduces exactly the unbounded wait the r02–r05
-hangs demonstrated.  This rule extends the PR-4 blocking-call machinery
+on the solve path reintroduces exactly the unbounded wait the watchdog
+exists to bound.  This rule extends the PR-4 blocking-call machinery
 (analysis/passes/lock_order's blocking set) to the device-path subtrees:
 
   unbounded-block   a blocking device call (``jax.device_get``,

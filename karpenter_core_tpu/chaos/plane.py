@@ -63,7 +63,7 @@ KIND_TIMEOUT = "timeout"
 KIND_PARTIAL = "partial"
 KIND_DUPLICATE = "duplicate"
 KIND_SKEW = "skew"
-# a silent stall (the r02–r05 relay failure shape): interpreted only by the
+# a silent stall (a device that hangs instead of erroring): interpreted only by the
 # watchdog's monitored dispatch sites (utils/watchdog.py, point solver.hang)
 # — the call blocks for delay_s (0 = until abandoned) instead of erroring
 KIND_HANG = "hang"

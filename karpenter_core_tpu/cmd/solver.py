@@ -22,21 +22,31 @@ import threading
 from karpenter_core_tpu.cmd.operator import load_cloud_provider
 
 
+def compose(provider=None, address=None):
+    """Start the sidecar the way the deployed binary does; returns
+    ``(server, bound_port)``.  ``main()`` calls it with the env-configured
+    provider and address; chip_smoke.py calls it with its own catalog and a
+    loopback port, so the smoke drives the same composition users run."""
+    from karpenter_core_tpu.service.snapshot_channel import serve
+
+    if provider is None:
+        provider = load_cloud_provider(
+            os.environ.get(
+                "CLOUD_PROVIDER",
+                "karpenter_core_tpu.cloudprovider.fake:FakeCloudProvider",
+            )
+        )
+    if address is None:
+        address = os.environ.get("KC_SOLVER_LISTEN", "0.0.0.0:8980")
+    return serve(provider, address=address)
+
+
 def main() -> int:
     logging.basicConfig(
         level=os.environ.get("LOG_LEVEL", "INFO").upper(),
         format="%(asctime)s %(levelname)s %(name)s %(message)s",
     )
-    from karpenter_core_tpu.service.snapshot_channel import serve
-
-    provider = load_cloud_provider(
-        os.environ.get(
-            "CLOUD_PROVIDER",
-            "karpenter_core_tpu.cloudprovider.fake:FakeCloudProvider",
-        )
-    )
-    address = os.environ.get("KC_SOLVER_LISTEN", "0.0.0.0:8980")
-    server, port = serve(provider, address=address)
+    server, port = compose()
     logging.getLogger(__name__).info("tpu solver sidecar listening on :%d", port)
 
     stop = threading.Event()

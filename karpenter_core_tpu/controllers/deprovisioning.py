@@ -794,7 +794,7 @@ class MultiNodeConsolidation(_ConsolidationBase):
             if self.solver_breaker is not None:
                 self.solver_breaker.release_trial()  # shape verdict, not backend
             return None
-        except Exception as e:  # backend init/relay faults: host binary search
+        except Exception as e:  # backend init/dispatch faults: host binary search
             if self.solver_breaker is not None:
                 self.solver_breaker.record_failure()
                 state = self.solver_breaker.state

@@ -73,7 +73,7 @@ POLICY_COUNTERPROPOSALS = REGISTRY.counter(
     ("kind",),
 )
 
-# consecutive unexpected kernel failures (backend init/relay faults, not
+# consecutive unexpected kernel failures (backend init/dispatch faults, not
 # KernelUnsupported routing) before the solver-backend circuit breaker opens
 # and batches route through the degraded host path until the breaker's
 # half-open trial re-proves the backend
@@ -273,7 +273,7 @@ class ProvisioningController:
         # across reconciles; its fallback policy decides full vs delta per
         # batch and KC_SOLVER_INCREMENTAL=0 disables it entirely
         self._incremental_session = None
-        # the solver-backend breaker: counts unexpected kernel/relay faults
+        # the solver-backend breaker: counts unexpected kernel/backend faults
         # (not KernelUnsupported routing); open = degraded mode (bounded host
         # solves here, deprovisioning paused), half-open = one trial batch
         # re-proves the device path.  Shared with the deprovisioning
@@ -379,8 +379,8 @@ class ProvisioningController:
                     state_nodes=[n for n in self.cluster.snapshot_nodes() if not n.marked()],
                     bound_pods=self.kube_client.list_pods(),
                 )
-            except Exception as e:  # noqa: BLE001 - warmup is best-effort
-                log.debug("speculative kernel warmup failed: %s", e)
+            except Exception:  # noqa: BLE001 - warmup runs off the solve path
+                log.warning("speculative kernel warmup failed", exc_info=True)
 
         thread = threading.Thread(target=run, name="kc-tpu-warmup", daemon=True)
         self._warmup_thread = thread
@@ -558,7 +558,7 @@ class ProvisioningController:
                     # half-open trial slot so a later batch can still probe
                     self.solver_breaker.release_trial()
                     raise
-                except Exception as e:  # backend init/relay faults, not routing
+                except Exception as e:  # backend init/dispatch faults, not routing
                     self.solver_breaker.record_failure()
                     TPU_KERNEL_FALLBACK.labels("backend-error").inc()
                     log.warning(

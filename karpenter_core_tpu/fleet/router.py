@@ -49,9 +49,14 @@ from karpenter_core_tpu.utils.clock import Clock
 
 log = logging.getLogger(__name__)
 
-# must match service/snapshot_channel.py SERVICE — redeclared so a thin
-# router process never imports the solver stack
+# must match service/snapshot_channel.py SERVICE / CHANNEL_OPTIONS —
+# redeclared so a thin router process never imports the solver stack (the
+# router forwards the replicas' answers whole, so it needs their size limits)
 SERVICE = "karpenter.v1.SnapshotSolver"
+CHANNEL_OPTIONS = (
+    ("grpc.max_send_message_length", -1),
+    ("grpc.max_receive_message_length", 1 << 30),
+)
 
 # the router→replica forwarding edge (docs/CHAOS.md): error (replica
 # unreachable), timeout (forward deadline), partial (the replica answered
@@ -150,7 +155,9 @@ class FleetRouter(grpc.GenericRpcHandler):
             if stub is None:
                 channel = self._channels.get(rid)
                 if channel is None:
-                    channel = grpc.insecure_channel(self.addresses[rid])
+                    channel = grpc.insecure_channel(
+                        self.addresses[rid], options=CHANNEL_OPTIONS
+                    )
                     self._channels[rid] = channel
                 stub = channel.unary_unary(f"/{SERVICE}/{method}")
                 self._stubs[key] = stub
@@ -377,6 +384,7 @@ def serve_router(fleet: FleetLocal, address: str = "127.0.0.1:0", *,
     server = grpc.server(
         futures.ThreadPoolExecutor(max_workers=max_workers),
         maximum_concurrent_rpcs=max_workers * 4,
+        options=CHANNEL_OPTIONS,
     )
     router = FleetRouter(fleet, clock=clock, tenant_config=tenant_config)
     server.add_generic_rpc_handlers((router,))

@@ -58,18 +58,20 @@ def _load() -> Optional[ctypes.CDLL]:
 def _build_and_load() -> Optional[ctypes.CDLL]:
     """Build (if needed) and dlopen the library.  Runs with NO lock held —
     the subprocess can take up to 120 s and must not stall other threads;
-    the caller holds the in-flight slot, so the build is still run once."""
-    if not os.path.exists(_LIB_PATH):
-        try:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        except Exception as e:  # noqa: BLE001 - fall back to numpy
-            log.warning("native runtime build failed, using numpy fallback: %s", e)
-            return None
+    the caller holds the in-flight slot, so the build is still run once.
+    ``make`` decides whether the binary is current (a timestamp check when
+    it is): an existing .so is never trusted on its own, since the
+    git-ignored file can outlive the source it was built from."""
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR],
+            check=True,
+            capture_output=True,
+            timeout=120,
+        )
+    except Exception as e:  # noqa: BLE001 - fall back to numpy
+        log.warning("native runtime build failed, using numpy fallback: %s", e)
+        return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError as e:

@@ -66,18 +66,18 @@ def load():
 def _build_and_import():
     """Build (if needed) and import the extension.  Runs with NO lock held —
     the g++ subprocess must not stall other threads; the caller holds the
-    in-flight slot, so the build still runs once."""
-    if not os.path.exists(_SO_PATH):
-        try:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR, "kc_sig.so"],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        except Exception as e:  # noqa: BLE001 - fall back to the Python twin
-            log.warning("kc_sig build failed, using Python fast key: %s", e)
-            return None
+    in-flight slot, so the build still runs once.  ``make`` decides whether
+    the binary is current (models/native.py has the reason)."""
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "kc_sig.so"],
+            check=True,
+            capture_output=True,
+            timeout=120,
+        )
+    except Exception as e:  # noqa: BLE001 - fall back to the Python twin
+        log.warning("kc_sig build failed, using Python fast key: %s", e)
+        return None
     if not os.path.exists(_SO_PATH):
         # headerless toolchain: the Makefile skipped the target gracefully
         log.info("kc_sig.so not built (no Python headers); Python fast key in use")

@@ -118,7 +118,6 @@ def _lane_sweep_fn(mesh_axes, key_has_bounds, n_slots: int, n_passes: int,
     JAX's compile cache (keyed on callable identity), so the builder is
     memoized on the topology + static config (the spec pytrees are hashable
     and shape-identifying)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from karpenter_core_tpu.parallel import mesh as mesh_mod
@@ -142,14 +141,14 @@ def _lane_sweep_fn(mesh_axes, key_has_bounds, n_slots: int, n_passes: int,
         new_viable=P(lane, None, cat), new_zone=P(lane), new_ct=P(lane),
         new_used=P(lane), new_tmpl=P(lane), new_cost=P(lane),
     )
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         # lane outputs are genuinely sharded and the catalog collectives
         # inside the body are exact — the mesh parity suite pins every
         # lane-sweep plane bit-identical to unsharded except new_cost, a
         # f32 sum whose reduction order XLA reassociates per program
         # (last-ulp only; the summands themselves are pinned exact)
-        check_rep=False,
+        check_vma=False,
     ))
 
 

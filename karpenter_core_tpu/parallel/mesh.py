@@ -45,7 +45,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from karpenter_core_tpu.models.snapshot import EncodedSnapshot
@@ -251,12 +250,12 @@ def tenant_solve_callable(mesh_axes, base_plain, structs):
     vmapped = jax.vmap(base_plain)
     in_specs = tuple(tenant_partition_specs(s) for s in structs)
     out_specs = tenant_partition_specs(jax.eval_shape(vmapped, *structs))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         vmapped, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         # every output leaf is sharded over the tenant axis (no replicated
         # outputs to verify) and tenants never exchange data; the coalesced
         # parity tests (tests/test_tenant_service.py) pin bit-identity
-        check_rep=False,
+        check_vma=False,
     ))
 
 
@@ -305,14 +304,14 @@ def sharded_solve_callable(mesh_axes, base_with_axis, base_plain, structs,
     mesh = mesh_for(mesh_axes)
     in_specs = tuple(partition_specs(s) for s in structs)
     out_specs = partition_specs(jax.eval_shape(base_plain, *structs))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         base_with_axis, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         # replicated out_specs are guaranteed by construction (every
         # cross-shard reduction is an exact collective inside the body);
-        # check_rep's rewrite machinery cannot see through the class scan,
+        # the varying-manual-axes check cannot see through the class scan,
         # so the static claim stands in for it — the mesh parity fuzz
         # (tests/test_mesh_dispatch.py) pins the guarantee at runtime
-        check_rep=False,
+        check_vma=False,
     ), donate_argnums=tuple(donate_argnums))
 
 

@@ -210,7 +210,10 @@ def run_relax(solver, prep, cls=None, n_slots: int = 0) -> solve_ops.SolveOutput
             jnp.asarray(eligible), jnp.asarray(_policy_weights(solver.policy)),
             jnp.int32(max_iters), jnp.float32(RELAX_TOL),
             jnp.uint32(RELAX_SEED),
-            key=(n_slots, packed, mesh_axes),
+            # the jit compiles inside this call: key on the shapes too, so
+            # a program that has yet to compile gets the cold budget
+            key=(compilecache.leaf_sig((cls_d, sa_d, pol_d)), n_slots, packed,
+                 mesh_axes),
         )
         iters, converged, violations, leftover, placed, n_used = watchdog.run(
             "solve.sync", jax.device_get,

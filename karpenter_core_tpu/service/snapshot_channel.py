@@ -65,6 +65,17 @@ log = logging.getLogger(__name__)
 
 SERVICE = "karpenter.v1.SnapshotSolver"
 
+# gRPC refuses a message over 4 MiB unless told otherwise, and a SolveClasses
+# answer lists every viable instance type of every new node — ~86 MB at the
+# north-star size (50k pods × 1k types).  Every endpoint of this service
+# (server, client, fleet router) opens with these: what this side produces
+# is sent whole; what it receives stays bounded (the tenant plane's
+# max_request_bytes is the tighter, per-tenant cap on requests).
+CHANNEL_OPTIONS = (
+    ("grpc.max_send_message_length", -1),
+    ("grpc.max_receive_message_length", 1 << 30),
+)
+
 # the gRPC channel's injection point (docs/CHAOS.md): one Point, both
 # transports — like kubeapi.put covers both kube backends
 SERVICE_RPC = chaos.point("service.rpc")
@@ -1454,6 +1465,7 @@ def serve(
     server = grpc.server(
         futures.ThreadPoolExecutor(max_workers=workers),
         maximum_concurrent_rpcs=max_rpcs,
+        options=CHANNEL_OPTIONS,
     )
     service = SnapshotSolverService(
         cloud_provider, clock=clock, tenant_config=tenant_config,
@@ -1516,7 +1528,7 @@ class SnapshotSolverClient:
     """Controller-plane client for the channel."""
 
     def __init__(self, address: str) -> None:
-        self.channel = grpc.insecure_channel(address)
+        self.channel = grpc.insecure_channel(address, options=CHANNEL_OPTIONS)
         self._solve = self.channel.unary_unary(f"/{SERVICE}/Solve")
         self._solve_classes = self.channel.unary_unary(f"/{SERVICE}/SolveClasses")
         self._health = self.channel.unary_unary(f"/{SERVICE}/Health")

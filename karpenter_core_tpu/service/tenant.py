@@ -402,10 +402,10 @@ def bucket_key(prep, kw=None) -> tuple:
     from karpenter_core_tpu.utils import compilecache
 
     key = (
-        compilecache._leaf_sig(prep.cls),
-        compilecache._leaf_sig(prep.statics_arrays),
-        compilecache._leaf_sig(prep.ex_state) if prep.ex_state is not None else None,
-        compilecache._leaf_sig(prep.ex_static) if prep.ex_static is not None else None,
+        compilecache.leaf_sig(prep.cls),
+        compilecache.leaf_sig(prep.statics_arrays),
+        compilecache.leaf_sig(prep.ex_state) if prep.ex_state is not None else None,
+        compilecache.leaf_sig(prep.ex_static) if prep.ex_static is not None else None,
         int(prep.n_slots),
         tuple(prep.key_has_bounds),
         int(prep.n_passes),
@@ -415,8 +415,8 @@ def bucket_key(prep, kw=None) -> tuple:
         key += (
             "repair",
             int(kw.get("n_slots") or 0) or int(prep.n_slots),
-            compilecache._leaf_sig(kw["warm_carry"]),
-            compilecache._leaf_sig(kw["repair_plan"])
+            compilecache.leaf_sig(kw["warm_carry"]),
+            compilecache.leaf_sig(kw["repair_plan"])
             if kw.get("repair_plan") is not None else None,
         )
     return key

@@ -393,7 +393,7 @@ class IncrementalSolveSession:
         pipelined loop — class docstring); full solves settle inline and the
         handle is immediately consumable.  With KC_PIPELINE=0 the handle is
         always settled inline — the serial loop bit-for-bit."""
-        from karpenter_core_tpu.solver.backendprobe import SOLVER_DISPATCH
+        from karpenter_core_tpu.solver.tpu import SOLVER_DISPATCH
 
         # settle the in-flight deferred tick FIRST: this tick's membership
         # diff and eviction plan read the bookkeeping that tick rewrites
@@ -1301,6 +1301,36 @@ class IncrementalSolveSession:
             "failed": len(w.failed_pods),
             "nodes": self.node_count(),
         }
+
+    def used_drift(self) -> float:
+        """Largest relative gap between the carry's per-slot ``used`` plane
+        and an exact (float64) recount of it from the lineage's own
+        assignments: zero up to f32 rounding when every repair returned
+        exactly what the scan charged.  A matmul that runs below f32
+        precision on the device (ops.solve._EXACT_F32) shows here as ~1e-3."""
+        import jax
+
+        from karpenter_core_tpu.utils import watchdog
+
+        self.settle()
+        w = self._warm
+        if w is None:
+            return 0.0
+        used, tmpl_id, open_, requests, daemon = watchdog.run(
+            "solve.sync", jax.device_get,
+            (w.carry.state.used, w.carry.state.tmpl_id, w.carry.state.open_,
+             w.prep.cls.requests, w.prep.statics_arrays.tmpl_daemon),
+            key="used-drift",
+        )
+        want = np.asarray(daemon, dtype=np.float64)[np.asarray(tmpl_id)] + np.einsum(
+            "cn,cr->nr", w.assign.astype(np.float64),
+            np.asarray(requests, dtype=np.float64)[: w.assign.shape[0]],
+        )
+        live = np.asarray(open_, dtype=bool) & (w.assign.sum(axis=0) > 0)
+        if not live.any():
+            return 0.0
+        gap = np.abs(np.asarray(used, dtype=np.float64) - want)[live]
+        return float((gap / np.maximum(np.abs(want[live]), 1e-30)).max())
 
     def node_signature(self):
         """Canonical multiset of per-node class loads, labeled by stable

@@ -4,9 +4,7 @@ The role the reference delegates to controller-runtime's logging/tracing
 context (knative logging + the scheduling loop's structured messages) is
 re-centered here as explicit spans, because the hot path this repo cares
 about is a *pipeline* (ingest → encode → dispatch → solve → decode →
-materialize) whose cost attribution is invisible in wall-clock logging —
-BENCH_r05 showed `solve_decode_s` at 98% of warm time with no internal
-breakdown.
+materialize) whose cost attribution is invisible in wall-clock logging.
 
 Design constraints:
 

@@ -25,8 +25,7 @@ FLAGS: Dict[str, str] = {
     "KC_KERNEL_FUSE_ZONES": "fuse the per-zone kernel phases into one dispatch",
     "KC_KERNEL_PACKED_MASKS": "bit-packed compatibility masks inside the scan kernel",
     "KC_TPU_SHAPE_BUCKETS": "explicit shape-bucket edges for the compile cache (comma-separated pod counts)",
-    "KC_TPU_COMPILE_CACHE": "enable/disable the per-(bucket, mesh) executable memo",
-    "KC_TPU_XLA_CACHE": "directory for the persistent XLA compilation cache",
+    "KC_TPU_COMPILE_CACHE": "directory for what the package persists (exported StableHLO, XLA cache, journal, leases)",
     "KC_TPU_KERNEL": "select the operator's solver kernel implementation",
     "KC_TPU_WARMUP": "pre-compile the solver executables at operator startup",
     "KC_SOLVER_MESH": "enable the sharded device-mesh solve path",
@@ -40,10 +39,7 @@ FLAGS: Dict[str, str] = {
     "KC_SOLVER_MODE": "solver family routing: scan | relax | auto (PolicyConfig/provisioner spec wins over env)",
     "KC_RELAX_MAX_ITERS": "projected-gradient iteration cap for the relax solver family",
     "KC_RELAX_MIN_PODS": "pod-count threshold above which auto mode picks the relax family",
-    # -- backend probe + watchdog ---------------------------------------------
-    "KC_PROBE_TIMEOUT_S": "accelerator backend probe deadline",
-    "KC_PROBE_LIVENESS_TIMEOUT_S": "liveness pre-check deadline before the full backend probe",
-    "KC_PROBE_FAIL_TTL_S": "how long a failed backend probe is cached before re-probing",
+    # -- watchdog --------------------------------------------------------------
     "KC_WATCHDOG": "adaptive watchdog over every blocking device interaction (0 = legacy unguarded waits)",
     "KC_WATCHDOG_FLOOR_S": "watchdog deadline floor",
     "KC_WATCHDOG_CEILING_S": "watchdog deadline ceiling",

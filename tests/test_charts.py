@@ -82,10 +82,10 @@ class TestMainChart:
         assert env_api["KC_KUBE_BACKEND"] == "apiserver"
         assert env_api["KC_KUBE_APISERVER"] == "http://127.0.0.1:8001"
 
-    def test_solver_pins_jax_platform_for_the_xla_cache(self):
-        # compilecache.enable() keeps the persistent XLA cache off for an
-        # unpinned/cpu platform; the deployed TPU solver must name its
-        # platform or silently lose the cache volume's benefit (ADVICE r5)
+    def test_solver_pins_jax_platform(self):
+        # the deployed TPU solver names its platform so JAX fails at
+        # start-up on a node without the accelerator instead of serving
+        # from CPU
         solver = render_chart(CHART)["solver.yaml"]
         deploy = next(d for d in solver if d["kind"] == "Deployment")
         env = {

@@ -1,0 +1,254 @@
+"""chip_smoke.py and the chip-or-fail plumbing around it (PR 21).
+
+The smoke's verdict must fail on every quiet way off the device; the compile
+cache must land where the environment (or the fixed in-checkout default)
+says; a build error must surface instead of rerouting the solve; bench.py's
+top level must never hold the chip while it starts a child.  (The end-to-end
+CPU dry run of the smoke itself is tests/test_smoke_dry_run.py: it compiles
+every leg, so it sorts after the cheap suites.)
+"""
+
+import ast
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+from karpenter_core_tpu.utils import compilecache  # noqa: E402
+
+def _clean_observation() -> dict:
+    return {
+        "platform": "tpu",
+        "expect_platform": "tpu",
+        "off_device_outputs": [],
+        "request_errors": [],
+        "builds": 7,
+        "plain_jit_runs": 0,
+        "watchdog_timeouts": {},
+        "fallback_counters": {
+            "karpenter_tpu_kernel_fallback": {},
+            "karpenter_degraded_solves_total": {},
+        },
+        "breaker_states": {"solver-backend": "closed", "tenant:smoke": "closed"},
+        "solve_modes": {"scan", "full", "delta"},
+        "warm_window_compiles": 0,
+    }
+
+
+class TestVerdict:
+    def test_clean_run_passes(self):
+        assert chip_smoke.verdict(_clean_observation()) == []
+
+    @pytest.mark.parametrize("field,value,needle", [
+        ("platform", "cpu", "backend is 'cpu'"),
+        ("off_device_outputs", ["SolveOutputs[3] on ['cpu']"], "not on a tpu device"),
+        ("request_errors", ["solve_classes.cold: RpcError: boom"], "request raised"),
+        ("builds", 0, "built no executable"),
+        ("plain_jit_runs", 1, "plain-jit"),
+        ("watchdog_timeouts", {"solve.dispatch": 1}, "watchdog timeouts"),
+        ("fallback_counters",
+         {"karpenter_tpu_kernel_fallback": {"reason=backend-error": 1.0},
+          "karpenter_degraded_solves_total": {}},
+         "karpenter_tpu_kernel_fallback moved"),
+        ("fallback_counters",
+         {"karpenter_tpu_kernel_fallback": {},
+          "karpenter_degraded_solves_total": {"controller=provisioning": 1.0}},
+         "karpenter_degraded_solves_total moved"),
+        ("breaker_states", {"solver-backend": "open"}, "breaker solver-backend is open"),
+        ("solve_modes", {"scan", "relax-fallback:existing-nodes"}, "relax-fallback"),
+        ("solve_modes", {"host"}, "'host' engaged"),
+        ("warm_window_compiles", 2, "inside the warm window"),
+    ])
+    def test_each_quiet_way_off_the_device_fails(self, field, value, needle):
+        obs = _clean_observation()
+        obs[field] = value
+        failures = chip_smoke.verdict(obs)
+        assert len(failures) == 1 and needle in failures[0], failures
+
+
+class TestSmokeEntry:
+    def test_refuses_to_run_without_a_tpu(self):
+        """No accelerator: non-zero exit, the platform named, NO result."""
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+            capture_output=True, text=True, timeout=120, cwd=REPO,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert proc.returncode == chip_smoke.EXIT_NO_CHIP
+        assert proc.stdout == ""
+        assert "'cpu'" in proc.stderr and "nothing was run" in proc.stderr
+
+    def test_dry_run_asked_for_but_not_pinned_is_refused(self, monkeypatch):
+        """--cpu-dry-run on a non-CPU backend is the same refusal."""
+        import jax
+
+        class FakeTpu:
+            platform, device_kind = "tpu", "TPU v5 lite"
+
+        monkeypatch.setattr(jax, "devices", lambda: [FakeTpu()])
+        assert chip_smoke.main(["--cpu-dry-run"]) == chip_smoke.EXIT_NO_CHIP
+
+
+class TestCompileCachePlacement:
+    @pytest.fixture()
+    def decided(self, monkeypatch):
+        """Re-arm the lazy XLA-cache decision on a pretend backend and record
+        (never apply) the jax.config updates it makes."""
+        import jax
+
+        updates = {}
+        monkeypatch.setattr(compilecache, "_xla_cache_decided", False)
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: updates.__setitem__(k, v)
+        )
+        monkeypatch.delenv("KC_TPU_COMPILE_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
+        def resolve(backend):
+            monkeypatch.setattr(jax, "default_backend", lambda: backend)
+            assert compilecache._resolved_backend() == backend
+            return updates
+
+        return resolve
+
+    def test_env_placed_cache_is_not_overridden_in_code(self, decided, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        updates = decided("tpu")
+        assert "jax_compilation_cache_dir" not in updates
+        # on (JAX reads the env var itself), fast compiles persisted too
+        assert updates == {"jax_persistent_cache_min_compile_time_secs": 0.0}
+
+    def test_default_is_the_fixed_in_checkout_directory(self, decided):
+        updates = decided("tpu")  # unnamed platform: JAX_PLATFORMS is irrelevant
+        want = os.path.join(REPO, ".kc_cache", "xla")
+        assert updates["jax_compilation_cache_dir"] == want
+        assert os.path.isdir(want)
+        assert compilecache.cache_dir() == os.path.join(REPO, ".kc_cache")
+
+    def test_kc_tpu_compile_cache_relocates_the_root(self, decided, monkeypatch, tmp_path):
+        monkeypatch.setenv("KC_TPU_COMPILE_CACHE", str(tmp_path / "vol"))
+        updates = decided("tpu")
+        assert updates["jax_compilation_cache_dir"] == str(tmp_path / "vol" / "xla")
+        assert os.path.isdir(tmp_path / "vol" / "xla")
+
+    def test_cpu_backend_keeps_the_xla_cache_off(self, decided):
+        assert decided("cpu") == {}
+
+    def test_enable_does_not_initialize_a_backend(self, monkeypatch):
+        """Operator.start calls enable(): it must stay off the backend."""
+        import jax
+
+        def boom():
+            raise AssertionError("enable() touched the backend")
+
+        monkeypatch.setattr(jax, "default_backend", boom)
+        monkeypatch.setattr(jax, "devices", boom)
+        compilecache.enable()
+
+
+class TestNoHiddenFallback:
+    def test_build_error_propagates_from_solve_callable(self, monkeypatch):
+        """The seed swallowed this into ``None`` and run_solve quietly ran
+        the plain jit instead."""
+        import numpy as np
+
+        from karpenter_core_tpu.ops import solve as solve_ops
+
+        def refuse(*a, **kw):
+            raise RuntimeError("XLA:TPU refused the program")
+
+        monkeypatch.setattr(compilecache, "_build_and_memo", refuse)
+        cls = solve_ops.ClassTensors(*(
+            np.zeros((2, 3), np.int32) for _ in solve_ops.ClassTensors._fields
+        ))
+        with pytest.raises(RuntimeError, match="refused the program"):
+            compilecache.solve_callable(cls, (np.zeros(3, np.float32),), 8, (False,))
+        # the failed build left no in-flight slot behind: a retry builds again
+        with pytest.raises(RuntimeError, match="refused the program"):
+            compilecache.solve_callable(cls, (np.zeros(3, np.float32),), 8, (False,))
+
+    def test_run_solve_has_no_second_solve_path(self):
+        import inspect
+
+        assert "_solve_jit" not in inspect.getsource(compilecache.run_solve)
+
+
+class TestBenchOneProcessPerChip:
+    def test_top_level_never_touches_jax_and_runs_children_in_sequence(self):
+        """A parent that has touched JAX holds the chip: the top level must
+        start every measuring child while still off JAX, one at a time, and
+        exit non-zero when a phase failed."""
+        script = textwrap.dedent("""
+            import json, subprocess, sys, types
+            import bench
+
+            spawned = []
+
+            def fake_run(cmd, **kw):
+                assert "jax" not in sys.modules, "top level imported jax"
+                args = cmd[2:]
+                spawned.append(args)
+                if "--measure" in args:
+                    out = {"metric": "m", "value": 1.0, "detail": {
+                        "platform": "tpu", "device_kind": "TPU v5 lite",
+                        "device_count": 4, "failed_phases": []}}
+                elif "--restart-probe" in args:
+                    out = {"restart_cold_s": 2.5, "scheduled": 1}
+                else:
+                    k = int(args[args.index("--sharded-probe") + 1])
+                    if k == FAIL_SIZE:
+                        return types.SimpleNamespace(
+                            returncode=1, stdout="", stderr="boom")
+                    out = {"mesh_devices": k, "solve_s": 1.0 / k,
+                           "scheduled": 9, "failed": 0, "nodes": 3}
+                return types.SimpleNamespace(
+                    returncode=0, stdout=json.dumps(out) + "\\n", stderr="")
+
+            bench.subprocess.run = fake_run
+            rc = bench.main(50000, 1000)
+            print(json.dumps({"rc": rc, "spawned": spawned}))
+        """)
+        for fail_size, want_rc in ((0, 0), (2, 1)):
+            proc = subprocess.run(
+                [sys.executable, "-c", f"FAIL_SIZE = {fail_size}\n" + script],
+                capture_output=True, text=True, timeout=60, cwd=REPO,
+            )
+            assert proc.returncode == 0, proc.stderr
+            bench_line, report = proc.stdout.strip().splitlines()[-2:]
+            report = json.loads(report)
+            assert report["rc"] == want_rc
+            flags = [next(a for a in args if a.startswith("--"))
+                     for args in report["spawned"]]
+            # main run, then restart probe, then mesh sizes 1/2/4 (8 trimmed
+            # to the 4 devices the main run stamped)
+            assert flags == ["--measure", "--restart-probe"] + ["--sharded-probe"] * 3
+            detail = json.loads(bench_line)["detail"]
+            assert detail["cold_s"] == 2.5 and detail["platform"] == "tpu"
+            assert detail["failed_phases"] == (["sharded"] if fail_size else [])
+
+    def test_only_run_child_starts_processes(self):
+        """The measuring run itself (``--measure``) starts no child: every
+        use of subprocess/os.exec* in bench.py sits inside ``run_child``."""
+        with open(os.path.join(REPO, "bench.py")) as f:
+            tree = ast.parse(f.read())
+        offenders = []
+        for fn in [n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)]:
+            for node in ast.walk(fn):
+                if (
+                    isinstance(node, ast.Attribute)
+                    and isinstance(node.value, ast.Name)
+                    and (node.value.id == "subprocess" or (
+                        node.value.id == "os" and node.attr.startswith(
+                            ("exec", "spawn", "fork", "system", "popen"))))
+                    and fn.name != "run_child"
+                ):
+                    offenders.append((fn.name, node.attr))
+        assert offenders == []

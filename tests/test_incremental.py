@@ -354,6 +354,48 @@ class TestSessionParity:
         agg = session.aggregates()
         assert agg["scheduled"] == len(ingest)
         assert agg["failed"] == 0
+        # capacity accounting: what the repairs returned is what the scan
+        # charged, up to f32 rounding
+        assert session.used_drift() < 1e-6
+
+    def test_resource_matmuls_are_pinned_to_full_f32_precision(self):
+        """Found on the TPU (PR 21): at default precision the chip rounds f32
+        matmul operands to bf16, so ``repair_free`` returned 0.1001 cpu for
+        every 0.1 the scan had charged and the used plane drifted (2e-3
+        relative) until the lineage diverged from a from-scratch solve.  CPU
+        cannot show the loss, so pin the contract on the traced program:
+        every dot whose operands are both f32 asks for HIGHEST."""
+        import jax
+        import numpy as np
+
+        from karpenter_core_tpu.ops import solve as solve_ops
+
+        solver = _solver()
+        ingest = PodIngest()
+        ingest.add_all(_population(24))
+        session = IncrementalSolveSession(
+            solver,
+            FallbackPolicy(enabled=True, audit_interval=0, max_delta_fraction=0.9),
+        )
+        session.solve(ingest)
+        w = session._warm
+        n_classes, n_slots = w.assign.shape
+        n_ex = w.assign_ex.shape[1]
+        jaxpr = jax.make_jaxpr(solve_ops._repair_free_impl)(
+            w.carry, np.zeros((n_classes, n_slots), np.int32),
+            np.zeros((n_classes, n_ex), np.int32),
+            np.asarray(w.prep.cls.requests), w.member_rows, w.own_inv_rows,
+        )
+        f32_dots = [
+            eqn for eqn in jaxpr.jaxpr.eqns
+            if eqn.primitive.name == "dot_general"
+            and all(v.aval.dtype == np.float32 for v in eqn.invars)
+        ]
+        assert len(f32_dots) == 2  # used (new slots) + used (existing nodes)
+        for eqn in f32_dots:
+            assert eqn.params["precision"] == (
+                jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST
+            ), eqn
 
     def test_windowed_repair_matches_full_solve(self, monkeypatch):
         """Same parity with the bounded repair window forced on at tier-1

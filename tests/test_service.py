@@ -456,6 +456,25 @@ class TestRemoteLeaseCAS:
         yield f"127.0.0.1:{port}"
         server.stop(0)
 
+    def test_messages_past_grpc_default_4mib_cross_both_ways(self, lease_server):
+        """A SolveClasses answer at the north-star size is ~86 MB; gRPC's
+        default cap is 4 MiB in each direction.  The lease plane echoes what
+        it is sent, so one fat lease proves request AND response sizes
+        without a solve."""
+        from karpenter_core_tpu.service.snapshot_channel import SnapshotSolverClient
+
+        client = SnapshotSolverClient(lease_server)
+        try:
+            fat = "x" * (5 << 20)
+            response = client.lease_apply(
+                {"name": "fat", "namespace": "", "holderIdentity": fat},
+                timeout=30.0,
+            )
+            assert response["ok"]
+            assert response["lease"]["holderIdentity"] == fat
+        finally:
+            client.close()
+
     @staticmethod
     def _lease(name="leader", holder="", transitions=0):
         from karpenter_core_tpu.apis.objects import Lease, LeaseSpec, ObjectMeta

@@ -2,7 +2,7 @@
 
 The Operator facade defaults the device kernel ON (matching the binary's
 KC_TPU_KERNEL default, cmd/operator.py) — VERDICT r2 weak #7.  When the
-backend faults at solve time (relay down, init failure), batches must land on
+backend faults at solve time (device lost, init failure), batches must land on
 the host scheduler with no pods lost, and repeated faults open the shared
 solver-backend circuit breaker (utils/retry.CircuitBreaker): batches run
 degraded on the host path without touching the backend until the breaker's
@@ -32,7 +32,7 @@ class TestTPUDefaultOn:
 
 class _ExplodingSolver:
     """Stands in for TPUSolver when the backend is unreachable: any
-    construction attempt raises the way a dead relay surfaces (RuntimeError
+    construction attempt raises the way a dead backend surfaces (RuntimeError
     from the first device op)."""
 
     calls = 0

@@ -166,6 +166,47 @@ class TestMonitoredDispatch:
             assert watchdog.run("t.x", lambda: "ok", deadline_s=2.0) == "ok"
         assert scenario.fired_counts().get("solver.hang") == 1
 
+    def test_dispatch_deadline_is_keyed_on_the_planes_shapes(self, monkeypatch):
+        """Found on the TPU (PR 21): a second catalog sharing n_slots /
+        passes / features with an already-warm one is a DIFFERENT executable
+        that has yet to compile — it must get the cold budget, not the first
+        program's 10 s warm floor (one TPU compile outlasts the floor, so the
+        dispatch was abandoned mid-compile)."""
+        from karpenter_core_tpu.utils import compilecache
+
+        monkeypatch.setattr(compilecache, "run_solve", lambda *a, **kw: None)
+        keys = []
+        real_run = watchdog.run
+
+        def spy(site, fn, *args, key=None, **kw):
+            keys.append(key)
+            return real_run(site, fn, *args, key=key, **kw)
+
+        monkeypatch.setattr(watchdog, "run", spy)
+        watchdog.reset_stats()
+        pods = make_pods(6, requests={"cpu": "500m"})
+
+        def dispatch(n_types, times):
+            solver = TPUSolver(
+                fake_cp.FakeCloudProvider(fake_cp.instance_types(n_types)),
+                [make_provisioner()],
+            )
+            prep = solver.prepare_encoded(solver.encode(pods), n_slots=64)
+            for _ in range(times):
+                solver.run_prepared(prep)
+            return prep, keys[-1]
+
+        small_prep, small = dispatch(8, times=2)  # 2nd completion: warm
+        large_prep, large = dispatch(40, times=1)
+        # everything the key held before PR 21 is equal...
+        assert (small_prep.n_slots, small_prep.n_passes, small_prep.features) == (
+            large_prep.n_slots, large_prep.n_passes, large_prep.features)
+        # ...yet the programs differ, and only the first is warm
+        assert small != large
+        cold = watchdog.deadline_for("solve.dispatch", key="never-seen")
+        assert watchdog.deadline_for("solve.dispatch", key=small) < cold
+        assert watchdog.deadline_for("solve.dispatch", key=large) == cold
+
     def test_poisoned_worker_never_rejoins_the_pool(self):
         with pytest.raises(watchdog.SolveTimeout):
             watchdog.run("t.slow", time.sleep, 600, deadline_s=0.1)

@@ -7,7 +7,7 @@ beyond the tolerance.  The reference gates every CI run the same way
 here — a 50x cushion never trips — so this gate tracks drift round-over-round.
 
 Cross-machine honesty: bench records carry a ``machine`` fingerprint
-(utils/compilecache._machine_tag).  When the last same-platform record came
+(bench._machine_tag).  When the last same-platform record came
 from a different machine the tolerance widens (observed cross-machine spread
 on the same code is ~15%), so the gate still catches collapses without
 flagging hardware variance as regressions.
@@ -33,26 +33,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 sys.path.insert(0, REPO)
-from bench import run_pinned  # noqa: E402 - shared run contract
-from karpenter_core_tpu.solver.backendprobe import probe_once  # noqa: E402
+from bench import run_child  # noqa: E402 - the bench's one process launcher
 
 
 def run_bench() -> dict:
-    """Run bench.py with backend pre-pinned by a single bounded probe (the
-    bench's own probe ladder is for the driver's unattended run).  The probe
-    timeout honors KC_PROBE_TIMEOUT_S when set, else a presubmit-tight 45 s."""
-    timeout = 45.0
-    if os.environ.get("KC_PROBE_TIMEOUT_S"):
-        try:
-            timeout = float(os.environ["KC_PROBE_TIMEOUT_S"])
-        except ValueError:
-            pass
-    platform = probe_once(timeout).platform
-    rec = run_pinned(platform or "cpu")
-    if "error" in rec:
-        sys.stderr.write(rec.get("stderr", "") + "\n")
-        raise SystemExit(f"perfgate bench run failed: {rec['error']}")
-    return rec
+    """Run ``python bench.py`` on whatever device JAX finds and return its one
+    JSON line.  This process never touches JAX, so the bench's own children
+    are the only owners of the chip.  A bench that exits non-zero (a phase
+    failed) or prints no line is a hard failure."""
+    try:
+        return run_child([], timeout_s=7200.0)
+    except Exception as e:  # noqa: BLE001 - any bench failure fails the gate
+        raise SystemExit(f"perfgate bench run failed: {e}")
 
 
 def last_record(platform: str):
