@@ -970,21 +970,35 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
 
     def _solve_classes(self, request: bytes, context) -> bytes:
         t0 = tenant_mod.monotonic()
-        partial = self._rpc_chaos(context, "SolveClasses")
-        try:
-            req = msgpack.unpackb(request)
-        except Exception as e:  # noqa: BLE001 - not even msgpack
-            context.abort(grpc.StatusCode.INVALID_ARGUMENT, f"malformed request: {e}")
-        try:
-            if isinstance(req, dict) and req.get("tenant"):
-                response = self._solve_classes_tenant(req, context, request, t0)
-            else:
-                response = self._solve_classes_stateless(req, context, t0)
-        except _AbortRequest as a:
-            context.abort(a.code, a.details)
-        if partial is not None:
-            context.abort(grpc.StatusCode.UNAVAILABLE, partial.describe())
-        return response
+        # the handler's root span, stateless and tenant alike; below it one
+        # span per PHASE (decode, materialize, payload, pack), never per
+        # class, so a request opens the same number whatever its size
+        with tracing.span("service.solve_classes",
+                          request_bytes=len(request)) as root:
+            partial = self._rpc_chaos(context, "SolveClasses")
+            try:
+                with tracing.span("service.decode", request_bytes=len(request)):
+                    req = msgpack.unpackb(request)
+            except Exception as e:  # noqa: BLE001 - not even msgpack
+                context.abort(grpc.StatusCode.INVALID_ARGUMENT, f"malformed request: {e}")
+            try:
+                if isinstance(req, dict) and req.get("tenant"):
+                    response = self._solve_classes_tenant(req, context, request, t0)
+                else:
+                    response = self._solve_classes_stateless(req, context, t0)
+            except _AbortRequest as a:
+                context.abort(a.code, a.details)
+            if partial is not None:
+                context.abort(grpc.StatusCode.UNAVAILABLE, partial.describe())
+            root.set(reply_bytes=len(response))
+            return response
+
+    @staticmethod
+    def _pack_reply(response: Dict) -> bytes:
+        with tracing.span("service.pack") as sp:
+            reply = msgpack.packb(response)
+            sp.set(reply_bytes=len(reply))
+        return reply
 
     def _solve_classes_stateless(self, req, context, t0: float) -> bytes:
         """The original stateless contract: every request is one snapshot
@@ -992,29 +1006,33 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
         from karpenter_core_tpu.models.snapshot import build_pod_ladder
 
         try:
-            entries = req.get("podClasses", [])
-            reps = [codec.pod_from_dict(e["pod"]) for e in entries]
-            classes = []
-            for rep, entry in zip(reps, entries):
-                cls = build_pod_ladder(rep)
-                cls.pods = [rep] * int(entry["count"])
-                classes.append(cls)
-            req_idx = {id(rep): i for i, rep in enumerate(reps)}
-            provisioners, daemonset_pods, state_nodes, bound, resolver, _ = (
-                self._decode_common(req)
-            )
+            with tracing.span("service.decode") as sp:
+                entries = req.get("podClasses", [])
+                reps = [codec.pod_from_dict(e["pod"]) for e in entries]
+                classes = []
+                for rep, entry in zip(reps, entries):
+                    cls = build_pod_ladder(rep)
+                    cls.pods = [rep] * int(entry["count"])
+                    classes.append(cls)
+                req_idx = {id(rep): i for i, rep in enumerate(reps)}
+                provisioners, daemonset_pods, state_nodes, bound, resolver, _ = (
+                    self._decode_common(req)
+                )
 
-            from karpenter_core_tpu.policy import PolicyConfig
+                from karpenter_core_tpu.policy import PolicyConfig
 
-            solver = TPUSolver(
-                self.cloud_provider, provisioners, daemonset_pods,
-                kube_client=resolver,
-                # policy over the wire (regression: a CPU controller replica
-                # with the objective enabled previously fell back SILENTLY to
-                # first-fit selection on remote solves — the field never
-                # crossed the channel)
-                policy=PolicyConfig.from_wire(req.get("policy")),
-            )
+                solver = TPUSolver(
+                    self.cloud_provider, provisioners, daemonset_pods,
+                    kube_client=resolver,
+                    # policy over the wire (regression: a CPU controller
+                    # replica with the objective enabled previously fell back
+                    # SILENTLY to first-fit selection on remote solves — the
+                    # field never crossed the channel)
+                    policy=PolicyConfig.from_wire(req.get("policy")),
+                )
+                sp.set(classes=len(classes),
+                       pods=sum(len(cls.pods) for cls in classes),
+                       state_nodes=len(state_nodes))
             self._deadline_guard(context, t0)
             snapshot = solver.encode_classes(
                 classes, state_nodes=state_nodes or None, bound_pods=bound
@@ -1029,7 +1047,10 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     counts[i] = counts.get(i, 0) + 1
                 return sorted(counts.items())
 
-            return msgpack.packb(self._classes_payload(results, class_counts))
+            with tracing.span("service.payload") as sp:
+                response = self._classes_payload(results, class_counts)
+                sp.set(nodes=len(response["newNodes"]))
+            return self._pack_reply(response)
         except KernelUnsupported as e:
             context.abort(grpc.StatusCode.FAILED_PRECONDITION, f"kernel unsupported: {e}")
         except _AbortRequest:
@@ -1069,7 +1090,10 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
 
         entries = req.get("podClasses", [])
         classes = []
+        copies: List[tuple] = []  # (count, uid base) per class, for pass two
         uid_class: Dict[str, int] = {}
+        # two passes, so that ONE span covers every class's copies: the
+        # representatives, ladders, digests and the duplicate check first
         for i, entry in enumerate(entries):
             rep = codec.pod_from_dict(entry["pod"])
             cls = build_pod_ladder(rep)
@@ -1083,8 +1107,12 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
             if uid_base in uid_class:
                 raise ValueError(f"duplicate pod class at index {i}")
             uid_class[uid_base] = i
-            cls.pods = cls_._materialize_class(rep, int(entry["count"]), uid_base)
+            copies.append((int(entry["count"]), uid_base))
             classes.append(cls)
+        with tracing.span("service.materialize", classes=len(classes),
+                          copies=sum(count for count, _ in copies)):
+            for cls, (count, uid_base) in zip(classes, copies):
+                cls.pods = cls_._materialize_class(cls.pods[0], count, uid_base)
         provisioners, daemonset_pods, state_nodes, bound, resolver, _ = (
             cls_._decode_common(req)
         )
@@ -1098,6 +1126,9 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
         tid = str(envelope.get("id") or "")
         if not tid:
             context.abort(grpc.StatusCode.INVALID_ARGUMENT, "tenant.id required")
+        # baggage on the handler's root: its phases carry the tenant as the
+        # session's spans do, so a per-tenant trace filter finds both
+        tracing.set_attrs(tenant=tid)
         plane = self.tenants
         decision = plane.admit(tid, weight=envelope.get("weight"))
         if not decision.admitted:
@@ -1133,21 +1164,23 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     f"tenant-ejected reason=oversized bytes={nbytes} "
                     f"limit={plane.config.max_request_bytes}",
                 )
-            try:
-                (classes, uid_class, provisioners, daemonset_pods, state_nodes,
-                 bound, resolver) = self._decode_tenant_classes(req)
-            except Exception as e:  # noqa: BLE001 - tenant-attributable
-                verdict = True
-                plane.record_bad_request(entry, "malformed")
-                context.abort(
-                    grpc.StatusCode.INVALID_ARGUMENT,
-                    f"tenant-ejected reason=malformed: {e}",
+            with tracing.span("service.decode") as sp:
+                try:
+                    (classes, uid_class, provisioners, daemonset_pods,
+                     state_nodes, bound, resolver) = self._decode_tenant_classes(req)
+                except Exception as e:  # noqa: BLE001 - tenant-attributable
+                    verdict = True
+                    plane.record_bad_request(entry, "malformed")
+                    context.abort(
+                        grpc.StatusCode.INVALID_ARGUMENT,
+                        f"tenant-ejected reason=malformed: {e}",
+                    )
+                solver = TPUSolver(
+                    self.cloud_provider, provisioners, daemonset_pods,
+                    kube_client=resolver,
+                    policy=PolicyConfig.from_wire(req.get("policy")),
                 )
-            solver = TPUSolver(
-                self.cloud_provider, provisioners, daemonset_pods,
-                kube_client=resolver,
-                policy=PolicyConfig.from_wire(req.get("policy")),
-            )
+                sp.set(classes=len(classes), state_nodes=len(state_nodes))
             claimed = int(envelope.get("sessionVersion") or 0)
             supply_digest = envelope.get("supplyDigest")
             self._deadline_guard(context, t0)
@@ -1305,18 +1338,20 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     counts[i] = counts.get(i, 0) + 1
                 return sorted(counts.items())
 
-            response = self._classes_payload(results, class_counts)
-            response["tenant"] = {
-                "id": tid,
-                "solveMode": mode,
-                "reason": reason,
-                "sessionVersion": version,
-                "batched": batched,
-            }
-            if recovered:
-                response["tenant"]["recovered"] = recovered
-                with entry.lock:
-                    entry.recovered = None
+            with tracing.span("service.payload") as sp:
+                response = self._classes_payload(results, class_counts)
+                response["tenant"] = {
+                    "id": tid,
+                    "solveMode": mode,
+                    "reason": reason,
+                    "sessionVersion": version,
+                    "batched": batched,
+                }
+                if recovered:
+                    response["tenant"]["recovered"] = recovered
+                    with entry.lock:
+                        entry.recovered = None
+                sp.set(nodes=len(response["newNodes"]))
             verdict = True
             plane.record_ok(entry)
             plane.observe_latencies(
@@ -1325,7 +1360,7 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                 solve_s=solve_s,
                 decode_s=tenant_mod.monotonic() - t_decode,
             )
-            return msgpack.packb(response)
+            return self._pack_reply(response)
         finally:
             if not verdict and decision.trial:
                 entry.breaker.release_trial()
@@ -1655,53 +1690,69 @@ class SnapshotSolverClient:
         if members is None:
             from karpenter_core_tpu.models.snapshot import _class_signature
 
-            by_sig: Dict[tuple, List[int]] = {}
-            for i, pod in enumerate(pods):
-                by_sig.setdefault(_class_signature(pod), []).append(i)
-            members = list(by_sig.values())
-        request = msgpack.packb(
-            {
-                "podClasses": [
-                    {"pod": codec.pod_to_dict(pods[idxs[0]]), "count": len(idxs)}
-                    for idxs in members
-                ],
-                "provisioners": [codec.provisioner_to_dict(p) for p in provisioners],
-                "daemonsetPods": [codec.pod_to_dict(p) for p in daemonset_pods or []],
-                "nodes": nodes or [],
-                "claimDrivers": claim_drivers or {},
-                "policy": _policy_wire(policy),
-            }
-        )
-        response = msgpack.unpackb(self._solve_classes(request, timeout=timeout))
-        cursors = [0] * len(members)
-
-        def take(counts) -> List[int]:
-            indices: List[int] = []
-            for c, n in counts:
-                start = cursors[c]
-                indices.extend(members[c][start : start + n])
-                cursors[c] = start + n
-            return indices
-
-        return {
-            "newNodes": [
+            with tracing.span("client.classify", pods=len(pods)) as sp:
+                by_sig: Dict[tuple, List[int]] = {}
+                for i, pod in enumerate(pods):
+                    by_sig.setdefault(_class_signature(pod), []).append(i)
+                members = list(by_sig.values())
+                sp.set(classes=len(members))
+        with tracing.span("client.pack", classes=len(members)) as sp:
+            request = msgpack.packb(
                 {
-                    "provisioner": n["provisioner"],
-                    "instanceTypes": n["instanceTypes"],
-                    "zones": n["zones"],
-                    "requests": n["requests"],
-                    "podIndices": take(n["classCounts"]),
+                    "podClasses": [
+                        {"pod": codec.pod_to_dict(pods[idxs[0]]), "count": len(idxs)}
+                        for idxs in members
+                    ],
+                    "provisioners": [codec.provisioner_to_dict(p) for p in provisioners],
+                    "daemonsetPods": [codec.pod_to_dict(p) for p in daemonset_pods or []],
+                    "nodes": nodes or [],
+                    "claimDrivers": claim_drivers or {},
+                    "policy": _policy_wire(policy),
                 }
-                for n in response["newNodes"]
-            ],
-            "existingAssignments": {
-                name: take(counts)
-                for name, counts in response["existingAssignments"].items()
-            },
-            "failedPodIndices": take(response["failedClassCounts"]),
-            "residualPodIndices": take(response.get("residualClassCounts", [])),
-            "existingCommittedZones": response.get("existingCommittedZones", {}),
-        }
+            )
+            sp.set(request_bytes=len(request))
+        response = self._classes_rpc(request, timeout)
+        with tracing.span("client.expand", classes=len(members)) as sp:
+            cursors = [0] * len(members)
+
+            def take(counts) -> List[int]:
+                indices: List[int] = []
+                for c, n in counts:
+                    start = cursors[c]
+                    indices.extend(members[c][start : start + n])
+                    cursors[c] = start + n
+                return indices
+
+            expanded = {
+                "newNodes": [
+                    {
+                        "provisioner": n["provisioner"],
+                        "instanceTypes": n["instanceTypes"],
+                        "zones": n["zones"],
+                        "requests": n["requests"],
+                        "podIndices": take(n["classCounts"]),
+                    }
+                    for n in response["newNodes"]
+                ],
+                "existingAssignments": {
+                    name: take(counts)
+                    for name, counts in response["existingAssignments"].items()
+                },
+                "failedPodIndices": take(response["failedClassCounts"]),
+                "residualPodIndices": take(response.get("residualClassCounts", [])),
+                "existingCommittedZones": response.get("existingCommittedZones", {}),
+            }
+            sp.set(nodes=len(expanded["newNodes"]), pods=sum(cursors))
+        return expanded
+
+    def _classes_rpc(self, request: bytes, timeout: float) -> Dict:
+        """One ``/SolveClasses`` round trip: the gRPC call (hop, framing and
+        the server's handler inside it), then the reply's msgpack."""
+        with tracing.span("client.rpc", request_bytes=len(request)) as sp:
+            reply = self._solve_classes(request, timeout=timeout)
+            sp.set(reply_bytes=len(reply))
+        with tracing.span("client.unpack", reply_bytes=len(reply)):
+            return msgpack.unpackb(reply)
 
     def solve_tenant_classes(
         self,
@@ -1727,26 +1778,32 @@ class SnapshotSolverClient:
         ``retry-after-s=`` hint (service.tenant.parse_retry_after)."""
         self._client_chaos("SolveClasses")
         envelope = dict(tenant)
+        # read BEFORE any span of this method opens: the context stamped
+        # into the envelope is the CALLER's.  Under a span of our own the
+        # server's solve.tenant would adopt a trace no caller holds and
+        # leave the trace of whoever wraps the handler.
         ctx = tracing.wire_context()
         if ctx is not None and "trace" not in envelope:
             # stamp the caller's active span so the server-side segment
             # joins the same trace tree (schema-additive; SCHEMA.md)
             envelope["trace"] = ctx
-        request = msgpack.packb(
-            {
-                "podClasses": [
-                    {"pod": codec.pod_to_dict(pod), "count": int(count)}
-                    for pod, count in pod_classes
-                ],
-                "provisioners": [codec.provisioner_to_dict(p) for p in provisioners],
-                "daemonsetPods": [codec.pod_to_dict(p) for p in daemonset_pods or []],
-                "nodes": nodes or [],
-                "claimDrivers": claim_drivers or {},
-                "policy": _policy_wire(policy),
-                "tenant": envelope,
-            }
-        )
-        return msgpack.unpackb(self._solve_classes(request, timeout=timeout))
+        with tracing.span("client.pack", classes=len(pod_classes)) as sp:
+            request = msgpack.packb(
+                {
+                    "podClasses": [
+                        {"pod": codec.pod_to_dict(pod), "count": int(count)}
+                        for pod, count in pod_classes
+                    ],
+                    "provisioners": [codec.provisioner_to_dict(p) for p in provisioners],
+                    "daemonsetPods": [codec.pod_to_dict(p) for p in daemonset_pods or []],
+                    "nodes": nodes or [],
+                    "claimDrivers": claim_drivers or {},
+                    "policy": _policy_wire(policy),
+                    "tenant": envelope,
+                }
+            )
+            sp.set(request_bytes=len(request))
+        return self._classes_rpc(request, timeout)
 
     def close(self) -> None:
         self.channel.close()

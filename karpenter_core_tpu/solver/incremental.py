@@ -395,82 +395,93 @@ class IncrementalSolveSession:
         always settled inline — the serial loop bit-for-bit."""
         from karpenter_core_tpu.solver.tpu import SOLVER_DISPATCH
 
-        # settle the in-flight deferred tick FIRST: this tick's membership
-        # diff and eviction plan read the bookkeeping that tick rewrites
-        self.settle()
-        # ``deferred`` shapes the RETURN TYPE (a handle); ``pipelined``
-        # whether the tick actually stays in flight — KC_PIPELINE=0 settles
-        # inline, so the handle is just the serial results in a box
-        pipelined = deferred and pipeline_mod.pipeline_enabled()
-        members, by_uid, classes = self._members_of(pods_or_classes)
-        if self._warm is not None:
-            self._absorb_bound({p.uid for p in (bound_pods or [])})
-        from karpenter_core_tpu.policy import planes as policy_planes
+        # everything the tick decides before it touches the solver — the
+        # membership view, the three digests, the diff and the policy's
+        # verdict — is one span (docs/OBSERVABILITY.md)
+        with tracing.span("session.diff") as diff_sp:
+            # settle the in-flight deferred tick FIRST: this tick's membership
+            # diff and eviction plan read the bookkeeping that tick rewrites
+            self.settle()
+            # ``deferred`` shapes the RETURN TYPE (a handle); ``pipelined``
+            # whether the tick actually stays in flight — KC_PIPELINE=0 settles
+            # inline, so the handle is just the serial results in a box
+            pipelined = deferred and pipeline_mod.pipeline_enabled()
+            members, by_uid, classes = self._members_of(pods_or_classes)
+            if self._warm is not None:
+                self._absorb_bound({p.uid for p in (bound_pods or [])})
+            from karpenter_core_tpu.policy import planes as policy_planes
 
-        catalog = store_mod.catalog_digest(
-            self.solver.provisioners, self.solver.instance_types
-        ) + policy_planes.policy_input_digest(
-            # the policy side of the supply: offering prices + interruption
-            # priors + objective knobs + the provider's pending-ICE set.  A
-            # set_price between reconciles (the spot market moving), a weight
-            # change, or a type starting to fail creates flips this digest
-            # and the fallback policy escalates to a full solve — a repair
-            # would otherwise keep optimizing against a stale price/risk
-            # sheet (docs/INCREMENTAL.md "Policy-digest escalation")
-            self.solver.instance_types, getattr(self.solver, "policy", None),
-            provider=getattr(self.solver, "cloud_provider", None),
-        )
-        # the comparison digest excludes bound pods this lineage placed itself
-        # (their binding is the lineage's own work materializing, not a supply
-        # change); the ANCHOR a full solve stores is unfiltered, because a
-        # fresh encode sees — and accounts — every bound pod
-        known = self._warm.materialized if self._warm is not None else ()
-        supply = store_mod.supply_digest(
-            state_nodes,
-            [p for p in (bound_pods or []) if p.uid not in known]
-            if known else bound_pods,
-        ) + catalog
-        supply_anchor = supply if not known else (
-            store_mod.supply_digest(state_nodes, bound_pods) + catalog
-        )
-
-        delta = None
-        if self._warm is not None:
-            delta = diff_members(
-                self._warm.members, members,
-                from_version=self._warm.versioned.version,
-                supply_changed=() if supply == self._warm.supply else ("supply",),
+            catalog = store_mod.catalog_digest(
+                self.solver.provisioners, self.solver.instance_types
+            ) + policy_planes.policy_input_digest(
+                # the policy side of the supply: offering prices + interruption
+                # priors + objective knobs + the provider's pending-ICE set.  A
+                # set_price between reconciles (the spot market moving), a weight
+                # change, or a type starting to fail creates flips this digest
+                # and the fallback policy escalates to a full solve — a repair
+                # would otherwise keep optimizing against a stale price/risk
+                # sheet (docs/INCREMENTAL.md "Policy-digest escalation")
+                self.solver.instance_types, getattr(self.solver, "policy", None),
+                provider=getattr(self.solver, "cloud_provider", None),
             )
-        # mesh-topology watch: the warm carry is sharded for (and its repair
-        # executable keyed on) the topology captured at prepare time — a
-        # KC_SOLVER_MESH flip or a device-count change escalates to full
-        from karpenter_core_tpu.parallel import mesh as mesh_mod
+            # the comparison digest excludes bound pods this lineage placed itself
+            # (their binding is the lineage's own work materializing, not a supply
+            # change); the ANCHOR a full solve stores is unfiltered, because a
+            # fresh encode sees — and accounts — every bound pod
+            known = self._warm.materialized if self._warm is not None else ()
+            supply = store_mod.supply_digest(
+                state_nodes,
+                [p for p in (bound_pods or []) if p.uid not in known]
+                if known else bound_pods,
+            ) + catalog
+            supply_anchor = supply if not known else (
+                store_mod.supply_digest(state_nodes, bound_pods) + catalog
+            )
 
-        mesh_changed = self._warm is not None and (
-            getattr(self._warm.prep, "mesh_axes", None)
-            != mesh_mod.solve_mesh_axes()
-        )
-        # solver-family watch (solver/modes.py): same contract as the mesh —
-        # the anchor records which family it was configured for, a flip
-        # re-anchors so the lineage's carry matches the routed program
-        mode_changed = self._warm is not None and (
-            _resolve_solve_mode(self.solver) != self._warm.solve_mode
-        )
-        mode, reason = self.policy.decide(
-            delta,
-            self._warm.delta_ticks if self._warm is not None else 0,
-            self._warm.n_next - self._warm.initial_slots_used
-            if self._warm is not None else 0,
-            known_classes=self._warm.class_index
-            if self._warm is not None else None,
-            mesh_changed=mesh_changed,
-            mode_changed=mode_changed,
-        )
-        forced = self._forced_reason
-        if forced is not None:
-            # lineage trust override (force_full): full re-anchor, one shot
-            mode, reason = MODE_FULL, forced
-            self._forced_reason = None
+            delta = None
+            if self._warm is not None:
+                delta = diff_members(
+                    self._warm.members, members,
+                    from_version=self._warm.versioned.version,
+                    supply_changed=() if supply == self._warm.supply else ("supply",),
+                )
+            # mesh-topology watch: the warm carry is sharded for (and its repair
+            # executable keyed on) the topology captured at prepare time — a
+            # KC_SOLVER_MESH flip or a device-count change escalates to full
+            from karpenter_core_tpu.parallel import mesh as mesh_mod
+
+            mesh_changed = self._warm is not None and (
+                getattr(self._warm.prep, "mesh_axes", None)
+                != mesh_mod.solve_mesh_axes()
+            )
+            # solver-family watch (solver/modes.py): same contract as the mesh —
+            # the anchor records which family it was configured for, a flip
+            # re-anchors so the lineage's carry matches the routed program
+            mode_changed = self._warm is not None and (
+                _resolve_solve_mode(self.solver) != self._warm.solve_mode
+            )
+            mode, reason = self.policy.decide(
+                delta,
+                self._warm.delta_ticks if self._warm is not None else 0,
+                self._warm.n_next - self._warm.initial_slots_used
+                if self._warm is not None else 0,
+                known_classes=self._warm.class_index
+                if self._warm is not None else None,
+                mesh_changed=mesh_changed,
+                mode_changed=mode_changed,
+            )
+            forced = self._forced_reason
+            if forced is not None:
+                # lineage trust override (force_full): full re-anchor, one shot
+                mode, reason = MODE_FULL, forced
+                self._forced_reason = None
+            diff_sp.set(**{"classes": len(members), "solve.mode": mode})
+            if delta is not None and tracing.enabled():
+                # a pass over the dirty classes: only a live span pays for it
+                diff_sp.set(
+                    arrivals=delta.added_count, departures=delta.evicted_count,
+                    dirty_classes=len(set(delta.added) | set(delta.evicted)),
+                )
 
         try:
             fault = SOLVER_DISPATCH.hit(
@@ -635,6 +646,7 @@ class IncrementalSolveSession:
         ))
         self._undecoded = pending.box
 
+    @tracing.traced("session.adopt")
     def _adopt(self, versioned, prep, outputs, results, members, supply,
                state_nodes, prev_nodes, reason):
         import jax
@@ -664,6 +676,8 @@ class IncrementalSolveSession:
             prep = self.solver.upload_prep(prep)
         index = versioned.index_of()
         row_key = {i: row.key for i, row in enumerate(versioned.rows)}
+        tracing.set_attrs(placed=len(pod_loc), failed=len(failed_pods),
+                          classes=len(row_key))
         self.last_audit_drift_nodes = None
         if prev_nodes is not None and reason.startswith("audit"):
             fresh = int(np.sum(np.sum(assign, axis=0) > 0))
@@ -840,6 +854,7 @@ class IncrementalSolveSession:
     # after dispatch and settles at the next solve's entry, so the stages of
     # consecutive ticks overlap (docs/KERNEL_PERF.md "Layer 7").
 
+    @tracing.traced("session.plan")
     def _delta_plan(self, delta, by_uid):
         """The host-side tick plan: eviction free planes, the delta count
         vector, and the post-tick membership.  None when an unseen class key
@@ -892,6 +907,8 @@ class IncrementalSolveSession:
         for key, uids in delta.added.items():
             members.setdefault(key, []).extend(uids)
         members_after = {k: tuple(v) for k, v in members.items() if v}
+        tracing.set_attrs(evictions=len(evicted_locs), dirty_rows=len(pods_by_root),
+                          placements=int(counts.sum()))
         return {
             "delta": delta, "free_new": free_new, "free_ex": free_ex,
             "evicted_locs": evicted_locs, "pods_by_root": pods_by_root,
@@ -1025,6 +1042,7 @@ class IncrementalSolveSession:
         results.new_nodes = [d for d in results.new_nodes if d.pods]
         return results
 
+    @tracing.traced("session.adopt")
     def _delta_adopt(self, disp, fetched) -> None:
         """Bookkeeping: fold the repair's placements into the lineage.  Runs
         only after the device work succeeded (the ticket's barrier)."""
@@ -1085,6 +1103,8 @@ class IncrementalSolveSession:
         w.n_next = n_next_h
         w.members = plan["members_after"]
         w.delta_ticks += 1
+        tracing.set_attrs(placed=len(loc_d), evicted=len(plan["evicted_locs"]),
+                          failed=len(w.failed_pods), windowed=window is not None)
 
     def _delta_solve(self, delta, by_uid, state_nodes):
         """The serial delta tick, stage order exactly as before the

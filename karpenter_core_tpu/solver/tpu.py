@@ -889,6 +889,9 @@ class TPUSolver:
         "grp_skew", "grp_is_zone", "grp_is_anti", "grp_member",
     )
 
+    # the ``prepare`` stage on the served path (``ops.solve.solve`` opens the
+    # same span on the library path): slot estimate, host planes, padding
+    @tracing.traced("prepare")
     def prepare_encoded(
         self,
         snapshot: EncodedSnapshot,
@@ -924,6 +927,7 @@ class TPUSolver:
                 )
         if n_slots <= 0:
             n_slots = solve_ops.estimate_slots(snapshot)  # snap_slots applied inside
+        tracing.set_attrs(classes=len(snapshot.classes), n_slots=n_slots)
         features = solve_ops.features_with_existing(snapshot, ex_static)
         pad = os.environ.get("KC_TPU_SHAPE_BUCKETS", "1") != "0"
         anchors = None

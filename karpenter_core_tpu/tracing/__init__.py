@@ -1,5 +1,5 @@
 """Tracing: spans over the solve pipeline, a ring-buffer trace store,
-decision audits, and exporters (JSON-lines + Chrome trace-event format).
+decision audits, and the Chrome trace-event exporter.
 
 See docs/OBSERVABILITY.md for the operator surface (``/debug/traces``).
 """
@@ -15,12 +15,13 @@ from karpenter_core_tpu.tracing.trace import (
     disable,
     enable,
     enabled,
+    set_attrs,
     span,
     span_remote,
     traced,
     wire_context,
 )
-from karpenter_core_tpu.tracing.export import from_jsonl, to_chrome, to_jsonl
+from karpenter_core_tpu.tracing.export import to_chrome
 from karpenter_core_tpu.tracing.audit import (
     classify_rejection,
     record_unschedulable,
@@ -39,13 +40,12 @@ __all__ = [
     "disable",
     "enable",
     "enabled",
-    "from_jsonl",
     "record_unschedulable",
     "rejection",
+    "set_attrs",
     "span",
     "span_remote",
     "to_chrome",
-    "to_jsonl",
     "traced",
     "wire_context",
 ]

@@ -1,11 +1,7 @@
-"""Trace exporters: JSON-lines and Chrome trace-event format.
+"""Trace exporter: Chrome trace-event format.
 
-JSON-lines is the archival/round-trip format (one span per line, prefixed by
-one trace-header line) — greppable, streamable, and loadable back into
-``Trace`` objects with ``from_jsonl``.
-
-The Chrome format (``to_chrome``) emits the trace-event JSON that
-``chrome://tracing`` and Perfetto's legacy loader read: complete events
+``to_chrome`` emits the trace-event JSON that ``chrome://tracing`` and
+Perfetto's legacy loader read: complete events
 (``ph: "X"``, microsecond ``ts``/``dur``) per span, instant events
 (``ph: "i"``) per span event, one ``tid`` lane per trace so concurrent
 solves render side by side.
@@ -13,51 +9,9 @@ solves render side by side.
 
 from __future__ import annotations
 
-import json
 from typing import Any, Dict, Iterable, List
 
 from karpenter_core_tpu.tracing.trace import Trace
-
-
-def to_jsonl(trace: Trace) -> str:
-    """One header line + one line per span; ends with a newline."""
-    lines = [
-        json.dumps(
-            {
-                "kind": "trace",
-                "traceId": trace.trace_id,
-                "name": trace.name,
-                "startWall": trace.start_wall,
-                "durationS": trace.duration_s,
-            }
-        )
-    ]
-    for rec in trace.spans:
-        lines.append(json.dumps({"kind": "span", **rec}))
-    return "\n".join(lines) + "\n"
-
-
-def from_jsonl(text: str) -> List[Trace]:
-    """Inverse of ``to_jsonl`` over a concatenation of exported traces."""
-    traces: List[Trace] = []
-    for line in text.splitlines():
-        line = line.strip()
-        if not line:
-            continue
-        rec = json.loads(line)
-        if rec.get("kind") == "trace":
-            traces.append(
-                Trace(
-                    trace_id=rec["traceId"],
-                    name=rec["name"],
-                    start_wall=rec["startWall"],
-                    duration_s=rec["durationS"],
-                )
-            )
-        elif rec.get("kind") == "span" and traces:
-            rec.pop("kind")
-            traces[-1].spans.append(rec)
-    return traces
 
 
 def to_chrome(traces: Iterable[Trace]) -> Dict[str, Any]:

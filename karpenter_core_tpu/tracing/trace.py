@@ -26,6 +26,14 @@ Design constraints:
 Spans also feed ``metrics.registry.SOLVE_STAGE_DURATION`` (one histogram
 time series per span name) with a ``trace_id`` exemplar, so a scrape can
 link a latency outlier back to the exact trace that produced it.
+
+Spans also reach the profiler: while tracing is on, every span holds a
+``jax.profiler.TraceAnnotation`` named ``kc:<span name>`` for its life, on the
+thread that runs it.  Outside a ``jax.profiler`` capture that is a branch in
+C++; inside one the span lands on ``/host:CPU`` on the clock of the device's
+op events, so an idle gap of the chip can be named by the host span that
+covers it.  The prefix keeps a span apart from an annotation of the same name
+that a caller wraps around it.
 """
 
 from __future__ import annotations
@@ -47,6 +55,12 @@ _finish_lock = threading.Lock()
 _current: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
     "kc_tracing_current", default=None
 )
+
+# what a span is named on the profiler's host plane: ``kc:<span name>``
+ANNOTATION_PREFIX = "kc:"
+# jax.profiler.TraceAnnotation, resolved by the first enabled span (this
+# module imports without JAX); a process without JAX annotates nothing
+_annotation = None
 
 # span-event payloads are debug artifacts, not a database: cap the per-span
 # event count so a pathological solve (50k failed pods) cannot balloon a trace
@@ -168,6 +182,36 @@ class Span:
             )
 
 
+def _resolve_annotation():
+    global _annotation
+    try:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    except Exception:  # noqa: BLE001 - tracing must work without JAX
+        _annotation = contextlib.nullcontext
+    return _annotation
+
+
+@contextlib.contextmanager
+def _running(sp: Span, sync: Any) -> Iterator[Span]:
+    """``sp`` as the current span, under its profiler annotation, until the
+    body ends; the annotation covers the close's ``sync`` wait as the span's
+    duration does."""
+    if sync is not None:
+        sp.sync_on(sync)
+    with (_annotation or _resolve_annotation())(ANNOTATION_PREFIX + sp.name):
+        token = _current.set(sp)
+        try:
+            yield sp
+        except BaseException as e:
+            sp.attrs.setdefault("error", f"{type(e).__name__}: {e}"[:200])
+            raise
+        finally:
+            _current.reset(token)
+            sp._finish()
+
+
 class _NoopSpan:
     """The disabled-path span: every method is a cheap no-op."""
 
@@ -199,19 +243,8 @@ def span(name: str, sync: Any = None, **attrs: Any) -> Iterator[object]:
     if not _enabled:
         yield _NOOP
         return
-    parent = _current.get()
-    sp = Span(name, parent, attrs)
-    if sync is not None:
-        sp.sync_on(sync)
-    token = _current.set(sp)
-    try:
+    with _running(Span(name, _current.get(), attrs), sync) as sp:
         yield sp
-    except BaseException as e:
-        sp.attrs.setdefault("error", f"{type(e).__name__}: {e}"[:200])
-        raise
-    finally:
-        _current.reset(token)
-        sp._finish()
 
 
 def current() -> Optional[Span]:
@@ -255,17 +288,8 @@ def span_remote(
     sp = Span(name, None, attrs)
     sp.trace_id = trace_id
     sp.parent_id = str(ctx.get("spanId") or "") or None
-    if sync is not None:
-        sp.sync_on(sync)
-    token = _current.set(sp)
-    try:
+    with _running(sp, sync):
         yield sp
-    except BaseException as e:
-        sp.attrs.setdefault("error", f"{type(e).__name__}: {e}"[:200])
-        raise
-    finally:
-        _current.reset(token)
-        sp._finish()
 
 
 def add_event(name: str, **attrs: Any) -> None:
@@ -273,6 +297,14 @@ def add_event(name: str, **attrs: Any) -> None:
     sp = _current.get()
     if sp is not None:
         sp.event(name, **attrs)
+
+
+def set_attrs(**attrs: Any) -> None:
+    """Set attributes on the active span (no-op without one): how a function
+    under ``@traced`` records the counts of the work it did."""
+    sp = _current.get()
+    if sp is not None:
+        sp.set(**attrs)
 
 
 def traced(name: str, **attrs: Any):
@@ -309,16 +341,6 @@ class Trace:
             "durationS": self.duration_s,
             "spans": self.spans,
         }
-
-    @classmethod
-    def from_dict(cls, data: Dict[str, Any]) -> "Trace":
-        return cls(
-            trace_id=data["traceId"],
-            name=data["name"],
-            start_wall=data["startWall"],
-            duration_s=data["durationS"],
-            spans=list(data.get("spans") or ()),
-        )
 
     def stage_durations(self) -> Dict[str, float]:
         """span name -> summed duration (seconds) across the trace."""

@@ -112,3 +112,17 @@ def _retrace_budget(request):
             "raising the budget (docs/ANALYSIS.md, docs/KERNEL_PERF.md).",
             pytrace=False,
         )
+
+
+@pytest.fixture()
+def traced():
+    """Tracing on, store clean; restores the disabled default afterwards."""
+    from karpenter_core_tpu import tracing
+
+    capacity = tracing.TRACE_STORE.capacity
+    tracing.TRACE_STORE.clear()
+    tracing.enable()
+    yield
+    tracing.disable()
+    tracing.TRACE_STORE.clear()
+    tracing.TRACE_STORE.set_capacity(capacity)
