@@ -355,6 +355,17 @@ SOAK_SLO_VIOLATIONS = Counter(
 )
 REGISTRY.register(SOAK_SLO_VIOLATIONS)
 
+# Slot-exhaustion retries (solver/tpu.py ``grow_until_fits``): the node-slot
+# estimate (ops/solve.estimate_slots) is optimistic, and each count here is
+# one cold solve run again at twice the slots.  0 is the steady state; a
+# rising count says the estimate under-counts this cluster's batches.
+SOLVER_SLOT_RETRIES = Counter(
+    NAMESPACE + "_solver_slot_retries_total",
+    "Cold solves run again at twice the node slots because the scan took "
+    "every slot it was given and still failed pods.",
+)
+REGISTRY.register(SOLVER_SLOT_RETRIES)
+
 # Policy-objective surface (docs/POLICY.md): the latest solve's selected
 # fleet cost, raw offering prices ({view="price"}) and risk-weighted
 # expectation ({view="expected"}), set by TPUSolver decode when the

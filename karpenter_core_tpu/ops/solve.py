@@ -2315,29 +2315,129 @@ def prepare_host(snapshot: EncodedSnapshot):
     return cls, statics_arrays, key_has_bounds
 
 
+def _distinct_rows(*planes: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """(ids i64[C], first i64[U]): rows that are byte-identical in every given
+    [C, ...] plane share an id, numbered in order of first appearance, and
+    ``first[u]`` is the first row of id ``u``."""
+    n = planes[0].shape[0]
+    rows = np.concatenate(
+        [np.ascontiguousarray(p).reshape(n, -1).view(np.uint8) for p in planes],
+        axis=1,
+    )
+    seen: dict = {}
+    ids = np.fromiter(
+        (seen.setdefault(r.tobytes(), len(seen)) for r in rows),
+        dtype=np.int64, count=n,
+    )
+    return ids, np.unique(ids, return_index=True)[1]
+
+
 def estimate_slots(snapshot: EncodedSnapshot) -> int:
-    """Optimistic node-count estimate: per class, best pods-per-node over the
-    catalog, plus slack for zone phases; rounded up to a power of two for
-    compile-cache friendliness."""
-    # zone-phase slack scales with the PADDED class count (the bucket the
-    # executable is compiled for), not the actual one — otherwise a one-class
-    # wobble in the pod mix moves the total across a power-of-two boundary
-    # and recompiles an otherwise-identical program (VERDICT r2 #3)
-    total = 16 + bucket(len(snapshot.classes)) * snapshot.cls_zone.shape[1]
-    alloc = snapshot.it_alloc  # [I, R]
-    for c, cls in enumerate(snapshot.classes):
-        size = snapshot.cls_requests[c]  # [R]
+    """The node slots N the scan is compiled for: the nodes this batch can
+    open, read off the snapshot's planes, rounded up to a power of two.
+
+    A scan step rewrites the whole NodeState, so it costs in proportion to N
+    whether a slot is used or not; an N too small costs one more solve (the
+    callers double N while ``TPUSolver.fetch_exhausted``), never a pod.  So
+    the terms follow what ``_phase`` / ``committal_block`` can open and no
+    more.  With ``share[c] = count[c] / pods of c that fit its first viable
+    template's roomiest type``:
+
+    - **resources**: classes whose requirement planes are identical certainly
+      merge onto one another's nodes, and every fill takes the emptiest open
+      node first, so such a *pool* opens ``ceil(sum of its shares)`` nodes —
+      one round-up a pool, not one a class.  Classes with different planes
+      may be unable to share a node (disjoint selectors, taints): each such
+      pool rounds up on its own.  That is read from the planes, not a flag.
+    - **zone phases**: a phase restricted to zone z fills the open nodes whose
+      zone mask still admits z — whichever class or group committed them —
+      emptiest first, and a fresh node it opens is the first candidate of the
+      next class placed in z.  So what zone committal strands is one partial
+      node a zone *per pool*, not per class nor per topology group: ``Z`` for
+      each pool that holds a class with a zone phase (spread, affinity, anti).
+    - **hostname caps**: a hostname spread (``skew`` a node) or anti-affinity
+      (one a node) caps the group's own members (classes that own the group
+      and match its selector), so group g needs ``ceil(members / cap)`` nodes
+      however small the pods; host ports cap a port's users at one a node the
+      same way.  Groups that can share nodes take the **largest** need, not
+      the sum: the nodes the largest group opened (one pod each) are open to
+      every other group and to every uncapped class.  Groups that **exclude**
+      one another — some class capped by one carries the label an
+      anti-affinity term of a class capped by the other selects — cannot
+      share a node, and their needs **add**.
+    - the three **add**: full nodes cannot take the capped pods, so a group
+      that arrives after R nodes have filled still opens its own.
+
+    Allocatable is taken before daemonset overhead and existing nodes are not
+    counted; the constant 16 and the power of two are the headroom.  A
+    one-class or few-pod wobble moves the sum by a node or two, well inside
+    the power of two, and ``compilecache.snap_slots`` absorbs a fall."""
+    count = np.asarray(snapshot.cls_count, dtype=np.int64)
+    n_classes = count.shape[0]
+    total = 16
+    if n_classes and snapshot.tmpl_it.shape[0] and count.any():
+        rows = np.arange(n_classes)
+        live = count > 0
+        groups = np.asarray(snapshot.cls_groups)
+        member = np.asarray(snapshot.grp_member)
+        n_groups = member.shape[1] - 1  # the last row is the dummy "none"
+
+        # pods of a class on a fresh node: the first template that tolerates
+        # it and has a type it fits (``_phase``'s t_star), at its best type
+        kind, first = _distinct_rows(snapshot.cls_requests, snapshot.cls_it)
+        size = snapshot.cls_requests[first][:, None, :]  # [U, 1, R]
         with np.errstate(divide="ignore", invalid="ignore"):
-            per = np.floor(np.where(size > 0, alloc / np.maximum(size, 1e-9), np.inf))
-        per_it = np.min(np.where(np.isfinite(per), per, np.inf), axis=-1)
-        best = np.max(per_it) if per_it.size else 0
-        host_cap = float(UNLIMITED)
-        if cls.host_spread is not None:
-            host_cap = float(cls.host_spread.skew)
-        if cls.host_anti is not None:
-            host_cap = 1.0
-        best = max(1.0, min(best, host_cap))
-        total += int(np.ceil(float(snapshot.cls_count[c]) / best))
+            fit = np.where(
+                size > 0,
+                np.floor((snapshot.it_alloc[None] + 1e-4) / np.maximum(size, 1e-9)),
+                np.inf,
+            ).min(axis=-1)  # [U, I]
+        fit = np.where(snapshot.cls_it[first], np.minimum(fit, UNLIMITED), 0)
+        catalog, first_tmpl = _distinct_rows(snapshot.tmpl_it)  # mostly one
+        per_catalog = np.stack(
+            [np.where(snapshot.tmpl_it[t], fit, 0).max(axis=1) for t in first_tmpl],
+            axis=1,
+        )  # [U, catalogs]
+        per_tmpl = np.where(snapshot.cls_tol, per_catalog[kind][:, catalog], 0)
+        best = per_tmpl[rows, np.argmax(per_tmpl > 0, axis=1)]  # 0: fits nowhere
+        share = np.where(best > 0, count / np.maximum(best, 1), 0.0)
+
+        pool, _ = _distinct_rows(
+            snapshot.cls_mask, snapshot.cls_defined, snapshot.cls_negative,
+            snapshot.cls_gt, snapshot.cls_lt, snapshot.cls_zone,
+            snapshot.cls_ct, snapshot.cls_it, snapshot.cls_tol,
+        )
+        total += int(np.ceil(np.bincount(pool, weights=share) - 1e-6).sum())
+        zoned = live & (groups[:, [0, 2, 4]] < n_groups).any(axis=1)
+        total += snapshot.cls_zone.shape[1] * np.unique(pool[zoned]).size
+
+        need = np.zeros(n_groups + 1)
+        capped = np.zeros((n_classes, n_groups + 1), dtype=bool)
+        for slot, cap in ((1, np.maximum(snapshot.grp_skew, 1)), (5, 1)):
+            own = groups[:, slot]
+            self_member = member[rows, own] & live  # the dummy row matches none
+            capped[rows, own] |= self_member
+            need += np.ceil(
+                np.bincount(own, weights=self_member * count, minlength=n_groups + 1)
+                / cap
+            )
+        need, capped = need[:n_groups], capped[:, :n_groups]
+        exclusive = np.zeros(n_groups, dtype=bool)
+        anti = groups[:, 5]
+        owners = live & (anti < n_groups)
+        if owners.any():
+            # repels[a, b]: b carries the label a's hostname anti term selects
+            repels = member[:, anti].T & owners[:, None] & live[None, :]
+            apart = (repels | repels.T).astype(np.float32)
+            units = capped.astype(np.float32)
+            clash = units.T @ apart @ units > 0  # [G, G]
+            np.fill_diagonal(clash, False)  # within a group: its own cap
+            exclusive = clash.any(axis=1)
+        ports = count @ np.asarray(snapshot.cls_ports, dtype=np.int64)
+        total += int(
+            need[exclusive].sum()
+            + max(need[~exclusive].max(initial=0), ports.max(initial=0))
+        )
     estimate = int(2 ** np.ceil(np.log2(max(total, 16))))
     # hysteresis at the shared derivation point so every caller (provisioning
     # solve, consolidation sweep, mesh studies) reuses covering executables

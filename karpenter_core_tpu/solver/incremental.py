@@ -568,8 +568,6 @@ class IncrementalSolveSession:
 
     def _full_solve(self, pods_or_classes, members, state_nodes, bound_pods,
                     supply, reason, deferred: bool = False):
-        import jax
-
         solver = self.solver
         prev_nodes = self.node_count() if self._warm is not None else None
         try:
@@ -580,7 +578,10 @@ class IncrementalSolveSession:
             else:
                 snapshot = solver.encode(pods_or_classes, state_nodes, bound_pods)
             versioned = self.store.commit(snapshot, supply=supply)
-            prep = solver.prepare_encoded(snapshot, state_nodes, bound_pods)
+            prep = solver.prepare_encoded(
+                snapshot, state_nodes, bound_pods,
+                max(solve_ops.estimate_slots(snapshot), self._continued_slots(reason)),
+            )
             run = self._run_prepared or solver.run_prepared
             outputs = run(prep)
             if deferred and pipeline_mod.pipeline_enabled():
@@ -603,16 +604,10 @@ class IncrementalSolveSession:
                     ),
                 )
                 return box
-            from karpenter_core_tpu.utils import watchdog
-
-            n_next_h, failed_h = watchdog.run(
-                "pipeline.fetch", jax.device_get,
-                (outputs.state.n_next, outputs.failed), key="anchor-check",
+            outputs, ticket = solver.grow_until_fits(prep, outputs, run=run)
+            results = solver.decode(
+                snapshot, outputs, state_nodes or [], fetched=ticket
             )
-            slots = outputs.assign.shape[1]
-            if int(np.sum(failed_h)) > 0 and int(n_next_h) >= slots:
-                outputs = run(prep, n_slots=slots * 2)
-            results = solver.decode(snapshot, outputs, state_nodes or [])
         except Exception:
             self._warm = None  # a half-built lineage must not seed repairs
             raise
@@ -620,23 +615,36 @@ class IncrementalSolveSession:
                     state_nodes, prev_nodes, reason)
         return results
 
+    def _continued_slots(self, reason: str) -> int:
+        """The least slots for an anchor that CONTINUES this lineage's
+        population (an audit, or a repair that ran out of room): a quarter
+        over what the lineage had opened, as a power of two; 0 where the
+        anchor starts over.  Repairs open fresh slots faster than departures
+        empty whole nodes, so ``n_next`` creeps between audits (PERF.md §6,
+        PR 26: +26 a cycle at 50k pods); an estimate that fits the answer
+        with little to spare would otherwise send such a tenant back here
+        every few ticks."""
+        w = self._warm
+        if w is None or not (reason.startswith("audit") or reason == "slots-exhausted"):
+            return 0
+        return 1 << (w.n_next + w.n_next // 4 - 1).bit_length() if w.n_next else 0
+
     def _settle_full(self, pending: _PendingTick) -> None:
         """Retire a deferred anchor: completion barrier, the slot-exhaustion
-        retry (synchronous, rare), adoption; decode stays deferred to the
+        retries (synchronous, rare), adoption; decode stays deferred to the
         handle's ``result()``."""
         f = pending.data
-        from karpenter_core_tpu.solver.tpu import TPUSolver
 
-        fetched = f["ticket"].wait()
-        slots = f["outputs"].assign.shape[1]
-        if TPUSolver.fetch_exhausted(fetched, slots):
-            outputs = f["run"](f["prep"], n_slots=slots * 2)
-            ticket = f["solver"].begin_fetch(outputs, ring=self._staging)
-            # adopt the retry's ticket BEFORE its barrier: a wait() that
+        def adopt(outputs, ticket):
+            # a retry's ticket is adopted BEFORE its barrier: a wait() that
             # fails must leave THIS ticket reachable for the settle error
             # path's invalidate, not leak it behind the consumed original
             f["outputs"], f["ticket"] = outputs, ticket
-            ticket.wait()
+
+        f["solver"].grow_until_fits(
+            f["prep"], f["outputs"], ticket=f["ticket"], run=f["run"],
+            ring=self._staging, adopt=adopt,
+        )
         self._adopt(
             f["versioned"], f["prep"], f["outputs"], None, f["members"],
             f["supply"], f["state_nodes"], f["prev_nodes"], f["reason"],

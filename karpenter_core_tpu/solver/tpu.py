@@ -10,6 +10,7 @@ with the bin-pack running as a batch tensor program on the TPU.
 
 from __future__ import annotations
 
+import logging
 import os
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional
@@ -21,6 +22,7 @@ from karpenter_core_tpu import chaos, tracing
 from karpenter_core_tpu.apis.objects import Pod
 from karpenter_core_tpu.apis.v1alpha5 import Provisioner, order_by_weight
 from karpenter_core_tpu.cloudprovider import CloudProvider, InstanceType
+from karpenter_core_tpu.metrics.registry import SOLVER_SLOT_RETRIES
 from karpenter_core_tpu.models.snapshot import (
     EncodedSnapshot,
     KernelUnsupported,
@@ -34,6 +36,7 @@ from karpenter_core_tpu.solver.machinetemplate import MachineTemplate
 from karpenter_core_tpu.solver.scheduler import _daemon_overhead
 from karpenter_core_tpu.utils import resources as resources_util
 
+log = logging.getLogger(__name__)
 
 # solver.dispatch: faults device-backend work at kernel dispatch — here and in
 # solver/incremental.py (which imports this Point).  Error kinds surface as
@@ -1213,17 +1216,44 @@ class TPUSolver:
             raise RuntimeError(fault.describe())
 
         prep = self.prepare_encoded(snapshot, state_nodes, bound_pods, n_slots)
-        outputs = self.run_prepared(prep)
-        # slot exhaustion: retry once with double capacity.  ONE ticket
-        # serves both the exhaustion check and decode (one device→host
-        # round trip instead of fetching n_next/failed twice).
-        ticket = self.begin_fetch(outputs)
-        fetched = ticket.wait()
-        slots = outputs.assign.shape[1]
-        if self.fetch_exhausted(fetched, slots):
-            outputs = self.run_prepared(prep, n_slots=slots * 2)
-            ticket = self.begin_fetch(outputs)
+        # ONE ticket serves both the exhaustion check and decode (one
+        # device→host round trip instead of fetching n_next/failed twice)
+        outputs, ticket = self.grow_until_fits(prep, self.run_prepared(prep))
         return self.decode(snapshot, outputs, state_nodes or [], fetched=ticket)
+
+    def grow_until_fits(self, prep: SolvePrep, outputs, ticket=None, run=None,
+                        ring=None, adopt=None):
+        """The slot-exhaustion loop every cold solve shares (here, the
+        session's anchor and its deferred settle): while the scan failed pods
+        with every slot taken (``fetch_exhausted``), solve again at twice the
+        slots.  ``estimate_slots`` is optimistic by design, so a wrong
+        estimate costs a second solve, never a failed pod; the loop ends at
+        the latest once the slots reach the pod count — a node a pod is all
+        any batch can open.  Returns ``(outputs, ticket)`` with the ticket's
+        barrier passed.  ``ticket`` is the fetch already begun on ``outputs``
+        (one is begun here otherwise), ``run`` the dispatch (default
+        ``run_prepared``), ``ring`` the staging ring of the retries' tickets,
+        and ``adopt(outputs, ticket)`` is called with each retry's pair BEFORE
+        its barrier, so a caller that invalidates its ticket on error never
+        loses the live one behind a consumed original."""
+        run = run or self.run_prepared
+        if ticket is None:
+            ticket = self.begin_fetch(outputs, ring=ring)
+        while True:
+            slots = outputs.assign.shape[1]
+            # the pod count is read only once a solve ran out: the planes may
+            # live on the device (KC_ENCODE_DEVICE_FINISH)
+            if not self.fetch_exhausted(ticket.wait(), slots) or slots >= int(
+                np.sum(np.asarray(prep.cls.count))
+            ):
+                return outputs, ticket
+            SOLVER_SLOT_RETRIES.inc()
+            log.info("solve ran out of node slots at %d: again at %d",
+                     slots, slots * 2)
+            outputs = run(prep, n_slots=slots * 2)
+            ticket = self.begin_fetch(outputs, ring=ring)
+            if adopt is not None:
+                adopt(outputs, ticket)
 
     def decode(
         self,
@@ -1239,6 +1269,9 @@ class TPUSolver:
                 new_nodes=len(results.new_nodes),
                 failed=len(results.failed_pods),
                 residual=len(results.spread_residual_pods),
+                # beside ``n_slots`` on ``prepare``: occupancy = used / allocated
+                slots_used=results.n_slots_used,
+                n_slots=int(outputs.assign.shape[1]),
             )
             return results
 
