@@ -284,11 +284,27 @@ def _isum(x: jnp.ndarray, statics: "Statics") -> jnp.ndarray:
     return x
 
 
-def _water_fill(count0: jnp.ndarray, allowed: jnp.ndarray, m: jnp.ndarray) -> jnp.ndarray:
+def _water_fill(
+    count0: jnp.ndarray,
+    allowed: jnp.ndarray,
+    m: jnp.ndarray,
+    ex_cum: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
     """i32[Z] quotas: distribute m pods over allowed zones, always filling the
     lowest-count zone first — the telescoped form of the reference's per-pod
     min-domain selection (topologygroup.go:155-182; maxSkew ≥ 1 guarantees the
     min-count zone is always admissible so skew never blocks the min choice).
+
+    Once the filled zones stand level, the pods left over go one each to the
+    zones in the order of their counts, then of their index.  With ``ex_cum``
+    they go to the zones the reference would reach first: it tries existing
+    nodes in index order before any new node (scheduler.go:176-180), so among
+    zones tied at the minimum the one whose first existing node with intake
+    left comes earliest takes the next pod.  ``ex_cum`` i32[E, Z] is the
+    running sum, in node order, of each existing node's intake for the class
+    in its zone: a zone's first node with intake left is where the sum passes
+    what the level fill gives the zone.  Zones with no such node keep the
+    order above among themselves, after the others.
     """
     z = count0.shape[0]
     c = jnp.where(allowed, count0.astype(jnp.float32), BIG)
@@ -309,12 +325,28 @@ def _water_fill(count0: jnp.ndarray, allowed: jnp.ndarray, m: jnp.ndarray) -> jn
     k_count = (k_star + 1).astype(jnp.float32)
     level = base_level + jnp.floor(rem / k_count)
     leftover = rem - jnp.floor(rem / k_count) * k_count
-    # zones among the k* lowest get filled to `level`, the first `leftover`
-    # (in sorted order) get one extra
-    in_fill = jnp.arange(z) <= k_star
-    extra = (jnp.arange(z) < leftover).astype(jnp.float32)
-    final_sorted = jnp.where(in_fill, jnp.maximum(s, level + extra), s)
-    final = jnp.zeros_like(c).at[order].set(final_sorted)
+    # zones among the k* lowest get filled to `level`; `leftover` of them get
+    # one extra: the first in sorted order, or by node order (docstring)
+    if ex_cum is None:
+        in_fill = jnp.arange(z) <= k_star
+        extra = (jnp.arange(z) < leftover).astype(jnp.float32)
+        final_sorted = jnp.where(in_fill, jnp.maximum(s, level + extra), s)
+        final = jnp.zeros_like(c).at[order].set(final_sorted)
+    else:
+        pos = jnp.zeros(z, dtype=jnp.int32).at[order].set(jnp.arange(z, dtype=jnp.int32))
+        in_fill = pos <= k_star
+        base = jnp.where(in_fill, jnp.maximum(c, level), c)
+        given = jnp.where(allowed, base - c, 0.0).astype(jnp.int32)
+        spare = ex_cum > given[None, :]  # [E, Z]
+        first = jnp.where(
+            jnp.any(spare, axis=0), jnp.argmax(spare, axis=0), ex_cum.shape[0]
+        ).astype(jnp.int32)
+        ahead = in_fill[None, :] & (
+            (first[None, :] < first[:, None])
+            | ((first[None, :] == first[:, None]) & (pos[None, :] < pos[:, None]))
+        )  # [Z, Z]: zone j takes a left-over pod before zone i
+        extra = in_fill & (jnp.sum(ahead, axis=1) < leftover)
+        final = base + extra.astype(jnp.float32)
     quota = jnp.where(allowed, final - c, 0.0)
     return jnp.maximum(quota, 0.0).astype(jnp.int32)
 
@@ -1425,11 +1457,12 @@ def _class_step(
         # one zone would under-estimate the others and under-place with no
         # detectable signal — the host can commit such a node to whichever
         # zone the fill needs.
-        ex_cap_z = jnp.sum(
-            jnp.minimum(ex_cap_spread, m)[:, None]
-            * ex_prep.zone_full.astype(jnp.int32),
-            axis=0,
-        )  # i32[Z]
+        # (the second bound keeps the running sum over nodes inside int32)
+        ex_cap_ez = (
+            jnp.minimum(jnp.minimum(ex_cap_spread, m), (2**31 - 1) // n_ex)[:, None]
+            * ex_prep.zone_full.astype(jnp.int32)
+        )  # i32[E, Z]
+        ex_cap_z = jnp.sum(ex_cap_ez, axis=0)  # i32[Z]
         fillable = tmpl_offers | (ex_cap_z > 0)
 
     # -- zone spread phases (one committed zone per phase) --------------------
@@ -1455,9 +1488,13 @@ def _class_step(
         quotas = jnp.zeros(n_zones, dtype=jnp.int32)
         sat = jnp.zeros(n_zones, dtype=bool)
         m_rem = m
+        # which tied zone the reference reaches first (_water_fill), in the
+        # first round: a later one runs only once a zone that no template
+        # offers ran out of existing intake, and keeps the order of the counts
+        ex_cum_z = jnp.cumsum(ex_cap_ez, axis=0)
         # worst case: one round per sequentially-saturating finite-cap zone,
         # plus a final redistribution round for the unbounded zones
-        for _ in range(n_zones + 1):
+        for fill_round in range(n_zones + 1):
             counts_now = counts_zs + quotas
             min_frozen = jnp.min(jnp.where(unreachable | sat, counts_now, BIGI))
             skew_cap = jnp.clip(min_frozen + skew_zs - counts_now, 0, UNLIMITED)
@@ -1466,7 +1503,9 @@ def _class_step(
             # level where the nearest capacity-bounded active zone saturates;
             # fills stop there so its frozen count bounds the next round
             lvl_sat = jnp.min(jnp.where(active & finite_cap, counts_now + cap_rem, BIGI))
-            q = _water_fill(counts_now, active, m_rem)
+            q = _water_fill(
+                counts_now, active, m_rem, ex_cum_z if fill_round == 0 else None
+            )
             q = jnp.minimum(q, jnp.clip(lvl_sat - counts_now, 0, UNLIMITED))
             q = jnp.minimum(q, jnp.minimum(skew_cap, cap_rem))
             q = jnp.where(active, q, 0)
@@ -1575,9 +1614,18 @@ def _class_step(
     if ft.zone_affinity:
         bootstrap_allowed = allowed_zone & fillable
         nonzero_zones = allowed_zone & (zone_fwd[g_zaf] > 0)
+        # the reference tries existing nodes in index order before any new
+        # node, and its bootstrap admits the zone of whichever node it is
+        # trying (topologygroup.go:210-231): the first existing node with
+        # intake for the class names the zone; with none, the first allowed
+        ex_zone_ok = ex_prep.zone_full & bootstrap_allowed[None, :]  # [E, Z]
+        ex_boot = (ex_cap_spread > 0) & jnp.any(ex_zone_ok, axis=-1)  # [E]
+        boot_from = jnp.where(
+            jnp.any(ex_boot), ex_zone_ok[jnp.argmax(ex_boot)], bootstrap_allowed
+        )
         bootstrap_zone = (
             jnp.zeros(n_zones, dtype=bool)
-            .at[jnp.argmax(bootstrap_allowed)]
+            .at[jnp.argmax(boot_from)]
             .set(jnp.any(bootstrap_allowed) & member_row[g_zaf])
         )
         zone_aff_restrict = jnp.where(
@@ -2368,8 +2416,15 @@ def estimate_slots(snapshot: EncodedSnapshot) -> int:
     - the three **add**: full nodes cannot take the capped pods, so a group
       that arrives after R nodes have filled still opens its own.
 
-    Allocatable is taken before daemonset overhead and existing nodes are not
-    counted; the constant 16 and the power of two are the headroom.  A
+    Allocatable is taken before daemonset overhead; the constant 16 and the
+    power of two are the headroom.  Existing nodes are NOT counted (unchanged
+    by PR 27, which first measured it): the estimate sizes the new-node slots
+    as if the cluster were empty, so a backlog that a live cluster absorbs
+    whole still scans over every slot an empty one would open — at 10 000
+    pending pods against 5 000 nodes 60 % full, 512 asked and 0 used (the
+    ``n_slots`` / ``slots_used`` counters on ``prepare`` / ``decode``).  Safe
+    (too many slots cost time, never a pod); subtracting the intake of the
+    existing nodes is a ``perf_opt`` of its own, with that cell to claim in.  A
     one-class or few-pod wobble moves the sum by a node or two, well inside
     the power of two, and ``compilecache.snap_slots`` absorbs a fall."""
     count = np.asarray(snapshot.cls_count, dtype=np.int64)

@@ -920,18 +920,25 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
         state_nodes = []
         bound = []
         node_pods = {}
-        for n in req.get("nodes", []):
-            state_node = StateNode(codec.node_from_dict(n["node"]), resolver)
-            for driver, limit in (n.get("volumeLimits") or {}).items():
-                state_node._volume_limits[driver] = int(limit)
-            pods_here = []
-            for p in n.get("pods", []):
-                pod = codec.pod_from_dict(p)
-                state_node.update_for_pod(pod)
-                bound.append(pod)
-                pods_here.append(pod)
-            node_pods[state_node.node.name] = pods_here
-            state_nodes.append(state_node)
+        nodes = req.get("nodes") or []
+        if nodes:
+            # the cluster as shipped: a StateNode per node, a Pod and an
+            # update_for_pod per pod bound to it — one span whatever the
+            # size, and none where no cluster is shipped
+            with tracing.span("service.decode_nodes", state_nodes=len(nodes)) as sp:
+                for n in nodes:
+                    state_node = StateNode(codec.node_from_dict(n["node"]), resolver)
+                    for driver, limit in (n.get("volumeLimits") or {}).items():
+                        state_node._volume_limits[driver] = int(limit)
+                    pods_here = []
+                    for p in n.get("pods", []):
+                        pod = codec.pod_from_dict(p)
+                        state_node.update_for_pod(pod)
+                        bound.append(pod)
+                        pods_here.append(pod)
+                    node_pods[state_node.node.name] = pods_here
+                    state_nodes.append(state_node)
+                sp.set(bound_pods=len(bound))
         return provisioners, daemonset_pods, state_nodes, bound, resolver, node_pods
 
     @staticmethod

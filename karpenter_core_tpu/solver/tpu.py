@@ -923,8 +923,15 @@ class TPUSolver:
         from karpenter_core_tpu.utils import compilecache
 
         ex_state = ex_static = None
+        pad = os.environ.get("KC_TPU_SHAPE_BUCKETS", "1") != "0"
         if state_nodes:
-            with tracing.span("encode.existing", state_nodes=len(state_nodes)):
+            with tracing.span(
+                "encode.existing", state_nodes=len(state_nodes),
+                bound_pods=len(bound_pods or ()), classes=len(snapshot.classes),
+                # the [E] axis as the kernel will see it (ops.solve.pad_planes)
+                e_padded=solve_ops.bucket(len(state_nodes), floor=8)
+                if pad else len(state_nodes),
+            ):
                 ex_state, ex_static = self.encode_existing(
                     snapshot, state_nodes, bound_pods
                 )
@@ -932,7 +939,6 @@ class TPUSolver:
             n_slots = solve_ops.estimate_slots(snapshot)  # snap_slots applied inside
         tracing.set_attrs(classes=len(snapshot.classes), n_slots=n_slots)
         features = solve_ops.features_with_existing(snapshot, ex_static)
-        pad = os.environ.get("KC_TPU_SHAPE_BUCKETS", "1") != "0"
         anchors = None
         if ex_state is None and pad:
             # the quantize flag rides the anchor tuple: a mid-process flip
@@ -1267,6 +1273,9 @@ class TPUSolver:
             self._apply_policy_selection(snapshot, outputs, results)
             sp.set(
                 new_nodes=len(results.new_nodes),
+                pods_on_existing=sum(
+                    len(placed) for placed in results.existing_assignments.values()
+                ),
                 failed=len(results.failed_pods),
                 residual=len(results.spread_residual_pods),
                 # beside ``n_slots`` on ``prepare``: occupancy = used / allocated
@@ -1366,12 +1375,23 @@ class TPUSolver:
             root_of = list(range(n_classes))
         cursors = [0] * n_classes  # keyed by root index
         assigned_ex_idx: set = set()
+        # the [C, E] plane read once, under a span of its own: with a live
+        # cluster it is the one piece of decode that grows with the nodes
+        # (its bytes came down with the batched fetch above)
+        placed_ex: Dict[int, tuple] = {}
+        if state_nodes:
+            with tracing.span("decode.existing",
+                              existing_bytes=int(assign_ex.nbytes)) as sp:
+                on_ex = assign_ex[:n_classes] > 0
+                for c in np.nonzero(on_ex.any(axis=1))[0].tolist():
+                    ex_idx = np.nonzero(on_ex[c])[0]
+                    placed_ex[c] = (ex_idx.tolist(), assign_ex[c][ex_idx].tolist())
+                sp.set(pods_on_existing=sum(sum(t) for _, t in placed_ex.values()))
         for c, cls in enumerate(snapshot.classes):
             r = root_of[c]
             pods, cursor = snapshot.classes[r].pods, cursors[r]
             # existing-node placements first (they were tried first in-kernel)
-            ex_idx = np.nonzero(assign_ex[c] > 0)[0]
-            for e, take in zip(ex_idx.tolist(), assign_ex[c][ex_idx].tolist()):
+            for e, take in zip(*placed_ex.get(c, ((), ()))):
                 if e < len(state_nodes):
                     name = state_nodes[e].node.name
                     results.existing_assignments.setdefault(name, []).extend(

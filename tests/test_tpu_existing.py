@@ -878,3 +878,105 @@ class TestUnknownZoneNode:
         assert sum(len(n.pods) for n in tpu.new_nodes) == sum(
             len(n.pods) for n in host.new_nodes
         ) == 4
+
+
+class TestNodeOrderBreaksZoneTies:
+    """PR 27: the reference tries existing nodes in index order before any new
+    node, so where several zones tie — the zone-spread pod left over once the
+    zones stand level, the first pod of a zone self-affinity group — the zone
+    of the FIRST existing node that can take the pod wins, not the first zone
+    by name.  With no existing node the order of the zones stands."""
+
+    @staticmethod
+    def _solve_both(make_pending, zones=("test-zone-2", "test-zone-1", "test-zone-3")):
+        from karpenter_core_tpu.solver.builder import build_scheduler
+
+        def build():
+            env = make_environment()
+            env.kube.create(make_provisioner())
+            for i, zone in enumerate(zones):
+                owned_ready_node(env, cpu=8, zone=zone, name=f"ex-{i}")
+            return env, make_pending()
+
+        env, pods = build()
+        host = build_scheduler(
+            env.kube, env.provider, cluster=None, pods=pods,
+            state_nodes=env.cluster.snapshot_nodes(), daemonset_pods=[],
+        ).solve(pods)
+        env, pods = build()
+        tpu = TPUSolver(env.provider, env.kube.list_provisioners()).solve(
+            pods, state_nodes=env.cluster.snapshot_nodes(), bound_pods=env.kube.list_pods(),
+        )
+        host_per_node = {n.name: len(n.pods) for n in host.existing_nodes if n.pods}
+        tpu_per_node = {name: len(p) for name, p in tpu.existing_assignments.items() if p}
+        assert not tpu.failed_pods and not host.failed_pods
+        return host_per_node, tpu_per_node
+
+    @pytest.mark.parametrize("n_pods,expected", [
+        (1, {"ex-0": 1}),  # the first node's zone, test-zone-2, not test-zone-1
+        (2, {"ex-0": 1, "ex-1": 1}),
+        (4, {"ex-0": 2, "ex-1": 1, "ex-2": 1}),
+    ])
+    def test_zone_spread_leftover_follows_the_first_node(self, n_pods, expected):
+        from karpenter_core_tpu.apis.objects import LabelSelector, TopologySpreadConstraint
+
+        sel = LabelSelector(match_labels={"app": "web"})
+
+        def pending():
+            return [
+                make_pod(labels={"app": "web"}, requests={"cpu": "1"}, topology_spread=[
+                    TopologySpreadConstraint(max_skew=1, topology_key=ZONE, label_selector=sel)
+                ])
+                for _ in range(n_pods)
+            ]
+
+        host, tpu = self._solve_both(pending)
+        assert tpu == host == expected
+
+    def test_zone_self_affinity_bootstraps_on_the_first_node(self):
+        from karpenter_core_tpu.apis.objects import LabelSelector, PodAffinityTerm
+
+        sel = LabelSelector(match_labels={"app": "db"})
+
+        def pending():
+            return [
+                make_pod(labels={"app": "db"}, requests={"cpu": "1"}, pod_affinity=[
+                    PodAffinityTerm(topology_key=ZONE, label_selector=sel)
+                ])
+                for _ in range(3)
+            ]
+
+        host, tpu = self._solve_both(pending)
+        assert tpu == host == {"ex-0": 3}
+
+    def test_water_fill_without_existing_nodes_is_what_it_was(self):
+        """Left-over pods go by count, then zone index, exactly as before the
+        node-order rule; with a running sum they go where a node comes first."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from karpenter_core_tpu.ops.solve import _water_fill
+
+        import jax
+
+        filled = jax.jit(_water_fill)  # one program, not one per jnp op
+
+        def fill(counts, m, ex_cum=None):
+            allowed = jnp.ones(len(counts), dtype=bool)
+            return np.asarray(filled(
+                jnp.asarray(counts, jnp.int32), allowed, jnp.int32(m), ex_cum
+            )).tolist()
+
+        assert fill([5, 5, 5], 1) == [1, 0, 0]
+        assert fill([5, 3, 5], 4) == [1, 3, 0]
+        assert fill([0, 0, 0], 7) == [3, 2, 2]
+        closed = jnp.zeros((1, 3), jnp.int32)  # one closed placeholder node
+        assert fill([5, 5, 5], 1, closed) == [1, 0, 0]
+        # node 0 sits in zone 2, node 1 in zone 0, each with room for two
+        ex_cap = jnp.asarray([[0, 0, 2], [2, 0, 0]], jnp.int32)
+        cum = jnp.cumsum(ex_cap, axis=0)
+        assert fill([5, 5, 5], 1, cum) == [0, 0, 1]
+        assert fill([5, 5, 5], 2, cum) == [1, 0, 1]
+        assert fill([5, 3, 5], 4, cum) == [1, 2, 1]
+        # zone 2's node is full once the level fill gave it two: zone 0 is next
+        assert fill([3, 5, 3], 5, cum) == [3, 0, 2]
