@@ -211,6 +211,13 @@ def _attach_pol(snapshot, statics_arrays):
     return pol
 
 
+def _signature_index(table: Dict[tuple, tuple], signature: tuple, row) -> int:
+    """The index of ``signature`` in ``table`` (signature -> (index, the first
+    row seen with it)), entered on first sight: how ``encode_existing`` groups
+    nodes, classes and bound pods whose predicates cannot differ."""
+    return table.setdefault(signature, (len(table), row))[0]
+
+
 class SolvePrep(NamedTuple):
     """One snapshot's kernel inputs, prepared (and bucket-padded) once.
 
@@ -585,6 +592,13 @@ class TPUSolver:
         (topology.go:231-276) for pre-existing matching pods.
         """
         from karpenter_core_tpu.apis import labels as labels_api
+        from karpenter_core_tpu.models.snapshot import (
+            GRP_ANTI,
+            UNLIMITED,
+            _group_spec,
+            pod_port_keys,
+            term_namespaces,
+        )
         from karpenter_core_tpu.scheduling import Taints
 
         vocab = snapshot.vocab
@@ -608,7 +622,6 @@ class TPUSolver:
         pod_count = np.zeros(E, dtype=np.int32)
         open_ = np.zeros(E, dtype=bool)
         init = np.zeros(E, dtype=bool)
-        tol = np.zeros((C, E), dtype=bool)
         P = len(snapshot.ports)
         ports = np.zeros((E, P), dtype=bool)
         grp_node_member = np.zeros((G1, E), dtype=np.int32)
@@ -622,6 +635,13 @@ class TPUSolver:
         tmpl_by_name = {t.provisioner_name: t for t in self.templates}
         zone_idx = {z: i for i, z in enumerate(snapshot.zones)}
         ct_idx = {c: i for i, c in enumerate(snapshot.capacity_types)}
+        # tol[c, e] and the bound pods' group counts depend only on a
+        # signature of each side (a taint set, a toleration set, a pod's
+        # namespace + labels), and signatures repeat — node pools, Deployments'
+        # replicas: each predicate is evaluated once per distinct pair below
+        # and scattered (tables: _signature_index)
+        taint_sets: Dict[tuple, tuple] = {}
+        node_sig = np.zeros(len(state_nodes), dtype=np.intp)
 
         for e, state_node in enumerate(state_nodes):
             node = state_node.node
@@ -660,36 +680,58 @@ class TPUSolver:
             if t_idx is not None:
                 node_tmpl[e] = t_idx
                 node_owned[e] = True
-            taints = Taints.of(state_node.taints())
-            for c, cls in enumerate(snapshot.classes):
-                tol[c, e] = taints.tolerates(cls.pods[0]) is None
+            taints = state_node.taints()
+            node_sig[e] = _signature_index(
+                taint_sets, tuple((t.key, t.value, t.effect) for t in taints), taints
+            )
+
+        # what Taints.tolerates reads of a pod is its tolerations alone
+        toleration_sets: Dict[tuple, tuple] = {}
+        cls_sig = np.zeros(C, dtype=np.intp)
+        for c, cls in enumerate(snapshot.classes):
+            pod = cls.pods[0]
+            cls_sig[c] = _signature_index(
+                toleration_sets,
+                tuple(
+                    (t.key, t.operator, t.value, t.effect)
+                    for t in pod.spec.tolerations
+                ),
+                pod,
+            )
+        tolerated = np.zeros((len(toleration_sets), len(taint_sets)), dtype=bool)
+        for j, node_taints in taint_sets.values():
+            taints = Taints.of(node_taints)
+            for i, pod in toleration_sets.values():
+                tolerated[i, j] = taints.tolerates(pod) is None
+        tol = np.zeros((C, E), dtype=bool)
+        tol[:, : len(state_nodes)] = tolerated[np.ix_(cls_sig, node_sig)]
 
         # pre-existing pod counts per topology group (countDomains semantics,
         # topology.go:231-276): members (forward) and anti-term owners
         # (inverse); pods being scheduled this solve are excluded
-        from karpenter_core_tpu.models.snapshot import (
-            GRP_ANTI,
-            UNLIMITED,
-            _group_spec,
-            term_namespaces,
-        )
-
         node_index = {n.node.name: e for e, n in enumerate(state_nodes)}
         group_of = {spec: g for g, spec in enumerate(snapshot.groups)}
         scheduling_uids = {p.uid for cls in snapshot.classes for p in cls.pods}
+        # what GroupScope.matches_pod reads of a pod: namespace and labels
+        pod_signatures: Dict[tuple, tuple] = {}
+        bound_sig: List[int] = []  # per bound pod that counts: its signature,
+        bound_node: List[int] = []  # and its node
         for pod in bound_pods or []:
             e = node_index.get(pod.spec.node_name)
             if e is None or pod.uid in scheduling_uids:
                 continue
-            from karpenter_core_tpu.models.snapshot import pod_port_keys as _ppk
-
-            for key in _ppk(pod):
+            for key in pod_port_keys(pod):
                 i = port_idx.get(key)
                 if i is not None:
                     ports[e, i] = True
-            for g, scope in enumerate(snapshot.group_selectors):
-                if scope is not None and scope.matches_pod(pod):
-                    grp_node_member[g, e] += 1
+            bound_sig.append(
+                _signature_index(
+                    pod_signatures,
+                    (pod.namespace or "", frozenset(pod.metadata.labels.items())),
+                    pod,
+                )
+            )
+            bound_node.append(e)
             affinity = pod.spec.affinity
             if affinity is not None and affinity.pod_anti_affinity is not None:
                 for term in affinity.pod_anti_affinity.required:
@@ -703,14 +745,25 @@ class TPUSolver:
                     g = group_of.get(spec)
                     if g is not None:
                         grp_node_owner[g, e] += 1
+        member = np.zeros((len(pod_signatures), G1), dtype=bool)
+        for i, pod in pod_signatures.values():
+            for g, scope in enumerate(snapshot.group_selectors):
+                member[i, g] = scope is not None and scope.matches_pod(pod)
+        sig_of = np.asarray(bound_sig, dtype=np.intp)
+        node_of = np.asarray(bound_node, dtype=np.intp)
+        for g in np.flatnonzero(member.any(axis=0)):
+            grp_node_member[g] = np.bincount(node_of[member[sig_of, g]], minlength=E)
+        tracing.set_attrs(
+            taint_sets=len(taint_sets),
+            toleration_sets=len(toleration_sets),
+            pod_signatures=len(pod_signatures),
+        )
 
         # -- volume attach-limit planes (volumeusage.go:33-236 as per-driver
         # counters; existingnode.go:77-130 enforcement).  Only existing nodes
         # carry limits (CSINode); the axis covers drivers mounted by a
         # scheduling class plus drivers already over their limit (which block
         # every add, volume-less pods included — VolumeCount.exceeds).
-        from karpenter_core_tpu.models.snapshot import UNLIMITED
-
         class_volumes = snapshot.class_volumes or [
             {"shared": {}, "per_pod": {}} for _ in snapshot.classes
         ]
