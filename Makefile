@@ -2,10 +2,10 @@
 
 PYTEST ?= python -m pytest
 
-presubmit: verify test kernel-smoke perf-gate  ## everything a PR needs to pass
+presubmit: verify test kernel-smoke  ## everything a PR needs to pass
 
 verify: chaos soak  ## static checks + the chaos and soak gates: bytecode-compile, kcanalyze (all analysis passes, baseline-aware), build the native library
-	python -m compileall -q karpenter_core_tpu tests bench.py chip_smoke.py __graft_entry__.py
+	python -m compileall -q karpenter_core_tpu tests chip_smoke.py __graft_entry__.py
 	python tools/kcanalyze.py --strict
 	$(MAKE) -C native
 
@@ -24,19 +24,10 @@ test-all:  ## everything incl. the compile-heavy kernel/parity tier (~25 min)
 kernel-smoke:  ## bounded kernel gate for presubmit: a parity slice compiles + solves (~1 min)
 	$(PYTEST) tests/test_tpu_solver.py -x -q -k "homogeneous or two_sizes or pod_count_limit"
 
-perf: perf-gate  ## performance-gated tests (reference: //go:build test_performance)
-	KC_TPU_PERF=1 $(PYTEST) tests/test_performance.py -q
-
-perf-gate:  ## round-over-round drift check: bench vs last same-platform BENCH_r*.json (advisory; KC_PERF_GATE_STRICT=1 to enforce)
-	python tools/perfgate.py
-
-bench:  ## headline benchmark on the device JAX finds (exits non-zero when a phase failed)
-	python bench.py
-
 chip-smoke:  ## the served solve path end to end on the local TPU, chip-or-fail (refuses to run where JAX finds no TPU)
 	python chip_smoke.py
 
 graft-check:  ## driver contract: compile check + multi-chip dry run
 	python __graft_entry__.py
 
-.PHONY: presubmit verify chaos soak test test-all kernel-smoke perf perf-gate bench chip-smoke graft-check
+.PHONY: presubmit verify chaos soak test test-all kernel-smoke chip-smoke graft-check

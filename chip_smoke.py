@@ -153,12 +153,330 @@ def _off_device(tree, platform: str, where: str) -> list:
     ]
 
 
+# -- the inputs ----------------------------------------------------------------
+
+
+def pod_mix(n_pods: int, rng=None) -> list:
+    """The reference benchmark's makeDiversePods shape
+    (scheduling_benchmark_test.go:185-197): 3/7 generic + 1/7 zonal spread +
+    1/7 hostname spread + 2/7 pod (self-)affinity.  ``rng``
+    (``random.Random``) draws each generic pod's cpu and memory from the
+    reference's own lists (randomCPU / randomMemory: 100m…1500m ×
+    100Mi…4Gi — 30 shapes, and 100m is not exact in bf16, which is what lets
+    a chip run catch a matmul running below f32 precision) and each affinity
+    pod's group, as the reference draws them from its seeded source: 39
+    classes.  None keeps a fixed four-size cycle: 13 classes."""
+    from karpenter_core_tpu.apis import labels as labels_api
+    from karpenter_core_tpu.apis.objects import (
+        LabelSelector,
+        PodAffinityTerm,
+        TopologySpreadConstraint,
+    )
+    from karpenter_core_tpu.testing import make_pod
+
+    pods = []
+    n_spread = n_pods // 7
+    n_host_spread = n_pods // 7
+    n_affinity = 2 * n_pods // 7
+    n_generic = n_pods - n_spread - n_host_spread - n_affinity
+    sizes = [
+        {"cpu": "500m", "memory": "512Mi"},
+        {"cpu": 1, "memory": "2Gi"},
+        {"cpu": 2, "memory": "4Gi"},
+        {"cpu": "250m", "memory": "256Mi"},
+    ]
+    for i in range(n_generic):
+        if rng is None:
+            size = sizes[i % len(sizes)]
+        else:
+            size = {
+                "cpu": rng.choice(("100m", "250m", "500m", "1000m", "1500m")),
+                "memory": rng.choice(
+                    ("100Mi", "256Mi", "512Mi", "1024Mi", "2048Mi", "4096Mi")),
+            }
+        pods.append(make_pod(requests=size))
+    for _ in range(n_spread):
+        pods.append(
+            make_pod(
+                labels={"app": "spread"},
+                requests={"cpu": "250m", "memory": "256Mi"},
+                topology_spread=[
+                    TopologySpreadConstraint(
+                        max_skew=1,
+                        topology_key=labels_api.LABEL_TOPOLOGY_ZONE,
+                        label_selector=LabelSelector(match_labels={"app": "spread"}),
+                    )
+                ],
+            )
+        )
+    for _ in range(n_host_spread):
+        pods.append(
+            make_pod(
+                labels={"app": "hspread"},
+                requests={"cpu": "250m", "memory": "256Mi"},
+                topology_spread=[
+                    TopologySpreadConstraint(
+                        max_skew=1,
+                        topology_key=labels_api.LABEL_HOSTNAME,
+                        label_selector=LabelSelector(match_labels={"app": "hspread"}),
+                    )
+                ],
+            )
+        )
+    # zone self-affinity groups over a 7-value label pool — the reference's
+    # 2/7 affinity share draws labels/selectors from the same 7 values
+    # (scheduling_benchmark_test.go:263-278); self-selecting groups avoid the
+    # cross-group scan-order dependency that routes to the host path
+    for i in range(n_affinity):
+        group = f"g{i % 7 if rng is None else rng.randrange(7)}"
+        pods.append(
+            make_pod(
+                labels={"aff-group": group},
+                requests={"cpu": "250m", "memory": "256Mi"},
+                pod_affinity=[
+                    PodAffinityTerm(
+                        topology_key=labels_api.LABEL_TOPOLOGY_ZONE,
+                        label_selector=LabelSelector(match_labels={"aff-group": group}),
+                    )
+                ],
+            )
+        )
+    return pods
+
+
+def build_inputs(n_pods: int, n_instance_types: int, n_provisioners: int):
+    from karpenter_core_tpu.cloudprovider import fake as fake_cp
+    from karpenter_core_tpu.solver.tpu import TPUSolver
+    from karpenter_core_tpu.testing import make_provisioner
+
+    provider = fake_cp.FakeCloudProvider(fake_cp.instance_types(n_instance_types))
+    provisioners = [
+        make_provisioner(name=f"prov-{i}", weight=n_provisioners - i)
+        for i in range(n_provisioners)
+    ]
+    return TPUSolver(provider, provisioners), pod_mix(n_pods)
+
+
+def consolidation_cluster(n_nodes: int, pods_per_node: int, instance_types):
+    """(env, candidates): a synthetic consolidatable cluster over
+    ``instance_types`` — nodes and bound pods pushed straight through the
+    informer plane, no provisioning round trips — and its disruption-sorted
+    candidate list, the input of a multi-node consolidation sweep."""
+    from karpenter_core_tpu.apis import labels as labels_api
+    from karpenter_core_tpu.controllers.deprovisioning import candidate_nodes
+    from karpenter_core_tpu.testing import make_node, make_pod, make_provisioner
+    from karpenter_core_tpu.testing.harness import make_environment
+    from karpenter_core_tpu.utils import resources as resources_util
+
+    env = make_environment(instance_types=instance_types)
+    env.kube.create(make_provisioner(name="default", consolidation_enabled=True))
+    # a roomy on-demand instance type: bound pods use a sliver of it, so most
+    # prefixes consolidate (the interesting, full-cost sweep shape)
+    choices = [
+        it for it in env.provider.get_instance_types(None)
+        if resources_util.parse_quantity(it.capacity.get("cpu", 0)) >= 8
+        and any(o.capacity_type == labels_api.CAPACITY_TYPE_ON_DEMAND and o.available
+                for o in it.offerings)
+    ]
+    it = choices[len(choices) // 2]
+    offering = next(
+        o for o in it.offerings
+        if o.capacity_type == labels_api.CAPACITY_TYPE_ON_DEMAND and o.available
+    )
+    for i in range(n_nodes):
+        node = make_node(
+            name=f"sweep-node-{i}",
+            labels={
+                labels_api.PROVISIONER_NAME_LABEL_KEY: "default",
+                labels_api.LABEL_INSTANCE_TYPE_STABLE: it.name,
+                labels_api.LABEL_TOPOLOGY_ZONE: offering.zone,
+                labels_api.LABEL_CAPACITY_TYPE: offering.capacity_type,
+                labels_api.LABEL_NODE_INITIALIZED: "true",
+            },
+            allocatable=it.allocatable(),
+            capacity=dict(it.capacity),
+            provider_id=f"fake://sweep-node-{i}",
+        )
+        env.kube.create(node)
+        for _ in range(pods_per_node):
+            pod = make_pod(requests={"cpu": "100m", "memory": "64Mi"})
+            env.kube.create(pod)
+            env.bind(pod, node.name)
+    env.clock.step(30)
+    dep = env.deprovisioning
+    candidates = sorted(
+        candidate_nodes(
+            env.cluster, env.kube, env.clock, env.provider,
+            dep.multi_node_consolidation.should_deprovision,
+        ),
+        key=lambda c: c.disruption_cost,
+    )
+    return env, candidates
+
+
+def churn_line(solver, ingest, churn_fraction: float = 0.02, ticks: int = 5) -> dict:
+    """Steady-state churn (ISSUE 7 acceptance): the resident pod
+    population stays fixed while ``churn_fraction`` of each class is replaced
+    per tick, and each tick is solved BOTH ways —
+
+      full re-solve   what every reconcile paid before this PR: encode the
+                      whole snapshot from scratch, solve every class, decode
+      delta repair    the incremental session: no encode, evictions returned
+                      to the warm carry, ONE repair executable over the delta
+
+    Reported: per-tick wall medians (``warm_solve_s`` / ``full_resolve_s``),
+    the speedup, the session's full/delta decision counts, and whether the
+    delta lineage's final assignments are identical (canonical per-node class
+    loads) to the from-scratch solve — the parity the repair claims.
+    Deterministic: evictions take each class's oldest members, replacements
+    deep-copy the class representative (same shape, fresh identity)."""
+    import copy
+    import statistics
+
+    from karpenter_core_tpu.apis.objects import new_uid
+    from karpenter_core_tpu.models import store as store_mod
+    from karpenter_core_tpu.ops import solve as solve_ops
+    from karpenter_core_tpu.solver.incremental import (
+        FallbackPolicy,
+        IncrementalSolveSession,
+        node_signature_of,
+    )
+
+    session = IncrementalSolveSession(
+        solver,
+        FallbackPolicy(enabled=True, audit_interval=0, max_delta_fraction=0.5),
+    )
+    t0 = time.perf_counter()
+    session.solve(ingest)
+    seed_s = time.perf_counter() - t0
+
+    warm_ticks, full_ticks, delta_ingest_ticks = [], [], []
+    churned_per_tick = []
+    delta_compile_s = None
+    identical = True
+    reps = {}  # class signature -> representative pod (shapes to re-mint)
+    # O(fleet) ingest yardstick: what a from-scratch re-ingest of the whole
+    # resident population costs — the per-tick delta ingest below must scale
+    # with the churned subset, not with this number (ISSUE 11 acceptance)
+    from karpenter_core_tpu.models.columnar import PodIngest
+
+    resident = ingest.pods()
+    t0 = time.perf_counter()
+    _full = PodIngest()
+    _full.add_all(resident)
+    full_ingest_s = time.perf_counter() - t0
+    del _full, resident
+    # churn concentrates in a rotating quarter of the classes per tick — the
+    # rollout/deployment shape (one workload's pods are replaced while the
+    # rest of the fleet idles), which is what makes the dirty REGION small
+    # even when the churned pod count is not
+    class_fraction = 0.25
+    for tick in range(ticks):
+        members = ingest.class_members()
+        sigs = sorted(members, key=lambda s: repr(s))
+        window = max(int(len(sigs) * class_fraction), 1)
+        start = (tick * window) % max(len(sigs), 1)
+        dirty = [sigs[(start + i) % len(sigs)] for i in range(window)]
+        target = max(int(len(ingest) * churn_fraction), 1)
+        pool = sum(len(members[s]) for s in dirty)
+        evictions, replacements = [], []
+        for sig in dirty:
+            uids = members[sig]
+            take = min(max(round(target * len(uids) / max(pool, 1)), 1), len(uids))
+            rep = reps.setdefault(sig, copy.deepcopy(ingest.get(uids[0])))
+            evictions.extend(uids[:take])
+            for _ in range(take):
+                pod = copy.deepcopy(rep)
+                pod.metadata.name = f"churn-{tick}-{len(replacements)}"
+                pod.metadata.uid = new_uid()
+                pod.spec.node_name = ""
+                replacements.append(pod)
+        # the delta-tick ingest cost: membership deltas applied to the live
+        # store (pod construction above deliberately excluded — it is the
+        # workload's cost, not the ingest's); must be O(churned), not O(fleet)
+        t0 = time.perf_counter()
+        for uid in evictions:
+            ingest.remove(uid)
+        for pod in replacements:
+            ingest.add(pod)
+        delta_ingest_ticks.append(time.perf_counter() - t0)
+        churned_per_tick.append(len(evictions) + len(replacements))
+
+        import jax
+
+        # the old path: full re-solve of the whole snapshot
+        t0 = time.perf_counter()
+        snapshot = solver.encode(ingest)
+        out_full = solve_ops.solve(snapshot)
+        results_full = solver.decode(snapshot, out_full)
+        full_ticks.append(time.perf_counter() - t0)
+
+        # fetch the full solve's planes (and thereby drain its device queue)
+        # BEFORE the delta timer starts — otherwise the repair's first sync
+        # absorbs the full solve's still-in-flight compute and the warm number
+        # reads slower than it is
+        assign_f, assign_ex_f = jax.device_get(
+            (out_full.assign, out_full.assign_existing)
+        )
+        # label loads by stable class identity, not row index: a fully-churned
+        # class re-enters the fresh encode at a different row among
+        # equal-request classes, which must not read as divergence
+        keys_f = [store_mod.class_key(c) for c in snapshot.classes]
+        full_sig = node_signature_of(assign_f, keys_f) + node_signature_of(
+            assign_ex_f, keys_f
+        )
+
+        # the delta path
+        t0 = time.perf_counter()
+        session.solve(ingest)
+        elapsed = time.perf_counter() - t0
+        if tick == 0:
+            # first repair pays the delta executable's cold compile; report
+            # it separately so the steady-state number is honest
+            delta_compile_s = elapsed
+        else:
+            warm_ticks.append(elapsed)
+
+        identical = identical and (full_sig == session.node_signature())
+
+    agg = session.aggregates()
+    warm_s = statistics.median(warm_ticks) if warm_ticks else float("inf")
+    full_s = statistics.median(full_ticks)
+    delta_ingest_s = statistics.median(delta_ingest_ticks) if delta_ingest_ticks else 0.0
+    churned = round(statistics.mean(churned_per_tick)) if churned_per_tick else 0
+    return {
+        "pods": len(ingest),
+        "churn_fraction": churn_fraction,
+        "ticks": ticks,
+        # per-tick membership-delta ingest vs the O(fleet) from-scratch
+        # yardstick: the O(churned) acceptance evidence (ISSUE 11)
+        "delta_ingest_s": round(delta_ingest_s, 5),
+        "churned_pods_per_tick": churned,
+        "full_ingest_s": round(full_ingest_s, 4),
+        "delta_ingest_fraction_of_full": round(
+            delta_ingest_s / full_ingest_s, 4
+        ) if full_ingest_s > 0 else None,
+        "seed_full_solve_s": round(seed_s, 4),
+        "delta_compile_s": round(delta_compile_s, 4) if delta_compile_s else None,
+        "warm_solve_s": round(warm_s, 4),
+        "full_resolve_s": round(full_s, 4),
+        "speedup": round(full_s / warm_s, 2) if warm_s > 0 else 0.0,
+        "modes": dict(session.mode_counts),
+        "identical_assignments": identical,
+        # capacity accounting: the carry's used plane vs an exact recount
+        # (IncrementalSolveSession.used_drift; ~1e-7 = f32 rounding)
+        "used_drift_max_rel": session.used_drift(),
+        "scheduled": agg["scheduled"],
+        "failed": agg["failed"],
+        "nodes": agg["nodes"],
+    }
+
+
 # -- the legs ------------------------------------------------------------------
 
 
 def served_leg(smoke: Smoke, args, pods, provisioners, catalog) -> None:
     """The sidecar over loopback gRPC, composed as the binary composes it."""
-    import bench
     from karpenter_core_tpu.cloudprovider.fake import FakeCloudProvider
     from karpenter_core_tpu.cmd import solver as solver_cmd
     from karpenter_core_tpu.models.snapshot import _class_signature
@@ -265,7 +583,7 @@ def served_leg(smoke: Smoke, args, pods, provisioners, catalog) -> None:
             smoke.breaker_states[f"tenant:{tenant_id}"] = entry.breaker.state
 
         # -- one /Consolidate sweep, through the controller's own envelope ----
-        env, candidates = bench.consolidation_cluster(args.sweep_nodes, 3, catalog)
+        env, candidates = consolidation_cluster(args.sweep_nodes, 3, catalog)
         mnc = env.deprovisioning.multi_node_consolidation
         mnc.solver_endpoint = f"127.0.0.1:{port}"
         cmd = smoke.request(
@@ -303,7 +621,6 @@ def kernel_leg(smoke: Smoke, args, pods, provisioners, catalog) -> None:
     import jax
     import numpy as np
 
-    import bench
     from karpenter_core_tpu.cloudprovider.fake import FakeCloudProvider
     from karpenter_core_tpu.models.columnar import PodIngest
     from karpenter_core_tpu.ops import solve as solve_ops
@@ -353,7 +670,7 @@ def kernel_leg(smoke: Smoke, args, pods, provisioners, catalog) -> None:
 
     # an UNHOOKED session: its churn repairs donate the warm carry
     donation0 = pipeline_mod.stats()
-    churn = smoke.request("kernel.session_churn", lambda: bench.churn_line(
+    churn = smoke.request("kernel.session_churn", lambda: churn_line(
         solver, ingest, churn_fraction=0.02, ticks=2))
     if churn is not None:
         donation = _moved(donation0, pipeline_mod.stats())
@@ -377,7 +694,6 @@ def kernel_leg(smoke: Smoke, args, pods, provisioners, catalog) -> None:
 
 def operator_leg(smoke: Smoke, args, rng) -> None:
     """The upstream suite's largest size through the in-process operator."""
-    import bench
     from karpenter_core_tpu.cloudprovider.fake import FakeCloudProvider, instance_types
     from karpenter_core_tpu.operator.kubeclient import KubeClient
     from karpenter_core_tpu.operator.operator import Operator
@@ -386,7 +702,7 @@ def operator_leg(smoke: Smoke, args, rng) -> None:
     from karpenter_core_tpu.testing.validator import validate_placements
 
     provider = FakeCloudProvider(instance_types(args.operator_types))
-    pods = bench.pod_mix(args.operator_pods, rng)
+    pods = pod_mix(args.operator_pods, rng)
     # the smoke's cluster is this in-memory store, and the pods are ITS
     # workload: they must not queue behind the operator's own client-side
     # write throttle (--kube-client-qps 200 would spread 5 000 creates over
@@ -436,7 +752,6 @@ def operator_leg(smoke: Smoke, args, rng) -> None:
 def oracle_leg(smoke: Smoke, args, rng) -> None:
     """Kernel vs host oracle on a cut the oracle can hold
     (tests/test_tpu_solver.py ``compare``)."""
-    import bench
     from karpenter_core_tpu.cloudprovider.fake import FakeCloudProvider, instance_types
     from karpenter_core_tpu.operator.kubeclient import KubeClient
     from karpenter_core_tpu.solver.builder import build_scheduler
@@ -445,7 +760,7 @@ def oracle_leg(smoke: Smoke, args, rng) -> None:
 
     catalog = instance_types(args.operator_types)
     provisioners = [make_provisioner(name="default")]
-    pods = bench.pod_mix(args.oracle_pods, rng)
+    pods = pod_mix(args.oracle_pods, rng)
 
     def kernel():
         solver = TPUSolver(FakeCloudProvider(catalog), provisioners)
@@ -529,7 +844,6 @@ def main(argv=None) -> int:
 
     import jaxlib
 
-    import bench
     from karpenter_core_tpu.cloudprovider.fake import instance_types
     from karpenter_core_tpu.controllers import provisioning as prov_mod
     from karpenter_core_tpu.models import native, nativesig
@@ -564,7 +878,7 @@ def main(argv=None) -> int:
     provisioners = [
         make_provisioner(name=f"prov-{i}", weight=5 - i) for i in range(5)
     ]
-    pods = bench.pod_mix(args.pods, rng)
+    pods = pod_mix(args.pods, rng)
     fallback_families = (prov_mod.TPU_KERNEL_FALLBACK, prov_mod.DEGRADED_SOLVES)
     fallbacks0 = {f.name: _counter_samples(f) for f in fallback_families}
     modes0 = _counter_samples(SOLVE_MODE)

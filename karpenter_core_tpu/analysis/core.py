@@ -35,7 +35,7 @@ class Finding:
 class SourceModule:
     """One parsed source file."""
 
-    name: str  # dotted module name ("" for non-package files like bench.py)
+    name: str  # dotted module name ("" for non-package files like __graft_entry__.py)
     path: Path
     relpath: str  # root-relative, forward slashes
     source: str
@@ -56,7 +56,7 @@ class Project:
     call-graph and lock passes look at ``package_modules`` alone.
     """
 
-    DEFAULT_EXTRA_ROOTS = ("tests", "tools", "bench.py", "__graft_entry__.py")
+    DEFAULT_EXTRA_ROOTS = ("tests", "tools", "__graft_entry__.py")
 
     def __init__(
         self,

@@ -1,10 +1,9 @@
-"""The retrace-budget manifest: one loader shared by every consumer.
+"""The retrace-budget manifest: the loader of the checked-in file.
 
-tests/conftest.py (per-test budget enforcement), bench.py (cold-compile
-warning), and tools/perfgate.py (post-bench re-check) all read the same
-checked-in file; keeping the path and the degrade-to-empty error policy in
-one place means moving or re-shaping the manifest is a one-file edit.
-Stdlib-only and safe to import before any backend decision.
+tests/conftest.py (per-test budget enforcement) reads it through here;
+keeping the path and the degrade-to-empty error policy in one place means
+moving or re-shaping the manifest is a one-file edit.  Stdlib-only and safe
+to import before any backend decision.
 """
 
 from __future__ import annotations

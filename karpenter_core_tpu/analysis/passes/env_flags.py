@@ -1,7 +1,7 @@
 """env-flags: every KC_* environment flag is registered and documented.
 
 The service grew ~50 ``KC_*`` tuning flags (KC_PIPELINE, KC_WATCHDOG,
-KC_COALESCE_WINDOW, KC_BUCKET_QUANTIZE, KC_FLEET_CHECKPOINT_KEEP, ...) with
+KC_COALESCE_WINDOW, KC_FLEET_CHECKPOINT_KEEP, ...) with
 no central inventory: a flag you cannot find is a flag you cannot audit,
 and a dead registry row is documentation that lies.  This pass closes the
 loop in both directions against the central registry
@@ -15,8 +15,8 @@ loop in both directions against the central registry
   dead-entry         a registry row no package code reads
   undocumented-flag  a registry row missing from the docs/FLAGS.md table
 
-Scope is the package only: bench/tools/tests harness flags (KC_BENCH_*,
-KC_PERF_GATE_STRICT, ...) are out of band and stay out of the registry.
+Scope is the package only: tools/tests harness flags (KC_CHAOS_SEED,
+KC_SOAK_SEED, ...) are out of band and stay out of the registry.
 Helper indirection is inferred, not hard-coded: any package function whose
 parameter flows into an environ read is an env-helper, and literal first
 arguments at its call sites count as reads of that flag.

@@ -301,7 +301,7 @@ class ProvisioningController:
         # loop spent doing other work instead of blocking.  The soak
         # runner's advisory ``tick_overlap_s`` probe reads this; ≈0 on this
         # controller's serial per-reconcile path, >0 when a pipelined loop
-        # (bench pipeline_line, deferred session ticks) drove the solve
+        # (deferred session ticks) drove the solve
         # (docs/KERNEL_PERF.md "Layer 7")
         self.last_overlap_s: float = 0.0
         # persistent signature/ladder interner: watch events become

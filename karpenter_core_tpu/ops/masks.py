@@ -20,13 +20,12 @@ Two storage layouts share one API.  The classic layout keeps the slots as a
 bool plane ``[..., K, V+1]``; the *bit-packed* layout stores the same slots as
 uint32 words ``[..., K, ceil((V+1)/32)]`` (``pack_mask``), which shrinks the
 solve kernel's scan carry up to 32× and turns every slot reduction into a
-word-wide AND + nonzero test (~100× faster than the bf16 einsum path on CPU
-at bench shapes; the layout the future Pallas kernel will consume directly).
+word-wide AND + nonzero test.
 A ReqTensor is packed iff ``mask.dtype == uint32``; packed callers must pass
 ``v`` — the semantic slot count V+1 — because the word plane cannot recover
-it.  The einsum/bool path stays fully supported (parity-fuzzed in
-tests/test_kernel_fusion_parity.py) behind the kernel's ``packed_masks``
-flag.
+it.  The solve kernel runs on the packed layout alone; the bool layout is
+what models/snapshot.py encodes and the reference the packed ops are tested
+against (tests/test_masks.py).
 
 All functions broadcast over leading batch axes and are jit/vmap-safe.
 """

@@ -26,7 +26,6 @@ data-dependent Python control flow.
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -375,36 +374,21 @@ def _merge_node_class(state: NodeState, cls, statics) -> mask_ops.ReqTensor:
 
 def _it_intersects(merged: mask_ops.ReqTensor, statics) -> jnp.ndarray:
     """bool[N, I]: InstanceType.Requirements.Intersects(nodeReqs) for every
-    (node, instance type) pair (node.go:143-145).  Packed masks reduce by a
-    word-wide AND + nonzero test per key (the hot path); the bool layout keeps
-    the per-key [N,V]x[V,I] matmul form so it lands on the MXU."""
-    it = statics.it  # ReqTensor [I, K, V+1] (or [I, K, W] packed words)
+    (node, instance type) pair (node.go:143-145): a word-wide AND + nonzero
+    test per key over the packed masks."""
+    it = statics.it  # ReqTensor [I, K, W] packed words
     n_keys = it.defined.shape[-1]
-    packed = statics.packed
-    if packed:
-        vocab = jnp.asarray(mask_ops.vocab_words(statics.mask_v))
-        a_other_all = mask_ops.other_bit(merged.mask, statics.mask_v)  # [N, K]
-        b_other_all = mask_ops.other_bit(it.mask, statics.mask_v)  # [I, K]
+    vocab = jnp.asarray(mask_ops.vocab_words(statics.mask_v))
+    a_other_all = mask_ops.other_bit(merged.mask, statics.mask_v)  # [N, K]
+    b_other_all = mask_ops.other_bit(it.mask, statics.mask_v)  # [I, K]
     ok_all = None
     for k in range(n_keys):  # K is small and static: unrolled
-        a_mask = merged.mask[:, k, :]  # [N, V+1] bools or [N, W] words
-        b_mask = it.mask[:, k, :]  # [I, V+1] bools or [I, W] words
-        if packed:
-            vocab_overlap = jnp.any(
-                (a_mask[:, None, :] & vocab & b_mask[None, :, :]) != 0, axis=-1
-            )
-            both_other = a_other_all[:, k, None] & b_other_all[None, :, k]
-        else:
-            vocab_overlap = (
-                jnp.einsum(
-                    "nv,iv->ni",
-                    a_mask[:, :-1].astype(jnp.bfloat16),
-                    b_mask[:, :-1].astype(jnp.bfloat16),
-                    preferred_element_type=jnp.float32,
-                )
-                > 0.5
-            )
-            both_other = a_mask[:, -1:] & b_mask[None, :, -1]
+        a_mask = merged.mask[:, k, :]  # [N, W] words
+        b_mask = it.mask[:, k, :]  # [I, W] words
+        vocab_overlap = jnp.any(
+            (a_mask[:, None, :] & vocab & b_mask[None, :, :]) != 0, axis=-1
+        )
+        both_other = a_other_all[:, k, None] & b_other_all[None, :, k]
         if statics.key_has_bounds[k]:
             gt = jnp.maximum(merged.gt[:, k, None], it.gt[None, :, k])
             lt = jnp.minimum(merged.lt[:, k, None], it.lt[None, :, k])
@@ -494,7 +478,9 @@ def _fill_with_pref(quota, cap, priority, pref):
 
 
 class Statics(NamedTuple):
-    """Trace-time constants bundled for the kernel."""
+    """Trace-time constants bundled for the kernel.  The requirement mask
+    planes (``it``, ``tmpl``, ``valid``) are uint32 words (ops/masks.py
+    pack_mask)."""
 
     it: mask_ops.ReqTensor
     it_alloc: jnp.ndarray
@@ -514,8 +500,7 @@ class Statics(NamedTuple):
     grp_is_anti: jnp.ndarray  # bool[G1]
     grp_member: jnp.ndarray  # bool[C, G1]
     key_has_bounds: Tuple[bool, ...]  # python tuple -> static per-key branching
-    packed: bool = False  # mask planes are uint32 words (ops/masks.py pack_mask)
-    mask_v: int = 0  # semantic slot count V+1 (only meaningful when packed)
+    mask_v: int = 0  # semantic slot count V+1 (the word planes cannot recover it)
     # mesh axis name the catalog (I) planes are sharded over inside a
     # shard_map body (parallel.mesh); None = unsharded, no collectives traced
     catalog_axis: "Optional[str]" = None
@@ -942,7 +927,6 @@ def _class_step(
     carry,
     cls_with_index,
     features: SnapshotFeatures = ALL_FEATURES,
-    fuse_zones: bool = True,
     pref: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     topo_base: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
 ):
@@ -962,11 +946,10 @@ def _class_step(
 
     ``features`` (static) prunes whole phase families the snapshot provably
     cannot exercise — they are never traced, not just runtime-skipped.
-    ``fuse_zones`` (static) replaces the Z sequential zone-committal
-    ``run_phase`` sweeps (zone spread, required zonal anti) with one batched
-    multi-zone block (``committal_block``) that shares a single dense prep and
-    resolves shared-node conflicts by zone order with cumulative caps; the
-    sequential path is kept for parity fuzzing."""
+    The zone-committal phases (zone spread, required zonal anti) run as one
+    batched multi-zone block (``committal_block``) that shares a single dense
+    prep and resolves shared-node conflicts by zone order with cumulative
+    caps."""
     ft = features
     state, ex, topo, remaining = carry
     cls, cls_index = cls_with_index
@@ -1527,19 +1510,11 @@ def _class_step(
             allowed_zone & fillable & ~sat & skew_headroom & cap_headroom
         )
         quotas_gated = jnp.where(has_zs, quotas, 0)
-        if fuse_zones:
-            results_zs = committal_block(
-                state, ex, remaining, quotas_gated, jnp.int32(UNLIMITED)
-            )
-            placed_zs = results_zs[4]
-            accumulate(results_zs)
-        else:
-            placed_zs = jnp.int32(0)
-            for z in range(n_zones):
-                restrict = jnp.zeros(n_zones, dtype=bool).at[z].set(True)
-                results_z = run_phase(state, ex, remaining, quotas_gated[z], restrict)
-                placed_zs = placed_zs + results_z[4]
-                accumulate(results_z)
+        results_zs = committal_block(
+            state, ex, remaining, quotas_gated, jnp.int32(UNLIMITED)
+        )
+        placed_zs = results_zs[4]
+        accumulate(results_zs)
         # quota granted but not realized in-phase: the water-fill's per-zone
         # intake estimate (ex_cap_z) is optimistic — e.g. a multi-zone node's
         # capacity counts into every zone of its mask — so a phase can place
@@ -1581,20 +1556,7 @@ def _class_step(
         # single largest per-class phase block, all compile + per-step cost
         if ft.required_zone_anti:
             anti_quota_z = (anti_required & zero_zones).astype(jnp.int32)
-            if fuse_zones:
-                accumulate(committal_block(state, ex, remaining, anti_quota_z, m))
-            else:
-                placed_anti = jnp.int32(0)
-                for z in range(n_zones):
-                    restrict = jnp.zeros(n_zones, dtype=bool).at[z].set(True)
-                    q = jnp.where(
-                        anti_required & zero_zones[z] & (placed_anti < m),
-                        jnp.int32(1),
-                        jnp.int32(0),
-                    )
-                    results_a = run_phase(state, ex, remaining, q, restrict)
-                    placed_anti = placed_anti + results_a[4]
-                    accumulate(results_a)
+            accumulate(committal_block(state, ex, remaining, anti_quota_z, m))
         anti_quota = jnp.where(
             has_zan & jnp.any(zero_zones),
             jnp.where(
@@ -1695,6 +1657,17 @@ def _class_step(
     )
 
 
+def pack_masks(sa: StaticArrays, class_tensors):
+    """The bool mask planes the encoder emits -> the uint32 words the kernel
+    runs on (ops/masks.py pack_mask)."""
+    sa = sa._replace(
+        it=mask_ops.pack_req(sa.it),
+        tmpl=mask_ops.pack_req(sa.tmpl),
+        valid=mask_ops.pack_mask(sa.valid),
+    )
+    return sa, class_tensors._replace(mask=mask_ops.pack_mask(class_tensors.mask))
+
+
 def solve_core(
     class_tensors,
     statics_arrays,
@@ -1705,8 +1678,6 @@ def solve_core(
     n_passes: int = 1,
     emit_zonal_anti: "Optional[bool]" = None,
     features: "Optional[SnapshotFeatures]" = None,
-    fuse_zones: bool = True,
-    packed_masks: bool = True,
     warm_carry: "Optional[WarmCarry]" = None,
     repair_plan: "Optional[RepairPlan]" = None,
     catalog_axis: "Optional[str]" = None,
@@ -1735,12 +1706,9 @@ def solve_core(
     pass EncodedSnapshot.features so constraint families no class can
     exercise are never traced (docs/KERNEL_PERF.md).  ``emit_zonal_anti`` is
     the legacy single-flag form (pre-features callers); it maps onto
-    features.required_zone_anti.  ``fuse_zones`` (static) selects the batched
-    multi-zone committal block over the sequential per-zone phases;
-    ``packed_masks`` (static) stores requirement masks as uint32 words and
-    runs the mask algebra as bitwise AND + popcount (ops/masks.py) instead of
-    bf16 einsums.  Both default on; the alternates are kept for parity
-    fuzzing.
+    features.required_zone_anti.  Requirement masks arrive as bool planes
+    (what models/snapshot.py encodes) and are packed here into uint32 words;
+    the mask algebra then runs as bitwise AND + popcount (ops/masks.py).
 
     ``warm_carry`` (traced pytree, shapes fixed) switches the call into a
     warm-start REPAIR solve: the scan resumes from a previous solve's final
@@ -1763,17 +1731,9 @@ def solve_core(
     ft = ft.canonical()
     sa = StaticArrays(*statics_arrays)
     width = sa.valid.shape[-1]  # semantic slot count V+1, pre-packing
-    if packed_masks:
-        sa = sa._replace(
-            it=mask_ops.pack_req(sa.it),
-            tmpl=mask_ops.pack_req(sa.tmpl),
-            valid=mask_ops.pack_mask(sa.valid),
-        )
-        class_tensors = class_tensors._replace(
-            mask=mask_ops.pack_mask(class_tensors.mask)
-        )
+    sa, class_tensors = pack_masks(sa, class_tensors)
     statics = Statics(
-        *sa, key_has_bounds=key_has_bounds, packed=packed_masks, mask_v=width,
+        *sa, key_has_bounds=key_has_bounds, mask_v=width,
         catalog_axis=catalog_axis,
     )
     n_zones = statics.tmpl_zone.shape[-1]
@@ -1799,13 +1759,10 @@ def solve_core(
         topo = TopoCounts(*wc.topo)
         remaining0 = wc.remaining
     else:
-        if packed_masks:
-            kmask0 = jnp.broadcast_to(
-                jnp.asarray(mask_ops.full_words(width)),
-                (n_slots, n_keys, mask_ops.words_for(width)),
-            )
-        else:
-            kmask0 = jnp.ones((n_slots, n_keys, width), dtype=bool)
+        kmask0 = jnp.broadcast_to(
+            jnp.asarray(mask_ops.full_words(width)),
+            (n_slots, n_keys, mask_ops.words_for(width)),
+        )
         state = NodeState(
             used=jnp.zeros((n_slots, n_res), dtype=jnp.float32),
             kmask=kmask0,
@@ -1825,7 +1782,7 @@ def solve_core(
         if existing_state is None:
             existing_state = empty_existing_state(n_res, n_keys, width, n_zones, n_ct, n_ports)
             existing_static = empty_existing_static(n_res, n_classes, g1)
-        if packed_masks and existing_state.kmask.dtype != jnp.uint32:
+        if existing_state.kmask.dtype != jnp.uint32:
             existing_state = existing_state._replace(
                 kmask=mask_ops.pack_mask(existing_state.kmask)
             )
@@ -1869,7 +1826,7 @@ def solve_core(
         def do(carry_in):
             return _class_step(
                 statics, existing_static, n_zones, carry_in, (cls, cls_index),
-                features=ft, fuse_zones=fuse_zones, pref=pref, topo_base=base,
+                features=ft, pref=pref, topo_base=base,
             )
 
         def skip(carry_in):
@@ -1996,8 +1953,7 @@ def empty_existing_static(
 _solve_jit = functools.partial(
     jax.jit,
     static_argnames=(
-        "n_slots", "key_has_bounds", "n_passes", "emit_zonal_anti",
-        "features", "fuse_zones", "packed_masks",
+        "n_slots", "key_has_bounds", "n_passes", "emit_zonal_anti", "features",
     ),
 )(solve_core)
 
@@ -2276,14 +2232,12 @@ def sync_outputs(outputs: SolveOutputs) -> SolveOutputs:
     The solve/decode stage split: ``solve()`` returns lazily (device compute
     still in flight) and decode's batched fetch is normally the first sync
     point, so a naive ``t(solve) + t(decode)`` measurement fuses device
-    compute into the decode number.  Callers that need the split — bench.py's
-    ``solve_s``/``decode_s`` stage lines, and the upcoming decode pipelining
-    work (overlap solve[k+1] with decode[k]) — call this between the two so
-    device compute lands in the solve stage and decode measures only
-    transfer + host expansion.  Production paths deliberately do NOT sync
-    here: skipping it saves one device→host round trip.  The barrier runs
-    under the watchdog (utils/watchdog.py): a device that went quiet raises
-    a bounded SolveTimeout instead of blocking forever."""
+    compute into the decode number.  Callers that need the split call this
+    between the two so device compute lands in the solve stage and decode
+    measures only transfer + host expansion.  Production paths deliberately
+    do NOT sync here: skipping it saves one device→host round trip.  The
+    barrier runs under the watchdog (utils/watchdog.py): a device that went
+    quiet raises a bounded SolveTimeout instead of blocking forever."""
     from karpenter_core_tpu.utils import watchdog
 
     watchdog.run("solve.sync", jax.block_until_ready, outputs)
@@ -2563,31 +2517,11 @@ def pad_catalog(cls, statics_arrays, multiple: int, it_price=None):
     return cls, sa, _pad_axis(np.asarray(it_price), 0, i_new, np.inf)
 
 
-def bucket_quantize_enabled() -> bool:
-    """KC_BUCKET_QUANTIZE: the opt-in coarser bucket ladder (docs/SERVICE.md
-    "Solve fusion").  When set, :func:`bucket` skips the 1.5x rungs and pads
-    straight up the powers of two — mixed-size tenants land in FEWER distinct
-    shape buckets, so more of them share one coalesced executable and batch
-    occupancy rises, at the cost of up to ~50% more padded rows per axis
-    (the padded-FLOP vs executable-reuse trade ``bench.py fusion_line``
-    measures).  Default off: unset (or "0") keeps the exact default grid,
-    byte-identical planes and cache keys."""
-    return os.environ.get("KC_BUCKET_QUANTIZE", "") not in ("", "0")
-
-
 def bucket(n: int, floor: int = 8) -> int:
     """Smallest grid value >= max(n, floor); the grid is the powers of two
-    and 1.5x powers of two starting at 2 (2, 3, 4, 6, 8, 12, ...).  Under
-    ``KC_BUCKET_QUANTIZE`` (``bucket_quantize_enabled``) the 1.5x rungs drop
-    out and the grid is the powers of two alone — a strict subset, so every
-    quantized bucket is >= its default-grid value and the distinct-bucket
-    count over any size mix can only shrink."""
+    and 1.5x powers of two starting at 2 (2, 3, 4, 6, 8, 12, ...)."""
     target = max(int(n), int(floor), 2)
     b = 2
-    if bucket_quantize_enabled():
-        while b < target:
-            b <<= 1
-        return b
     while b < target:
         b = b * 3 // 2 if (b & (b - 1)) == 0 else (b // 3) * 4
     return b
@@ -2625,17 +2559,10 @@ def _pad_req(t: mask_ops.ReqTensor, k_new: int, v_new: int) -> mask_ops.ReqTenso
     )
 
 
-def pad_planes(cls, statics_arrays, key_has_bounds, ex_state=None, ex_static=None,
-               device_finish=False):
+def pad_planes(cls, statics_arrays, key_has_bounds, ex_state=None, ex_static=None):
     """Bucket-pad kernel inputs (host numpy pytrees from prepare_host /
     TPUSolver.encode_existing).  Returns (cls, statics_arrays, key_has_bounds,
-    ex_state, ex_static) with stable shapes across nearby problem sizes.
-
-    ``device_finish`` assembles the class-axis planes ON DEVICE under a small
-    memoized jit (``finish_class_planes_device``): the host ships the compact
-    class rows and the broadcast/scatter into the padded bucket happens
-    device-side — bit-identical fills, smaller host→device transfer, no host
-    np.pad over the class block (docs/KERNEL_PERF.md "Layer 6")."""
+    ex_state, ex_static) with stable shapes across nearby problem sizes."""
     sa = StaticArrays(*statics_arrays)
 
     c_old = cls.count.shape[0]
@@ -2650,37 +2577,31 @@ def pad_planes(cls, statics_arrays, key_has_bounds, ex_state=None, ex_static=Non
     g1_new = bucket(g1_old - 1, floor=4) + 1
     p_new = bucket(p_old, floor=4)
 
-    if device_finish:
-        cls = finish_class_planes_device(
-            cls, c_new=c_new, k_new=k_new, v_new=v_new,
-            g1_old=g1_old, g1_new=g1_new, p_new=p_new,
-        )
-    else:
-        groups = np.asarray(cls.groups)
-        groups = np.where(groups >= g1_old - 1, g1_new - 1, groups)
-        cls_t = _pad_req(
-            mask_ops.ReqTensor(cls.mask, cls.defined, cls.negative, cls.gt, cls.lt),
-            k_new, v_new,
-        )
-        cls = ClassTensors(
-            mask=_pad_axis(cls_t.mask, 0, c_new, True),
-            defined=_pad_axis(cls_t.defined, 0, c_new, False),
-            negative=_pad_axis(cls_t.negative, 0, c_new, False),
-            gt=_pad_axis(cls_t.gt, 0, c_new, -np.inf),
-            lt=_pad_axis(cls_t.lt, 0, c_new, np.inf),
-            zone=_pad_axis(np.asarray(cls.zone), 0, c_new, True),
-            ct=_pad_axis(np.asarray(cls.ct), 0, c_new, True),
-            it=_pad_axis(np.asarray(cls.it), 0, c_new, True),
-            requests=_pad_axis(np.asarray(cls.requests), 0, c_new, 0),
-            count=_pad_axis(np.asarray(cls.count), 0, c_new, 0),
-            tol=_pad_axis(np.asarray(cls.tol), 0, c_new, False),
-            ports=_pad_axis(_pad_axis(np.asarray(cls.ports), -1, p_new, False), 0, c_new, False),
-            groups=_pad_axis(groups, 0, c_new, g1_new - 1),
-            relax_next=_pad_axis(np.asarray(cls.relax_next), 0, c_new, -1),
-            anti_soft=_pad_axis(np.asarray(cls.anti_soft), 0, c_new, False),
-            # padded rows never place (count 0), so any root value is inert
-            root=_pad_axis(np.asarray(cls.root), 0, c_new, 0),
-        )
+    groups = np.asarray(cls.groups)
+    groups = np.where(groups >= g1_old - 1, g1_new - 1, groups)
+    cls_t = _pad_req(
+        mask_ops.ReqTensor(cls.mask, cls.defined, cls.negative, cls.gt, cls.lt),
+        k_new, v_new,
+    )
+    cls = ClassTensors(
+        mask=_pad_axis(cls_t.mask, 0, c_new, True),
+        defined=_pad_axis(cls_t.defined, 0, c_new, False),
+        negative=_pad_axis(cls_t.negative, 0, c_new, False),
+        gt=_pad_axis(cls_t.gt, 0, c_new, -np.inf),
+        lt=_pad_axis(cls_t.lt, 0, c_new, np.inf),
+        zone=_pad_axis(np.asarray(cls.zone), 0, c_new, True),
+        ct=_pad_axis(np.asarray(cls.ct), 0, c_new, True),
+        it=_pad_axis(np.asarray(cls.it), 0, c_new, True),
+        requests=_pad_axis(np.asarray(cls.requests), 0, c_new, 0),
+        count=_pad_axis(np.asarray(cls.count), 0, c_new, 0),
+        tol=_pad_axis(np.asarray(cls.tol), 0, c_new, False),
+        ports=_pad_axis(_pad_axis(np.asarray(cls.ports), -1, p_new, False), 0, c_new, False),
+        groups=_pad_axis(groups, 0, c_new, g1_new - 1),
+        relax_next=_pad_axis(np.asarray(cls.relax_next), 0, c_new, -1),
+        anti_soft=_pad_axis(np.asarray(cls.anti_soft), 0, c_new, False),
+        # padded rows never place (count 0), so any root value is inert
+        root=_pad_axis(np.asarray(cls.root), 0, c_new, 0),
+    )
 
     statics_arrays = sa._replace(
         it=_pad_req(sa.it, k_new, v_new),
@@ -2753,77 +2674,3 @@ def pad_planes(cls, statics_arrays, key_has_bounds, ex_state=None, ex_static=Non
             ),
         )
     return cls, statics_arrays, key_has_bounds, ex_state, ex_static
-
-
-# -- device-side plane finishing (docs/KERNEL_PERF.md "Layer 6") --------------
-#
-# The encode's class planes are compact (C rows); the executable wants the
-# bucket-padded layout.  With KC_ENCODE_DEVICE_FINISH=1 the pad/scatter runs
-# ON DEVICE under a small memoized jit: the host→device transfer carries the
-# exact class rows and the padded planes never exist host-side.  Fill values
-# mirror pad_planes' host branch cell for cell, so the two finishing paths
-# are bit-identical (tests/test_encode_delta.py pins it).
-
-
-def encode_device_finish_enabled() -> bool:
-    """KC_ENCODE_DEVICE_FINISH=1 opts the prepare path into device-side
-    class-plane finishing (default off: on CPU backends the device IS the
-    host, so the jit adds dispatch cost for no transfer win)."""
-    return os.environ.get("KC_ENCODE_DEVICE_FINISH", "0") == "1"
-
-
-def _jpad(a, axis, target, value):
-    cur = a.shape[axis]
-    if cur >= target:
-        return a
-    widths = [(0, 0)] * a.ndim
-    widths[axis] = (0, target - cur)
-    return jnp.pad(a, widths, constant_values=value)
-
-
-def _jwiden_mask(mask, v_new):
-    v = mask.shape[-1] - 1
-    if v >= v_new:
-        return mask
-    block = jnp.zeros(mask.shape[:-1] + (v_new - v,), dtype=mask.dtype)
-    return jnp.concatenate([mask[..., :v], block, mask[..., v:]], axis=-1)
-
-
-@functools.lru_cache(maxsize=64)
-def _cls_finish_fn(c_new: int, k_new: int, v_new: int, g1_old: int,
-                   g1_new: int, p_new: int):
-    """One jitted finisher per (bucket-target, group-extent) combination —
-    steady-state encodes reuse a single compiled program per shape bucket."""
-
-    def finish(cls):
-        mask = _jwiden_mask(cls.mask, v_new)
-        mask = _jpad(mask, -2, k_new, True)
-        groups = jnp.where(cls.groups >= g1_old - 1, g1_new - 1, cls.groups)
-        return ClassTensors(
-            mask=_jpad(mask, 0, c_new, True),
-            defined=_jpad(_jpad(cls.defined, -1, k_new, False), 0, c_new, False),
-            negative=_jpad(_jpad(cls.negative, -1, k_new, False), 0, c_new, False),
-            gt=_jpad(_jpad(cls.gt, -1, k_new, -jnp.inf), 0, c_new, -jnp.inf),
-            lt=_jpad(_jpad(cls.lt, -1, k_new, jnp.inf), 0, c_new, jnp.inf),
-            zone=_jpad(cls.zone, 0, c_new, True),
-            ct=_jpad(cls.ct, 0, c_new, True),
-            it=_jpad(cls.it, 0, c_new, True),
-            requests=_jpad(cls.requests, 0, c_new, 0),
-            count=_jpad(cls.count, 0, c_new, 0),
-            tol=_jpad(cls.tol, 0, c_new, False),
-            ports=_jpad(_jpad(cls.ports, -1, p_new, False), 0, c_new, False),
-            groups=_jpad(groups, 0, c_new, g1_new - 1),
-            relax_next=_jpad(cls.relax_next, 0, c_new, -1),
-            anti_soft=_jpad(cls.anti_soft, 0, c_new, False),
-            # padded rows never place (count 0), so any root value is inert
-            root=_jpad(cls.root, 0, c_new, 0),
-        )
-
-    return jax.jit(finish)
-
-
-def finish_class_planes_device(cls, c_new: int, k_new: int, v_new: int,
-                               g1_old: int, g1_new: int, p_new: int):
-    """Padded ClassTensors assembled on device from the compact host rows —
-    the device-finishing twin of pad_planes' host class branch."""
-    return _cls_finish_fn(c_new, k_new, v_new, g1_old, g1_new, p_new)(cls)

@@ -34,9 +34,9 @@ template) and zeroes violating cells — their pods join the leftover vector
 the orchestrator (relax/solve.py) hands to the exact repair pass.
 
 Everything below runs under ``_relax_jit`` (module-level, same idiom as
-ops.solve._solve_jit); statics are ``n_slots``, ``key_has_bounds`` and
-``packed_masks`` — exactly the compile-cache key fields they correspond to
-in utils/compilecache.relax_callable.
+ops.solve._solve_jit); statics are ``n_slots`` and ``key_has_bounds`` —
+exactly the compile-cache key fields they correspond to in
+utils/compilecache.relax_callable.
 """
 
 from __future__ import annotations
@@ -120,7 +120,6 @@ def relax_core(
     *,
     n_slots: int,
     key_has_bounds,
-    packed_masks: bool = True,
 ) -> RelaxResult:
     """Relax, round, audit, and materialize one snapshot's eligible classes.
 
@@ -133,18 +132,9 @@ def relax_core(
     runtime values so weight/knob changes never retrace."""
     sa = solve_ops.StaticArrays(*statics_arrays)
     width = sa.valid.shape[-1]  # semantic slot count V+1, pre-packing
-    if packed_masks:
-        sa = sa._replace(
-            it=mask_ops.pack_req(sa.it),
-            tmpl=mask_ops.pack_req(sa.tmpl),
-            valid=mask_ops.pack_mask(sa.valid),
-        )
-        class_tensors = class_tensors._replace(
-            mask=mask_ops.pack_mask(class_tensors.mask)
-        )
+    sa, class_tensors = solve_ops.pack_masks(sa, class_tensors)
     statics = solve_ops.Statics(
-        *sa, key_has_bounds=key_has_bounds, packed=packed_masks, mask_v=width,
-        catalog_axis=None,
+        *sa, key_has_bounds=key_has_bounds, mask_v=width, catalog_axis=None,
     )
     cls = class_tensors
     n_classes = cls.count.shape[0]
@@ -357,7 +347,7 @@ def relax_core(
     z_s = s_s - i_s * n_zones
     t_s = tsel.reshape(n_total)[gidc]
 
-    km = merged.mask[c_s, t_s]  # [N, K, W] (or [N, K, V+1] unpacked)
+    km = merged.mask[c_s, t_s]  # [N, K, W]
     kd = merged.defined[c_s, t_s]
     kn = merged.negative[c_s, t_s]
     kg = merged.gt[c_s, t_s]
@@ -370,13 +360,10 @@ def relax_core(
     viable_row = feas_z & (pp_row >= a[:, None])
     used_row = statics.tmpl_daemon[t_s] + a[:, None].astype(jnp.float32) * cls.requests[c_s]
 
-    if packed_masks:
-        kmask0 = jnp.broadcast_to(
-            jnp.asarray(mask_ops.full_words(width)),
-            (n_slots, n_keys, mask_ops.words_for(width)),
-        )
-    else:
-        kmask0 = jnp.ones((n_slots, n_keys, width), dtype=bool)
+    kmask0 = jnp.broadcast_to(
+        jnp.asarray(mask_ops.full_words(width)),
+        (n_slots, n_keys, mask_ops.words_for(width)),
+    )
     state = solve_ops.NodeState(
         used=jnp.where(sel[:, None], used_row, 0.0),
         kmask=jnp.where(sel[:, None, None], km, kmask0),
@@ -415,5 +402,5 @@ def relax_core(
 
 _relax_jit = functools.partial(
     jax.jit,
-    static_argnames=("n_slots", "key_has_bounds", "packed_masks"),
+    static_argnames=("n_slots", "key_has_bounds"),
 )(relax_core)

@@ -109,7 +109,7 @@ def _policy_weights(policy) -> np.ndarray:
     return np.asarray([1.0, 0.0, 0.0], dtype=np.float32)
 
 
-def _empty_carry_planes(prep, cls, n_slots: int, packed: bool):
+def _empty_carry_planes(prep, cls, n_slots: int):
     """(ex_state, topo, remaining) for a cold relax result — the same inert
     planes solve_core builds internally for a cold scan with no existing
     nodes, so the repair resumes over semantics identical by construction."""
@@ -124,8 +124,7 @@ def _empty_carry_planes(prep, cls, n_slots: int, packed: bool):
     ex_state = solve_ops.empty_existing_state(
         n_res, n_keys, width, n_zones, n_ct, n_ports
     )
-    if packed:
-        ex_state = ex_state._replace(kmask=mask_ops.pack_mask(ex_state.kmask))
+    ex_state = ex_state._replace(kmask=mask_ops.pack_mask(ex_state.kmask))
     topo = solve_ops.TopoCounts(
         fwd_ex=jnp.zeros((g1, 1), dtype=jnp.int32),
         inv_ex=jnp.zeros((g1, 1), dtype=jnp.int32),
@@ -181,13 +180,12 @@ def run_relax(solver, prep, cls=None, n_slots: int = 0) -> solve_ops.SolveOutput
 
     n_slots = int(n_slots or prep.n_slots)
     n_classes = int(counts.shape[0])
-    _, packed = compilecache.kernel_flags()
     mesh_axes = getattr(prep, "mesh_axes", None)
     max_iters = modes.relax_max_iters()
 
     fn = compilecache.relax_callable(
         cls, prep.statics_arrays, pol, n_slots, prep.key_has_bounds,
-        packed_masks=packed, mesh_axes=mesh_axes,
+        mesh_axes=mesh_axes,
     )
     trees = (cls, prep.statics_arrays, pol)
     if mesh_axes is not None:
@@ -212,7 +210,7 @@ def run_relax(solver, prep, cls=None, n_slots: int = 0) -> solve_ops.SolveOutput
             jnp.uint32(RELAX_SEED),
             # the jit compiles inside this call: key on the shapes too, so
             # a program that has yet to compile gets the cold budget
-            key=(compilecache.leaf_sig((cls_d, sa_d, pol_d)), n_slots, packed,
+            key=(compilecache.leaf_sig((cls_d, sa_d, pol_d)), n_slots,
                  mesh_axes),
         )
         iters, converged, violations, leftover, placed, n_used = watchdog.run(
@@ -226,9 +224,8 @@ def run_relax(solver, prep, cls=None, n_slots: int = 0) -> solve_ops.SolveOutput
             violations=int(violations), placed=int(placed),
             leftover=int(np.sum(leftover)),
         )
-        # bench/test observability: the last relax dispatch's verdict, host
-        # data only (mirrors the span attrs — bench.relax_line reports the
-        # audited-violation count from here)
+        # test observability: the last relax dispatch's verdict, host data
+        # only (mirrors the span attrs)
         solver.last_relax_stats = {
             "iters": int(iters),
             "converged": bool(converged),
@@ -243,7 +240,7 @@ def run_relax(solver, prep, cls=None, n_slots: int = 0) -> solve_ops.SolveOutput
 
     leftover = np.asarray(leftover, dtype=np.int32)
     total_leftover = int(np.sum(leftover))
-    ex_state, topo, remaining = _empty_carry_planes(prep, cls, n_slots, packed)
+    ex_state, topo, remaining = _empty_carry_planes(prep, cls, n_slots)
     g1 = int(topo.fwd_ex.shape[0])
     n_zones = int(np.asarray(cls.zone).shape[-1])
 

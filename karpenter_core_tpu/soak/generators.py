@@ -32,7 +32,7 @@ from karpenter_core_tpu.soak.trace import (
 )
 from karpenter_core_tpu.utils.retry import DeterministicRNG
 
-# the request-size palette (mirrors bench.py's diverse-pod mix)
+# the request-size palette
 SIZES: Sequence[Dict[str, str]] = (
     {"cpu": "250m", "memory": "256Mi"},
     {"cpu": "500m", "memory": "512Mi"},

@@ -1362,8 +1362,8 @@ class IncrementalSolveSession:
 
     def node_signature(self):
         """Canonical multiset of per-node class loads, labeled by stable
-        class identity — the assignment-identity view the churn bench
-        compares against a from-scratch full solve (order- and
+        class identity — the assignment-identity view churn parity checks
+        compare against a from-scratch full solve (order- and
         row-index-independent)."""
         self.settle()
         w = self._warm

@@ -957,21 +957,15 @@ class TPUSolver:
     ) -> SolvePrep:
         """Kernel inputs for one encoded snapshot, existing-node planes
         included, bucket-padded (unless KC_TPU_SHAPE_BUCKETS=0) and ready for
-        ``run_prepared``.  ``KC_BUCKET_QUANTIZE`` selects the coarser
-        powers-of-two padding ladder (ops.solve.bucket_quantize_enabled):
-        mixed-size tenants quantize into fewer distinct shape buckets, so
-        more of them fuse onto one coalesced executable (docs/SERVICE.md
-        "Solve fusion").  Splitting prepare from run is what lets the
+        ``run_prepared``.  Splitting prepare from run is what lets the
         incremental session hold a prep across reconciles and re-run it with
         a delta count vector + warm carry (docs/INCREMENTAL.md).
 
-        Two delta-native fast paths (docs/KERNEL_PERF.md "Layer 6"): when the
+        The delta-native fast path (docs/KERNEL_PERF.md "Layer 6"): when the
         snapshot's shape planes are IDENTICAL (by reference — the delta
         encode's contract) to the last prepared ones and no existing-node
         planes are needed, the previous prep is reused with only a fresh
-        padded count vector — the compact delta is all that moves.  And with
-        KC_ENCODE_DEVICE_FINISH=1 the class-plane bucket padding is assembled
-        on device under a small jit instead of host np.pad."""
+        padded count vector — the compact delta is all that moves."""
         from karpenter_core_tpu.parallel import mesh as mesh_mod
         from karpenter_core_tpu.utils import compilecache
 
@@ -994,12 +988,9 @@ class TPUSolver:
         features = solve_ops.features_with_existing(snapshot, ex_static)
         anchors = None
         if ex_state is None and pad:
-            # the quantize flag rides the anchor tuple: a mid-process flip
-            # (bench A/B legs, tests) must not serve a prep padded under the
-            # other grid
             anchors = tuple(
                 getattr(snapshot, f, None) for f in self._PREP_ANCHOR_FIELDS
-            ) + (solve_ops.bucket_quantize_enabled(),)
+            )
             cached = getattr(self, "_prep_cache", None)
             if cached is not None and all(
                 a is b for a, b in zip(cached["anchors"], anchors)
@@ -1028,8 +1019,7 @@ class TPUSolver:
         if pad:
             cls, statics_arrays, key_has_bounds, ex_state, ex_static = (
                 solve_ops.pad_planes(
-                    cls, statics_arrays, key_has_bounds, ex_state, ex_static,
-                    device_finish=solve_ops.encode_device_finish_enabled(),
+                    cls, statics_arrays, key_has_bounds, ex_state, ex_static
                 )
             )
         prep = SolvePrep(
@@ -1162,10 +1152,9 @@ class TPUSolver:
                 warm_carry is not None,
                 # executable-variant axes that recompile without moving the
                 # shape identity: a flip (KC_PIPELINE, policy toggling
-                # donation, kernel triage flags) must budget as a fresh
-                # cold key, not spike a warm EWMA into a spurious timeout
+                # donation) must budget as a fresh cold key, not spike a
+                # warm EWMA into a spurious timeout
                 donate, pipeline_mod.donation_enabled(),
-                compilecache.kernel_flags(),
             ),
             n_passes=prep.n_passes,
             features=prep.features,
@@ -1300,8 +1289,7 @@ class TPUSolver:
             ticket = self.begin_fetch(outputs, ring=ring)
         while True:
             slots = outputs.assign.shape[1]
-            # the pod count is read only once a solve ran out: the planes may
-            # live on the device (KC_ENCODE_DEVICE_FINISH)
+            # the pod count is summed only once a solve ran out
             if not self.fetch_exhausted(ticket.wait(), slots) or slots >= int(
                 np.sum(np.asarray(prep.cls.count))
             ):

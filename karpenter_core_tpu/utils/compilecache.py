@@ -60,8 +60,7 @@ _stats = {"builds": 0, "memo_hits": 0}
 # trade measurable per (bucket, mesh): real class rows the snapshot shipped
 # vs padded rows the kernel ran, and a padded-work proxy in "flops"
 # (wasted rows × slots × passes × tenants — a relative yardstick for fusion
-# tuning, not a hardware FLOP count).  Exported on /metrics and surfaced as
-# ``detail.batch_occupancy`` by bench.py's tenant line.
+# tuning, not a hardware FLOP count).  Exported on /metrics.
 from karpenter_core_tpu.metrics import REGISTRY
 
 BATCH_OCCUPANCY = REGISTRY.gauge(
@@ -335,8 +334,6 @@ def solve_callable(
     ex_static=None,
     n_passes: int = 1,
     features=None,
-    fuse_zones: bool = True,
-    packed_masks: bool = True,
     warm_carry=None,
     repair_plan=None,
     mesh_axes=None,
@@ -391,8 +388,6 @@ def solve_callable(
         tuple(key_has_bounds),
         n_passes,
         tuple(features),
-        fuse_zones,
-        packed_masks,
         has_ex,
         has_warm,
         donate_carry,
@@ -423,8 +418,8 @@ def solve_callable(
     try:
         return _build_and_memo(key, cls, statics_arrays, n_slots,
                                key_has_bounds, ex_state, ex_static, n_passes,
-                               features, fuse_zones, packed_masks, warm_carry,
-                               repair_plan, mesh_axes, donate_carry)
+                               features, warm_carry, repair_plan, mesh_axes,
+                               donate_carry)
     finally:
         with _lock:
             _in_flight.pop(key, None)
@@ -432,7 +427,7 @@ def solve_callable(
 
 
 def _base_solve_fn(has_warm, has_ex, n_slots, key_has_bounds, n_passes,
-                   features, fuse_zones, packed_masks, catalog_axis=None):
+                   features, catalog_axis=None):
     """The positional-signature solve body for one variant: (cls, statics[,
     ...]) matching how callers invoke the memoized executable.
     ``catalog_axis`` threads the mesh axis name into solve_core's exact
@@ -445,27 +440,24 @@ def _base_solve_fn(has_warm, has_ex, n_slots, key_has_bounds, n_passes,
         # passed separately because its tol/vol rows are per-class
         return lambda c, s, exst, w, rp: solve_ops.solve_core(
             c, s, n_slots, key_has_bounds, None, exst, n_passes=n_passes,
-            features=features, fuse_zones=fuse_zones,
-            packed_masks=packed_masks, warm_carry=w, repair_plan=rp,
+            features=features, warm_carry=w, repair_plan=rp,
             catalog_axis=catalog_axis,
         )
     if has_ex:
         return lambda c, s, exs, exst: solve_ops.solve_core(
             c, s, n_slots, key_has_bounds, exs, exst, n_passes=n_passes,
-            features=features, fuse_zones=fuse_zones,
-            packed_masks=packed_masks, catalog_axis=catalog_axis,
+            features=features, catalog_axis=catalog_axis,
         )
     return lambda c, s: solve_ops.solve_core(
         c, s, n_slots, key_has_bounds, n_passes=n_passes,
-        features=features, fuse_zones=fuse_zones, packed_masks=packed_masks,
-        catalog_axis=catalog_axis,
+        features=features, catalog_axis=catalog_axis,
     )
 
 
 def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
                     ex_state, ex_static, n_passes, features=None,
-                    fuse_zones=True, packed_masks=True, warm_carry=None,
-                    repair_plan=None, mesh_axes=None, donate_carry=False):
+                    warm_carry=None, repair_plan=None, mesh_axes=None,
+                    donate_carry=False):
     """Build one executable for ``key``: export-cache load (or trace+export),
     then AOT compile, then memoize.  Callers hold the key's in-flight slot.
     Mesh variants (``mesh_axes``) build jit(shard_map(...)) instead and skip
@@ -495,11 +487,10 @@ def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
 
         base_axis = _base_solve_fn(
             has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-            fuse_zones, packed_masks, catalog_axis=mesh_axes[0][0],
+            catalog_axis=mesh_axes[0][0],
         )
         base_plain = _base_solve_fn(
             has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-            fuse_zones, packed_masks,
         )
         fn = mesh_mod.sharded_solve_callable(
             mesh_axes, base_axis, base_plain, structs,
@@ -515,7 +506,6 @@ def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
         # memo + XLA persistent cache keep it warm
         base = jax.jit(_base_solve_fn(
             has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-            fuse_zones, packed_masks,
         ), donate_argnums=donate_argnums)
         compiled = base.lower(*structs).compile()
         with _lock:
@@ -534,7 +524,6 @@ def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
     if fn is None:
         base = jax.jit(_base_solve_fn(
             has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-            fuse_zones, packed_masks,
         ))
         exported = jax.export.export(base)(*structs)
         os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -558,7 +547,6 @@ def relax_callable(
     pol,
     n_slots: int,
     key_has_bounds,
-    packed_masks: bool = True,
     mesh_axes=None,
 ):
     """The relax-family executable (karpenter_core_tpu/relax): the
@@ -587,7 +575,6 @@ def relax_callable(
         _resolved_backend(),
         n_slots,
         tuple(key_has_bounds),
-        packed_masks,
         mesh_axes,
         leaf_sig(cls),
         leaf_sig(statics_arrays),
@@ -602,7 +589,6 @@ def relax_callable(
         relax_kernel._relax_jit,
         n_slots=int(n_slots),
         key_has_bounds=tuple(key_has_bounds),
-        packed_masks=bool(packed_masks),
     )
     with _lock:
         _memo[key] = fn
@@ -648,7 +634,6 @@ def batched_solve_callable(
     solo solve (tests/test_tenant_service.py)."""
     import jax
 
-    fuse_zones, packed_masks = kernel_flags()
     features = snap_features(features)
     has_warm = warm_carry is not None
     has_ex = ex_state is not None and not has_warm
@@ -661,8 +646,6 @@ def batched_solve_callable(
         tuple(key_has_bounds),
         n_passes,
         tuple(features),
-        fuse_zones,
-        packed_masks,
         has_ex,
         mesh_axes,
         leaf_sig(cls),
@@ -679,7 +662,6 @@ def batched_solve_callable(
             return fn
     base = _base_solve_fn(
         has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-        fuse_zones, packed_masks,
     )
     if has_warm:
         solo_args = (cls, statics_arrays, ex_static, warm_carry, repair_plan)
@@ -701,16 +683,6 @@ def batched_solve_callable(
         _memo[key] = fn
         _stats["builds"] += 1
     return fn
-
-
-def kernel_flags():
-    """(fuse_zones, packed_masks) process defaults: both on, individually
-    disengageable for triage via KC_KERNEL_FUSE_ZONES=0 /
-    KC_KERNEL_PACKED_MASKS=0 (docs/KERNEL_PERF.md)."""
-    return (
-        os.environ.get("KC_KERNEL_FUSE_ZONES", "1") != "0",
-        os.environ.get("KC_KERNEL_PACKED_MASKS", "1") != "0",
-    )
 
 
 def resolve_mesh_axes(mesh_axes, statics_arrays):
@@ -791,7 +763,6 @@ def run_solve(
     from karpenter_core_tpu.ops import solve as solve_ops
     from karpenter_core_tpu.utils import pipeline as pipeline_mod
 
-    fuse_zones, packed_masks = kernel_flags()
     features = snap_features(features)
     mesh_axes = resolve_mesh_axes(mesh_axes, statics_arrays)
     if donate_carry == "auto":
@@ -832,15 +803,15 @@ def run_solve(
             upload = pool.submit(_upload, (cls, statics_arrays, ex_state, ex_static))
             fn = solve_callable(
                 cls, statics_arrays, n_slots, key_has_bounds, ex_state, ex_static,
-                n_passes, features, fuse_zones, packed_masks, warm_carry,
-                repair_plan, mesh_axes, donate_carry,
+                n_passes, features, warm_carry, repair_plan, mesh_axes,
+                donate_carry,
             )
             cls, statics_arrays, ex_state, ex_static = upload.result()
         if warm_carry is not None:
             out = fn(cls, statics_arrays, ex_static, warm_carry, repair_plan)
             # donation effectiveness ledger: a donated buffer is consumed at
             # dispatch; a live host view (or an undonated variant) degrades
-            # to a realloc, which bench.pipeline_line surfaces
+            # to a realloc (pipeline.stats()["donation_reallocs"])
             probe = getattr(
                 getattr(warm_carry, "state", None), "used", None
             )

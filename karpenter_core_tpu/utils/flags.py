@@ -4,8 +4,8 @@ Every ``KC_*`` flag read anywhere in the package MUST have a row here and a
 row in the docs table (docs/FLAGS.md) — the ``env-flags`` analysis pass
 (docs/ANALYSIS.md) enforces both directions: an unregistered read and a
 registry row no code reads are both gate failures.  Harness-side flags
-(KC_BENCH_*, KC_PERF_GATE_STRICT, KC_CHAOS_* seeds read by tests/tools) are
-deliberately out of band: this table is the runtime surface operators tune.
+(the KC_CHAOS_SEED / KC_SOAK_SEED seeds read by tests/tools) are deliberately
+out of band: this table is the runtime surface operators tune.
 
 The registry is DATA, parsed by the analysis pass without importing this
 module; keep ``FLAGS`` a plain dict literal of ``flag -> one-line effect``.
@@ -20,10 +20,6 @@ from typing import Dict
 
 FLAGS: Dict[str, str] = {
     # -- solver kernel + encode ------------------------------------------------
-    "KC_BUCKET_QUANTIZE": "pow2 shape-bucket ladder for cross-tenant fusion (padded FLOPs for fewer executables)",
-    "KC_ENCODE_DEVICE_FINISH": "force device completion at encode boundaries (A/B pin for the async pipeline)",
-    "KC_KERNEL_FUSE_ZONES": "fuse the per-zone kernel phases into one dispatch",
-    "KC_KERNEL_PACKED_MASKS": "bit-packed compatibility masks inside the scan kernel",
     "KC_TPU_SHAPE_BUCKETS": "explicit shape-bucket edges for the compile cache (comma-separated pod counts)",
     "KC_TPU_COMPILE_CACHE": "directory for what the package persists (exported StableHLO, XLA cache, journal, leases)",
     "KC_TPU_KERNEL": "select the operator's solver kernel implementation",

@@ -36,7 +36,7 @@ Buffer donation rides the same switch: ``donation_enabled()`` gates the
 ``donate_argnums`` solve variants (utils/compilecache, parallel/mesh) that
 let steady-state churn repairs reuse the warm carry's device memory instead
 of reallocating per tick.  ``record_donation`` keeps the effectiveness
-ledger (``donation_reallocs`` in bench.py's ``pipeline_line``).
+ledger (``stats()["donated"]`` / ``["donation_reallocs"]``).
 
 ``KC_PIPELINE=0`` switches all of it off and restores the serial loop
 bit-for-bit; ``KC_PIPELINE_DEPTH`` (default 2) sizes the ring.
@@ -91,7 +91,7 @@ _stats = {
     "tickets_open": 0,
 }
 # last completed fetch's overlap record (provisioning surfaces it as the
-# soak probe ``tick_overlap_s``; bench reads it per tick)
+# soak probe ``tick_overlap_s``)
 _last_overlap: Dict[str, float] = {"hidden_s": 0.0, "exposed_s": 0.0}
 
 

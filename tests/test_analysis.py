@@ -1123,7 +1123,6 @@ class TestRetraceManifest:
     def test_manifest_shape(self):
         manifest = json.loads(MANIFEST_PATH.read_text())
         assert int(manifest["default_budget"]) > 0
-        assert int(manifest["bench_cold_compiles"]) > 0
         assert isinstance(manifest.get("tests", {}), dict)
         for nodeid, budget in manifest.get("tests", {}).items():
             assert nodeid.startswith("tests/"), nodeid

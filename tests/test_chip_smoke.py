@@ -2,18 +2,16 @@
 
 The smoke's verdict must fail on every quiet way off the device; the compile
 cache must land where the environment (or the fixed in-checkout default)
-says; a build error must surface instead of rerouting the solve; bench.py's
-top level must never hold the chip while it starts a child.  (The end-to-end
-CPU dry run of the smoke itself is tests/test_smoke_dry_run.py: it compiles
-every leg, so it sorts after the cheap suites.)
+says; a build error must surface instead of rerouting the solve; the input
+builders give what they are asked for; ``make presubmit`` names only what
+exists.  (The end-to-end CPU dry run of the smoke itself is
+tests/test_smoke_dry_run.py: it compiles every leg, so it sorts after the
+cheap suites.)
 """
 
-import ast
-import json
 import os
 import subprocess
 import sys
-import textwrap
 
 import pytest
 
@@ -181,74 +179,82 @@ class TestNoHiddenFallback:
         assert "_solve_jit" not in inspect.getsource(compilecache.run_solve)
 
 
-class TestBenchOneProcessPerChip:
-    def test_top_level_never_touches_jax_and_runs_children_in_sequence(self):
-        """A parent that has touched JAX holds the chip: the top level must
-        start every measuring child while still off JAX, one at a time, and
-        exit non-zero when a phase failed."""
-        script = textwrap.dedent("""
-            import json, subprocess, sys, types
-            import bench
+class TestBuilders:
+    """The input builders the smoke's legs (and two slow tests) share."""
 
-            spawned = []
+    def test_pod_mix_is_a_pure_function_of_its_seed(self):
+        import random
 
-            def fake_run(cmd, **kw):
-                assert "jax" not in sys.modules, "top level imported jax"
-                args = cmd[2:]
-                spawned.append(args)
-                if "--measure" in args:
-                    out = {"metric": "m", "value": 1.0, "detail": {
-                        "platform": "tpu", "device_kind": "TPU v5 lite",
-                        "device_count": 4, "failed_phases": []}}
-                elif "--restart-probe" in args:
-                    out = {"restart_cold_s": 2.5, "scheduled": 1}
-                else:
-                    k = int(args[args.index("--sharded-probe") + 1])
-                    if k == FAIL_SIZE:
-                        return types.SimpleNamespace(
-                            returncode=1, stdout="", stderr="boom")
-                    out = {"mesh_devices": k, "solve_s": 1.0 / k,
-                           "scheduled": 9, "failed": 0, "nodes": 3}
-                return types.SimpleNamespace(
-                    returncode=0, stdout=json.dumps(out) + "\\n", stderr="")
+        from karpenter_core_tpu.models.snapshot import classify_pods
 
-            bench.subprocess.run = fake_run
-            rc = bench.main(50000, 1000)
-            print(json.dumps({"rc": rc, "spawned": spawned}))
-        """)
-        for fail_size, want_rc in ((0, 0), (2, 1)):
-            proc = subprocess.run(
-                [sys.executable, "-c", f"FAIL_SIZE = {fail_size}\n" + script],
-                capture_output=True, text=True, timeout=60, cwd=REPO,
-            )
-            assert proc.returncode == 0, proc.stderr
-            bench_line, report = proc.stdout.strip().splitlines()[-2:]
-            report = json.loads(report)
-            assert report["rc"] == want_rc
-            flags = [next(a for a in args if a.startswith("--"))
-                     for args in report["spawned"]]
-            # main run, then restart probe, then mesh sizes 1/2/4 (8 trimmed
-            # to the 4 devices the main run stamped)
-            assert flags == ["--measure", "--restart-probe"] + ["--sharded-probe"] * 3
-            detail = json.loads(bench_line)["detail"]
-            assert detail["cold_s"] == 2.5 and detail["platform"] == "tpu"
-            assert detail["failed_phases"] == (["sharded"] if fail_size else [])
+        def shapes(pods):
+            return [
+                (sorted(p.metadata.labels.items()),
+                 sorted(p.spec.containers[0].resources.requests.items()),
+                 len(p.spec.topology_spread_constraints or ()),
+                 p.spec.affinity is not None)
+                for p in pods
+            ]
 
-    def test_only_run_child_starts_processes(self):
-        """The measuring run itself (``--measure``) starts no child: every
-        use of subprocess/os.exec* in bench.py sits inside ``run_child``."""
-        with open(os.path.join(REPO, "bench.py")) as f:
-            tree = ast.parse(f.read())
-        offenders = []
-        for fn in [n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)]:
-            for node in ast.walk(fn):
-                if (
-                    isinstance(node, ast.Attribute)
-                    and isinstance(node.value, ast.Name)
-                    and (node.value.id == "subprocess" or (
-                        node.value.id == "os" and node.attr.startswith(
-                            ("exec", "spawn", "fork", "system", "popen"))))
-                    and fn.name != "run_child"
-                ):
-                    offenders.append((fn.name, node.attr))
-        assert offenders == []
+        a = chip_smoke.pod_mix(140, random.Random(7))
+        b = chip_smoke.pod_mix(140, random.Random(7))
+        assert len(a) == len(b) == 140
+        assert shapes(a) == shapes(b)
+        assert len(classify_pods(a)) == len(classify_pods(b))
+        # another seed draws other sizes; no rng is the fixed four-size cycle
+        assert shapes(chip_smoke.pod_mix(140, random.Random(8))) != shapes(a)
+        assert len(classify_pods(chip_smoke.pod_mix(140))) == 13
+
+    def test_consolidation_cluster_yields_the_asked_nodes_and_pods(self):
+        from karpenter_core_tpu.cloudprovider import fake as fake_cp
+
+        env, candidates = chip_smoke.consolidation_cluster(
+            6, 3, fake_cp.instance_types(24))
+        assert len(env.kube.list_nodes()) == 6
+        assert len(candidates) == 6
+        assert all(len(c.pods) == 3 for c in candidates)
+        costs = [c.disruption_cost for c in candidates]
+        assert costs == sorted(costs)
+
+    def test_build_inputs_yields_the_asked_catalog_and_provisioners(self):
+        solver, pods = chip_smoke.build_inputs(70, 24, n_provisioners=3)
+        assert len(pods) == 70
+        assert len(solver.cloud_provider.get_instance_types(None)) == 24
+        weights = [p.spec.weight for p in solver.provisioners]
+        assert weights == [3, 2, 1]
+
+
+class TestMakefile:
+    def test_presubmit_names_only_targets_and_scripts_that_exist(self):
+        """Every prerequisite, down from ``presubmit``, is a target of the
+        Makefile, and every script or test file a recipe names exists: a
+        gate must have something to gate on."""
+        import re
+
+        with open(os.path.join(REPO, "Makefile")) as f:
+            text = f.read()
+        targets, recipes, current = {}, {}, None
+        for line in text.splitlines():
+            m = re.match(r"^([A-Za-z][\w-]*):([^#=]*)", line)
+            if m:
+                current = m.group(1)
+                targets[current] = m.group(2).split()
+                recipes[current] = []
+            elif line.startswith("\t") and current:
+                recipes[current].append(line.strip())
+        assert "presubmit" in targets
+        seen, todo = set(), ["presubmit"]
+        while todo:
+            name = todo.pop()
+            if name in seen:
+                continue
+            seen.add(name)
+            assert name in targets, f"prerequisite {name!r} is no target"
+            todo.extend(targets[name])
+            for recipe in recipes[name]:
+                for word in recipe.split():
+                    if re.fullmatch(r"[\w./-]+\.py", word):
+                        assert os.path.exists(os.path.join(REPO, word)), (
+                            f"{name}: {word} does not exist")
+        phony = re.search(r"^\.PHONY:(.*)$", text, re.M).group(1).split()
+        assert set(phony) == set(targets), set(phony) ^ set(targets)

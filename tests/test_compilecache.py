@@ -212,6 +212,29 @@ class TestShapeBuckets:
         assert solve_ops.bucket(3, floor=2) == 3
         assert solve_ops.bucket(5, floor=4) == 6
 
+    def test_bucket_rungs_are_pow2_and_one_and_a_half_pow2(self):
+        """The grid is 2, 3, 4, 6, 8, 12, ...: every rung a power of two or
+        1.5x one, none skipped, whatever the floor."""
+        rungs = sorted({solve_ops.bucket(n, floor=2) for n in range(1, 4097)})
+        want, b = [], 2
+        while b <= 4096:
+            want += [b, b * 3 // 2]
+            b *= 2
+        assert rungs == [r for r in want if r <= 4096]
+        for floor in (2, 4, 8):
+            assert sorted(
+                {solve_ops.bucket(n, floor=floor) for n in range(1, 4097)}
+            ) == [r for r in want if floor <= r <= 4096]
+
+    def test_bucket_is_monotone_and_covers_its_argument(self):
+        prev = 0
+        for n in range(1, 4097):
+            b = solve_ops.bucket(n, floor=2)
+            assert b >= n and b >= prev, n
+            assert solve_ops.bucket(b, floor=2) == b, n  # a rung maps to itself
+            assert n < 2 or 2 * b < 3 * n, n  # padding stays under one half
+            prev = b
+
     def test_padding_parity(self, cache_dir, monkeypatch):
         from karpenter_core_tpu.apis.objects import LabelSelector, TopologySpreadConstraint
         from karpenter_core_tpu.testing import make_pod
@@ -299,72 +322,3 @@ class TestShapeBuckets:
             )
         assert sigs["0"] == sigs["1"]
         assert sigs["1"][1], "some pods should land on the existing node"
-
-
-class TestBucketQuantize:
-    """KC_BUCKET_QUANTIZE (PR 18, docs/SERVICE.md "Solve fusion"): the
-    opt-in power-of-two ladder must STRICTLY REDUCE distinct executable
-    keys over a mixed-size tenant population, and stay byte-identical to
-    the default grid when unset."""
-
-    def test_quantized_grid_factors_through_default(self, monkeypatch):
-        """Every default rung maps to exactly one quantized rung (the
-        power-of-two grid is a subset grid), so over ANY size mix the
-        distinct-bucket count under quantization can only shrink."""
-        rung_map = {}
-        for n in range(1, 4097):
-            monkeypatch.setenv("KC_BUCKET_QUANTIZE", "0")
-            b = solve_ops.bucket(n)
-            monkeypatch.setenv("KC_BUCKET_QUANTIZE", "1")
-            q = solve_ops.bucket(n)
-            assert q >= n and q >= b, n
-            assert q & (q - 1) == 0, (n, q)  # always a power of two
-            assert rung_map.setdefault(b, q) == q, (n, b, q)
-
-    def test_mixed_tenant_sizes_strictly_fewer_executable_keys(
-        self, cache_dir, monkeypatch
-    ):
-        """The fusion_line sweep's shape, pinned: class counts straddling
-        (1.5x-rung, next-pow2) pairs like (10, 14) -> default buckets
-        {12, 16} merge into {16} under the quantized ladder, so the
-        mixed population lands in STRICTLY FEWER coalescer rendezvous
-        keys (= distinct batched executables)."""
-        from karpenter_core_tpu.service.tenant import bucket_key
-        from karpenter_core_tpu.testing import make_pod
-
-        monkeypatch.setenv("KC_TPU_SHAPE_BUCKETS", "1")
-        provider = fake_cp.FakeCloudProvider(fake_cp.instance_types(10))
-        mixed = [5, 7, 10, 14, 20, 28]
-
-        def keys_under(flag: str):
-            monkeypatch.setenv("KC_BUCKET_QUANTIZE", flag)
-            found = set()
-            for n_classes in mixed:
-                # fresh solver per prep: the prep cache anchors on the
-                # quantize flag, stale preps must not leak across legs
-                solver = TPUSolver(provider, [make_provisioner()])
-                ingest = PodIngest()
-                ingest.add_all([
-                    make_pod(requests={"cpu": f"{100 + 25 * j}m"})
-                    for j in range(n_classes)
-                    for _ in range(12)
-                ])
-                found.add(bucket_key(solver.prepare_encoded(
-                    solver.encode(ingest))))
-            return found
-
-        default_keys = keys_under("0")
-        quantized_keys = keys_under("1")
-        assert len(quantized_keys) < len(default_keys), (
-            sorted(default_keys), sorted(quantized_keys),
-        )
-
-    def test_unset_is_byte_identical_to_disabled(self, monkeypatch):
-        monkeypatch.delenv("KC_BUCKET_QUANTIZE", raising=False)
-        assert solve_ops.bucket_quantize_enabled() is False
-        unset_grid = [solve_ops.bucket(n) for n in range(1, 513)]
-        monkeypatch.setenv("KC_BUCKET_QUANTIZE", "0")
-        assert solve_ops.bucket_quantize_enabled() is False
-        assert [solve_ops.bucket(n) for n in range(1, 513)] == unset_grid
-        monkeypatch.setenv("KC_BUCKET_QUANTIZE", "1")
-        assert solve_ops.bucket_quantize_enabled() is True

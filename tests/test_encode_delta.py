@@ -405,23 +405,6 @@ class TestPreparedFastPaths:
         prep = solver.prepare_encoded(snap, state_nodes=[StateNode(node)])
         assert prep.ex_state is not None
 
-    def test_device_finish_bit_identical(self, monkeypatch):
-        solver = _solver(n_types=6)
-        ingest = PodIngest()
-        ingest.add_all([make_pod(requests={"cpu": "250m"}) for _ in range(16)])
-        snap = solver.encode(ingest)
-        host_prep = solver.prepare_encoded(snap)
-        monkeypatch.setenv("KC_ENCODE_DEVICE_FINISH", "1")
-        dev_solver = _solver(n_types=6)
-        snap2 = dev_solver.encode(ingest)
-        dev_prep = dev_solver.prepare_encoded(snap2)
-        for f in host_prep.cls._fields:
-            host_arr = np.asarray(getattr(host_prep.cls, f))
-            dev_arr = np.asarray(getattr(dev_prep.cls, f))
-            assert host_arr.dtype == dev_arr.dtype, f
-            assert host_arr.shape == dev_arr.shape, f
-            assert np.array_equal(host_arr, dev_arr), f
-
 
 class TestSoakIngestProbe:
     def test_probe_registered_advisory(self):
@@ -437,9 +420,9 @@ class TestScaleParity:
     def test_100k_encode_parity(self):
         """The acceptance-scale cross-check: 100k pods x 2k types, delta vs
         from-scratch encodes bit-identical after a churn tick."""
-        import bench as bench_mod
+        import chip_smoke
 
-        solver, pods = bench_mod.build_inputs(100_000, 2_000, n_provisioners=5)
+        solver, pods = chip_smoke.build_inputs(100_000, 2_000, n_provisioners=5)
         ingest = PodIngest()
         ingest.add_all(pods)
         solver.encode(ingest)
@@ -454,6 +437,6 @@ class TestScaleParity:
             ingest.add(pod)
         snap = solver.encode(ingest)
         assert snap.encode_reused
-        fresh_solver, _ = bench_mod.build_inputs(100, 2_000, n_provisioners=5)
+        fresh_solver, _ = chip_smoke.build_inputs(100, 2_000, n_provisioners=5)
         snap_fresh = fresh_solver.encode(ingest)
         _assert_snapshots_identical(snap, snap_fresh)

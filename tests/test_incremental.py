@@ -12,7 +12,7 @@ Three layers:
   - ``IncrementalSolveSession`` parity: over randomized steady-churn event
     sequences the delta lineage's final per-node assignment multiset must be
     IDENTICAL to a from-scratch full solve of the same population, at small N
-    in tier-1 (kernel-scale churn is the bench's churn_line and the slow
+    in tier-1 (kernel-scale churn is chip_smoke.churn_line under the slow
     marker below).  KC_SOLVER_INCREMENTAL=0 keeps the old path as the
     degenerate case.
 """
@@ -614,16 +614,16 @@ class TestChurnSteadySmoke:
 
 @pytest.mark.slow
 class TestKernelScaleChurn:
-    def test_bench_churn_line_meets_acceptance(self):
+    def test_churn_line_meets_acceptance(self):
         """The ISSUE 7 acceptance at kernel scale: warm repair ≥ 2x the full
-        re-solve with identical assignments, through bench.churn_line."""
-        import bench
+        re-solve with identical assignments, through chip_smoke.churn_line."""
+        import chip_smoke
 
-        solver, pods = bench.build_inputs(20000, 40, n_provisioners=5)
+        solver, pods = chip_smoke.build_inputs(20000, 40, n_provisioners=5)
         ingest = PodIngest()
         ingest.add_all(pods)
         solver.warmup()
-        line = bench.churn_line(solver, ingest, churn_fraction=0.02, ticks=5)
+        line = chip_smoke.churn_line(solver, ingest, churn_fraction=0.02, ticks=5)
         assert line["identical_assignments"] is True
         assert line["speedup"] >= 2.0, line
         assert line["modes"].get("delta", 0) >= 4

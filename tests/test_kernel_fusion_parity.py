@@ -1,26 +1,17 @@
-"""Kernel-vs-kernel parity for the phase-fused, statically-pruned, bit-packed
-solve program (docs/KERNEL_PERF.md).
+"""Parity for the phase-fused, statically-pruned, bit-packed solve program
+(docs/KERNEL_PERF.md).
 
-Three independent rewrites of the solve kernel must be bit-for-bit
-output-preserving, each fuzzed against its reference form on randomized
-snapshots:
-
-  - ``fuse_zones``: the batched multi-zone committal block vs the sequential
-    per-zone ``run_phase`` sweeps (zone spread quotas, required zonal anti)
-  - ``packed_masks``: uint32-word mask algebra (AND + popcount) vs the
-    bool-plane einsum path
+  - the mask-op algebra, standalone (fast, no kernel compile): every
+    ops/masks.py operation must agree packed vs bool on random requirement
+    tensors, bounds included — the bool layout is what models/snapshot.py
+    encodes, the packed one what the kernel runs on
+  - the zone-committal block (``committal_block``: zone-spread quotas,
+    required zonal anti-affinity) against the HOST ORACLE
+    (solver/scheduler.py) through the real controller, at tier-1 size
   - ``features``: static phase pruning vs the all-phases trace — a pruned
-    family must have been a provable no-op
-
-The pure mask-op algebra is additionally fuzzed standalone (fast, no kernel
-compile): every ops/masks.py operation must agree packed vs unpacked on
-random requirement tensors, bounds included.
-
-The kernel-level comparisons compile 2 full solve programs per case, so they
-carry the ``slow`` marker (excluded from the budgeted tier-1 run, included in
-``make test-all``); the production kernel configuration itself is exercised
-against the HOST oracle throughout tier-1 (tests/test_parity_fuzz.py and the
-topology matrices), so a semantic regression cannot hide behind the marker.
+    family must have been a provable no-op.  These compile 2 full solve
+    programs per case, so they carry the ``slow`` marker (excluded from the
+    budgeted tier-1 run, included in ``make test-all``)
 """
 
 import random
@@ -42,6 +33,8 @@ from karpenter_core_tpu.ops import masks as mask_ops
 from karpenter_core_tpu.ops import solve as solve_ops
 from karpenter_core_tpu.solver.tpu import TPUSolver
 from karpenter_core_tpu.testing import make_pod, make_provisioner
+from karpenter_core_tpu.testing.harness import expect_provisioned, make_environment
+from karpenter_core_tpu.testing.validator import expect_valid_placements
 
 ZONE = labels_api.LABEL_TOPOLOGY_ZONE
 HOSTNAME = labels_api.LABEL_HOSTNAME
@@ -163,7 +156,108 @@ def test_pack_unpack_roundtrip():
             assert not np.any(top)
 
 
-# -- kernel parity: fused / packed / pruned vs the reference trace ------------
+# -- the committal block vs the host oracle ------------------------------------
+
+
+def _zone_spread(rng: random.Random, app: str):
+    return dict(
+        labels={"app": app}, requests=rng.choice(SIZES),
+        topology_spread=[TopologySpreadConstraint(
+            max_skew=rng.choice((1, 2)), topology_key=ZONE,
+            label_selector=LabelSelector(match_labels={"app": app}))],
+    )
+
+
+def _zone_anti(rng: random.Random, app: str):
+    return dict(
+        labels={"app": app}, requests=rng.choice(SIZES),
+        pod_anti_affinity=[PodAffinityTerm(
+            topology_key=ZONE,
+            label_selector=LabelSelector(match_labels={"app": app}))],
+    )
+
+
+def _committal_batch(family: str, seed: int):
+    """<= 300 pods: one or two classes of each committal family asked for,
+    among generic, hostname-spread and hostname-anti classes (the families
+    whose single-batch counts equal the host's exactly)."""
+    rng = random.Random(seed)
+    pods = []
+    if family in ("zone-spread", "both"):
+        for i in range(rng.randint(1, 2)):
+            kwargs = _zone_spread(rng, f"zs{i}")
+            pods.extend(make_pod(**kwargs) for _ in range(rng.randint(7, 60)))
+    if family in ("zone-anti", "both"):
+        for i in range(rng.randint(1, 2)):
+            kwargs = _zone_anti(rng, f"za{i}")
+            pods.extend(make_pod(**kwargs) for _ in range(rng.randint(2, 6)))
+    for i in range(rng.randint(2, 4)):
+        labels = {"app": f"c{i}"}
+        kwargs = dict(labels=labels, requests=rng.choice(SIZES))
+        shape = rng.random()
+        if shape < 0.3:
+            kwargs["topology_spread"] = [TopologySpreadConstraint(
+                max_skew=rng.choice((1, 2)), topology_key=HOSTNAME,
+                label_selector=LabelSelector(match_labels=dict(labels)))]
+        elif shape < 0.5:
+            kwargs["pod_anti_affinity"] = [PodAffinityTerm(
+                topology_key=HOSTNAME,
+                label_selector=LabelSelector(match_labels=dict(labels)))]
+        pods.extend(make_pod(**kwargs) for _ in range(rng.randint(2, 40)))
+    assert len(pods) <= 300
+    return pods
+
+
+def _provision(family: str, seed: int, use_kernel: bool, batches: int):
+    """(scheduled per class, failed, nodes) after up to ``batches``
+    reconciles of one batch through the real controller, every placement
+    checked by the independent validity oracle."""
+    env = make_environment(instance_types=fake_cp.instance_types(16))
+    env.kube.create(make_provisioner(name="default"))
+    env.provisioning.use_tpu_kernel = use_kernel
+    env.provisioning.tpu_kernel_min_pods = 1
+    pods = _committal_batch(family, seed)
+    scheduled = {}
+    for batch in range(batches):
+        if batch:
+            env.make_all_nodes_ready()
+            env.clock.step(21)
+        result = expect_provisioned(env, *pods)
+        expect_valid_placements(env, pods)
+        placed = [p for p in pods if result[p.uid] is not None]
+        for pod in placed:
+            app = pod.metadata.labels["app"]
+            scheduled[app] = scheduled.get(app, 0) + 1
+        if not placed:
+            break
+    failed = len(pods) - sum(scheduled.values())
+    return scheduled, failed, len(env.kube.list_nodes())
+
+
+@pytest.mark.compile
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("family", ["zone-spread", "zone-anti", "both"])
+def test_committal_block_matches_host_oracle(family, seed):
+    """The kernel's ONE batch against the host oracle on scheduled (per
+    class), failed and node count.  Zone spread: the host's one batch,
+    all three equal.  Required zonal anti-affinity: the host admits one
+    member a batch as each node registers its zone
+    (topology_test.go:1879-1923) where the committal block places one member
+    per admissible zone at once, so the oracle is the host reconciled until
+    it stops moving — the same fixpoint, reached over batches, each of which
+    may launch a node the kernel's single batch shared."""
+    anti = family != "zone-spread"
+    host = _provision(family, seed, use_kernel=False, batches=6 if anti else 1)
+    kernel = _provision(family, seed, use_kernel=True, batches=1)
+    assert kernel[0] == host[0], f"scheduled: kernel={kernel[0]} host={host[0]}"
+    assert kernel[1] == host[1], f"failed: kernel={kernel[1]} host={host[1]}"
+    if anti:
+        assert kernel[2] <= host[2], f"nodes: kernel={kernel[2]} host={host[2]}"
+    else:
+        assert kernel[2] == host[2], f"nodes: kernel={kernel[2]} host={host[2]}"
+
+
+# -- kernel parity: pruned vs the all-phases trace ------------------------------
 
 
 def _random_batch(rng: random.Random, with_ports: bool = False):
@@ -228,10 +322,9 @@ def _assert_same(ref, got, label):
 @pytest.mark.compile
 @pytest.mark.slow
 @pytest.mark.parametrize("seed", range(4))
-def test_fused_and_packed_kernel_matches_reference(seed):
-    """Production configuration (features + fused zones + packed masks) must
-    produce SolveOutputs identical to the unpruned, sequential, bool-mask
-    reference trace on randomized snapshots."""
+def test_pruned_kernel_matches_all_phases_trace(seed):
+    """The snapshot's phase plan (``features``) must produce SolveOutputs
+    identical to the unpruned trace on randomized snapshots."""
     rng = random.Random(1000 + seed)
     provider = fake_cp.FakeCloudProvider(fake_cp.instance_types(16))
     solver = TPUSolver(provider, [make_provisioner()])
@@ -241,55 +334,16 @@ def test_fused_and_packed_kernel_matches_reference(seed):
     n_slots = solve_ops.estimate_slots(snap)
     ft = solve_ops.snapshot_features(snap)
 
-    ref = _solve_variant(cls, sa, n_slots, khb, snap.scan_passes,
-                         features=None, fuse_zones=False, packed_masks=False)
-    prod = _solve_variant(cls, sa, n_slots, khb, snap.scan_passes,
-                          features=ft, fuse_zones=True, packed_masks=True)
-    _assert_same(ref, prod, f"seed {seed} production-vs-reference")
+    ref = _solve_variant(cls, sa, n_slots, khb, snap.scan_passes, features=None)
+    prod = _solve_variant(cls, sa, n_slots, khb, snap.scan_passes, features=ft)
+    _assert_same(ref, prod, f"seed {seed} pruned-vs-all-phases")
 
 
 @pytest.mark.compile
 @pytest.mark.slow
-def test_fused_zone_block_matches_sequential_alone():
-    """Isolate the fuse_zones axis: same features, same mask layout."""
-    rng = random.Random(7)
-    provider = fake_cp.FakeCloudProvider(fake_cp.instance_types(16))
-    solver = TPUSolver(provider, [make_provisioner()])
-    # force both committal families: zone spread class + required zonal anti
-    pods = [
-        make_pod(
-            labels={"app": "zs"}, requests={"cpu": "250m"},
-            topology_spread=[TopologySpreadConstraint(
-                max_skew=1, topology_key=ZONE,
-                label_selector=LabelSelector(match_labels={"app": "zs"}))],
-        )
-        for _ in range(9)
-    ] + [
-        make_pod(
-            labels={"app": "za"}, requests={"cpu": "250m"},
-            pod_anti_affinity=[PodAffinityTerm(
-                topology_key=ZONE,
-                label_selector=LabelSelector(match_labels={"app": "za"}))],
-        )
-        for _ in range(4)
-    ] + _random_batch(rng)
-    snap = solver.encode(pods)
-    assert snap.features.zone_spread and snap.features.required_zone_anti
-    cls, sa, khb = solve_ops.prepare_host(snap)
-    n_slots = solve_ops.estimate_slots(snap)
-    ft = solve_ops.snapshot_features(snap)
-    seq = _solve_variant(cls, sa, n_slots, khb, snap.scan_passes,
-                         features=ft, fuse_zones=False, packed_masks=True)
-    fused = _solve_variant(cls, sa, n_slots, khb, snap.scan_passes,
-                           features=ft, fuse_zones=True, packed_masks=True)
-    _assert_same(seq, fused, "fused-vs-sequential")
-
-
-@pytest.mark.compile
-@pytest.mark.slow
-def test_parity_with_existing_nodes():
-    """The committal block's existing-node path and the pruned volume/port
-    families against the reference, with real state nodes in play."""
+def test_pruned_parity_with_existing_nodes():
+    """The pruned volume/port families against the all-phases trace, with
+    real state nodes in play."""
     from karpenter_core_tpu.testing.harness import make_environment
     from karpenter_core_tpu.testing import make_node
 
@@ -334,8 +388,8 @@ def test_parity_with_existing_nodes():
             out.ex_state.vol_used,
         ))
 
-    ref = run(features=None, fuse_zones=False, packed_masks=False)
-    prod = run(features=ft, fuse_zones=True, packed_masks=True)
+    ref = run(features=None)
+    prod = run(features=ft)
     for i, (a, b) in enumerate(zip(ref, prod)):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b), err_msg=f"field {i}"

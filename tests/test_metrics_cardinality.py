@@ -126,8 +126,8 @@ class TestLabelValueEscaping:
 class TestBatchOccupancyLedger:
     """The coalescer's real-vs-padded accounting (utils.compilecache):
     `record_batch_occupancy` is called once per device dispatch and must
-    (a) keep a cumulative per-(bucket, mesh) ledger for bench's
-    `detail.batch_occupancy`, and (b) publish the live gauge/counter pair
+    (a) keep a cumulative per-(bucket, mesh) ledger
+    (`occupancy_stats`), and (b) publish the live gauge/counter pair
     `karpenter_batch_occupancy_ratio` / `karpenter_padded_flops_total`."""
 
     @pytest.fixture(autouse=True)
