@@ -366,6 +366,26 @@ SOLVER_SLOT_RETRIES = Counter(
 )
 REGISTRY.register(SOLVER_SLOT_RETRIES)
 
+# The sidecar's collector policy (service/collector.py): every pass of the
+# cyclic collector the process ran while a sidecar was up, by generation, as
+# its ``gc.callbacks`` hook saw them.  Generation 2 is paced by the server —
+# one or two a minute is the steady state; a rate of several a second says the
+# pacing is not installed (or an embedder calls ``gc.collect()`` in a loop).
+SOLVER_GC_COLLECTIONS = Counter(
+    NAMESPACE + "_solver_gc_collections_total",
+    "Passes of the cyclic garbage collector in the solver sidecar's process, "
+    "by generation.",
+    ("generation",),
+)
+REGISTRY.register(SOLVER_GC_COLLECTIONS)
+SOLVER_GC_SECONDS = Counter(
+    NAMESPACE + "_solver_gc_seconds_total",
+    "Seconds the solver sidecar's process spent inside passes of the cyclic "
+    "garbage collector, by generation.",
+    ("generation",),
+)
+REGISTRY.register(SOLVER_GC_SECONDS)
+
 # Policy-objective surface (docs/POLICY.md): the latest solve's selected
 # fleet cost, raw offering prices ({view="price"}) and risk-weighted
 # expectation ({view="expected"}), set by TPUSolver decode when the

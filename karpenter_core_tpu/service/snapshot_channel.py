@@ -45,6 +45,7 @@ import logging
 import os
 import threading
 import time
+import weakref
 from concurrent import futures
 from typing import Dict, List, Optional
 
@@ -55,6 +56,7 @@ from karpenter_core_tpu import chaos, tracing
 from karpenter_core_tpu import metrics as metrics_mod
 from karpenter_core_tpu.apis import codec
 from karpenter_core_tpu.models.snapshot import KernelUnsupported
+from karpenter_core_tpu.service import collector
 from karpenter_core_tpu.service import journal as journal_mod
 from karpenter_core_tpu.service import tenant as tenant_mod
 from karpenter_core_tpu.solver.tpu import TPUSolver
@@ -679,20 +681,20 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
     # -- grpc plumbing --------------------------------------------------------
 
     def service(self, handler_call_details):
-        method = handler_call_details.method
-        if method == f"/{SERVICE}/Solve":
-            return grpc.unary_unary_rpc_method_handler(self._solve)
-        if method == f"/{SERVICE}/SolveClasses":
-            return grpc.unary_unary_rpc_method_handler(self._solve_classes)
-        if method == f"/{SERVICE}/Health":
-            return grpc.unary_unary_rpc_method_handler(self._health)
-        if method == f"/{SERVICE}/Consolidate":
-            return grpc.unary_unary_rpc_method_handler(self._consolidate)
-        if method == f"/{SERVICE}/LeaseGet":
-            return grpc.unary_unary_rpc_method_handler(self._lease_get)
-        if method == f"/{SERVICE}/LeaseApply":
-            return grpc.unary_unary_rpc_method_handler(self._lease_apply)
-        return None
+        # resolved per request (an instance attribute may wrap a handler), and
+        # every kind ends in one request boundary of the collector policy
+        prefix, _, method = handler_call_details.method.rpartition("/")
+        handler = {
+            "Solve": self._solve,
+            "SolveClasses": self._solve_classes,
+            "Health": self._health,
+            "Consolidate": self._consolidate,
+            "LeaseGet": self._lease_get,
+            "LeaseApply": self._lease_apply,
+        }.get(method) if prefix == f"/{SERVICE}" else None
+        if handler is None:
+            return None
+        return grpc.unary_unary_rpc_method_handler(collector.POLICY.paced(handler))
 
     # -- handlers -------------------------------------------------------------
 
@@ -982,6 +984,7 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
         # class, so a request opens the same number whatever its size
         with tracing.span("service.solve_classes",
                           request_bytes=len(request)) as root:
+            full0, full_s0 = collector.POLICY.full_passes()
             partial = self._rpc_chaos(context, "SolveClasses")
             try:
                 with tracing.span("service.decode", request_bytes=len(request)):
@@ -997,7 +1000,11 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                 context.abort(a.code, a.details)
             if partial is not None:
                 context.abort(grpc.StatusCode.UNAVAILABLE, partial.describe())
-            root.set(reply_bytes=len(response))
+            # full collector passes that began inside this request: 0 while
+            # the policy paces them (docs/OBSERVABILITY.md)
+            full, full_s = collector.POLICY.full_passes()
+            root.set(reply_bytes=len(response), gc_full=full - full0,
+                     gc_full_s=full_s - full_s0)
             return response
 
     @staticmethod
@@ -1499,7 +1506,12 @@ def serve(
     durable-session journal: recovery replay runs HERE, before the port
     binds, so the first request a client lands already sees warm lineages.
     ``drain_on_sigterm`` installs the graceful-drain SIGTERM handler
-    (main-thread processes only)."""
+    (main-thread processes only).
+
+    While it is up the sidecar paces the process's full garbage collections
+    (service/collector.py: one installation shared by every sidecar of the
+    process); ``server.stop()`` of the last one puts the collector back as
+    found."""
     from karpenter_core_tpu.utils import compilecache
 
     compilecache.enable()  # sidecar restarts reuse compiled solve kernels
@@ -1516,6 +1528,20 @@ def serve(
     server.add_generic_rpc_handlers((service,))
     port = server.add_insecure_port(address)
     server.start()
+    # the sidecar owns its process's collector while it is up: full passes
+    # are paced by the server, not started by an allocation inside a request
+    # (service/collector.py); stop() hands the collector back
+    release_collector = collector.POLICY.acquire()
+    grpc_stop = server.stop
+
+    def stop(grace=None):
+        try:
+            return grpc_stop(grace)
+        finally:
+            release_collector()
+
+    server.stop = stop
+    weakref.finalize(server, release_collector)  # a server dropped unstopped
     # the service (and its tenant plane) stays reachable for operators/tests
     server.kc_service = service
     server.kc_http = None

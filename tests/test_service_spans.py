@@ -93,8 +93,11 @@ def test_stateless_request_opens_the_same_spans_whatever_its_class_count(
     reply_bytes = _one(spans, "service.pack")["attrs"]["reply_bytes"]
     assert _one(spans, "client.rpc")["attrs"] == {
         "request_bytes": request_bytes, "reply_bytes": reply_bytes}
+    # gc_full: full collector passes begun inside the request — the sidecar
+    # paces them to request boundaries (tests/test_collector_policy.py)
     assert _one(spans, "service.solve_classes")["attrs"] == {
-        "request_bytes": request_bytes, "reply_bytes": reply_bytes}
+        "request_bytes": request_bytes, "reply_bytes": reply_bytes,
+        "gc_full": 0, "gc_full_s": 0.0}
     assert _one(spans, "client.unpack")["attrs"]["reply_bytes"] == reply_bytes
     (objects,) = [s for s in spans
                   if s["name"] == "service.decode" and "classes" in s["attrs"]]
