@@ -18,10 +18,11 @@ as dedicated axes rather than general masks (models.vocab.STRUCTURAL_KEYS).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from karpenter_core_tpu import tracing
 from karpenter_core_tpu.apis import labels as labels_api
 from karpenter_core_tpu.apis.objects import SCHEDULE_ANYWAY, Pod
 from karpenter_core_tpu.apis.v1alpha5 import Provisioner
@@ -70,6 +71,63 @@ class GroupScope:
         if (pod.namespace or "") not in self.namespaces:
             return False
         return self.selector is not None and self.selector.matches(pod.metadata.labels)
+
+
+def group_membership(
+    reps: Sequence[Pod], scopes: Sequence[Optional[GroupScope]]
+) -> Tuple[np.ndarray, Dict[str, int]]:
+    """``member[i, g] = scopes[g].matches_pod(reps[i])`` (False for a ``None``
+    scope) as a ``[len(reps), len(scopes)]`` bool plane, and what it cost;
+    ``reps`` are representatives — one pod per class, or per distinct
+    (namespace, labels) of the bound pods — never a pod collection:
+    ``namespaces`` among them, ``candidates`` (pairs evaluated) and
+    ``members`` (true cells).
+
+    From an index, never the product: a group's candidates are the pods of the
+    scope's namespaces — for a selector with ``match_labels``, the shortest of
+    its labels' posting lists keyed (namespace, key, value) — and
+    ``matches_pod`` itself decides each candidate.  Thousands of Deployments
+    that each select their own ``name`` label cost one lookup a group;
+    selectors of expressions alone over one namespace cost what the product
+    did."""
+    by_namespace: Dict[str, List[int]] = {}
+    postings: Dict[tuple, List[int]] = {}
+    for i, pod in enumerate(reps):
+        namespace = pod.namespace or ""
+        by_namespace.setdefault(namespace, []).append(i)
+        for key, value in pod.metadata.labels.items():
+            postings.setdefault((namespace, key, value), []).append(i)
+    rows: List[int] = []
+    cols: List[int] = []
+    candidates = 0
+    for g, scope in enumerate(scopes):
+        if scope is None or scope.selector is None:
+            continue
+        selector = scope.selector
+        # a value that is not text (None) also matches a pod WITHOUT the key:
+        # no posting list holds those
+        wanted = [
+            (k, v) for k, v in selector.match_labels.items() if isinstance(v, str)
+        ]
+        indexed = wanted and len(wanted) == len(selector.match_labels)
+        for namespace in scope.namespaces:
+            if indexed:
+                pool = min(
+                    (postings.get((namespace, k, v), ()) for k, v in wanted), key=len
+                )
+            else:
+                pool = by_namespace.get(namespace, ())
+            candidates += len(pool)
+            hits = [i for i in pool if scope.matches_pod(reps[i])]
+            rows.extend(hits)
+            cols.extend([g] * len(hits))
+    member = np.zeros((len(reps), len(scopes)), dtype=bool)
+    member[rows, cols] = True
+    return member, {
+        "namespaces": len(by_namespace),
+        "candidates": candidates,
+        "members": len(rows),  # a pod has one namespace: no pair twice
+    }
 
 
 @dataclass
@@ -1211,41 +1269,43 @@ def _populate_class_planes(
             snap.cls_root[nxt] = snap.cls_root[c]
     snap.cls_tol = np.zeros((C, T), dtype=bool)
     # -- topology groups (hash-deduped, topologygroup.go:137-153) -------------
-    group_index: Dict[GroupSpec, int] = {}
-    group_selectors: list = []
-    for cls in classes:
-        for spec in cls.owned_groups():
+    with tracing.span("encode.groups", classes=C) as sp:
+        group_index: Dict[GroupSpec, int] = {}
+        group_selectors: list = []
+        for cls in classes:
+            for spec in cls.owned_groups():
+                if spec not in group_index:
+                    group_index[spec] = len(group_index)
+                    group_selectors.append(cls.selectors[spec])
+        # anti-affinity groups owned only by already-bound cluster pods still
+        # gate the pods they select (inverse topologies, topology.go:185-198)
+        for spec, selector in extra_anti_groups or []:
             if spec not in group_index:
                 group_index[spec] = len(group_index)
-                group_selectors.append(cls.selectors[spec])
-    # anti-affinity groups owned only by already-bound cluster pods still gate
-    # the pods they select (inverse topologies, topology.go:185-198)
-    for spec, selector in extra_anti_groups or []:
-        if spec not in group_index:
-            group_index[spec] = len(group_index)
-            group_selectors.append(GroupScope(selector, spec.namespaces))
-    G = len(group_index)
-    snap.groups = list(group_index)
-    snap.group_selectors = group_selectors
-    snap.grp_skew = np.full(G + 1, UNLIMITED, dtype=np.int32)
-    snap.grp_is_zone = np.zeros(G + 1, dtype=bool)
-    snap.grp_is_anti = np.zeros(G + 1, dtype=bool)
-    snap.grp_member = np.zeros((C, G + 1), dtype=bool)
-    snap.cls_groups = np.full((C, 6), G, dtype=np.int32)
-    for spec, g in group_index.items():
-        snap.grp_skew[g] = spec.skew
-        snap.grp_is_zone[g] = spec.is_zone
-        snap.grp_is_anti[g] = spec.gtype == GRP_ANTI
-    for c, cls in enumerate(classes):
-        rep = cls.pods[0]
-        for g, scope in enumerate(group_selectors):
-            snap.grp_member[c, g] = scope is not None and scope.matches_pod(rep)
-        for slot, spec in enumerate(
-            (cls.zone_spread, cls.host_spread, cls.zone_affinity,
-             cls.host_affinity, cls.zone_anti, cls.host_anti)
-        ):
-            if spec is not None:
-                snap.cls_groups[c, slot] = group_index[spec]
+                group_selectors.append(GroupScope(selector, spec.namespaces))
+        G = len(group_index)
+        snap.groups = list(group_index)
+        snap.group_selectors = group_selectors
+        snap.grp_skew = np.full(G + 1, UNLIMITED, dtype=np.int32)
+        snap.grp_is_zone = np.zeros(G + 1, dtype=bool)
+        snap.grp_is_anti = np.zeros(G + 1, dtype=bool)
+        snap.grp_member = np.zeros((C, G + 1), dtype=bool)
+        snap.cls_groups = np.full((C, 6), G, dtype=np.int32)
+        for spec, g in group_index.items():
+            snap.grp_skew[g] = spec.skew
+            snap.grp_is_zone[g] = spec.is_zone
+            snap.grp_is_anti[g] = spec.gtype == GRP_ANTI
+        snap.grp_member[:, :G], cost = group_membership(
+            [cls.pods[0] for cls in classes], group_selectors
+        )
+        for c, cls in enumerate(classes):
+            for slot, spec in enumerate(
+                (cls.zone_spread, cls.host_spread, cls.zone_affinity,
+                 cls.host_affinity, cls.zone_anti, cls.host_anti)
+            ):
+                if spec is not None:
+                    snap.cls_groups[c, slot] = group_index[spec]
+        sp.set(groups=G, **cost)
     for c, cls in enumerate(classes):
         requests = dict(cls.requests)
         requests[resources_util.PODS] = 1.0

@@ -596,6 +596,7 @@ class TPUSolver:
             GRP_ANTI,
             UNLIMITED,
             _group_spec,
+            group_membership,
             pod_port_keys,
             term_namespaces,
         )
@@ -745,10 +746,9 @@ class TPUSolver:
                     g = group_of.get(spec)
                     if g is not None:
                         grp_node_owner[g, e] += 1
-        member = np.zeros((len(pod_signatures), G1), dtype=bool)
-        for i, pod in pod_signatures.values():
-            for g, scope in enumerate(snapshot.group_selectors):
-                member[i, g] = scope is not None and scope.matches_pod(pod)
+        member, _ = group_membership(
+            [pod for _, pod in pod_signatures.values()], snapshot.group_selectors
+        )
         sig_of = np.asarray(bound_sig, dtype=np.intp)
         node_of = np.asarray(bound_node, dtype=np.intp)
         for g in np.flatnonzero(member.any(axis=0)):
@@ -1000,6 +1000,9 @@ class TPUSolver:
                 count = solve_ops._pad_axis(
                     np.asarray(snapshot.cls_count, dtype=np.int32), 0, c_pad, 0
                 )
+                tracing.set_attrs(
+                    c_padded=c_pad, g_padded=np.shape(prev.statics_arrays.grp_skew)[0]
+                )
                 return SolvePrep(
                     cls=prev.cls._replace(count=count),
                     statics_arrays=prev.statics_arrays,
@@ -1022,6 +1025,11 @@ class TPUSolver:
                     cls, statics_arrays, key_has_bounds, ex_state, ex_static
                 )
             )
+        # the class and group axes as the scan will see them, beside n_slots
+        tracing.set_attrs(
+            c_padded=np.shape(cls.count)[0],
+            g_padded=np.shape(statics_arrays.grp_skew)[0],
+        )
         prep = SolvePrep(
             cls=cls, statics_arrays=statics_arrays, key_has_bounds=key_has_bounds,
             ex_state=ex_state, ex_static=ex_static, n_slots=n_slots,
