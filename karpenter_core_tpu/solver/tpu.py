@@ -183,6 +183,22 @@ class TPUNodeDecision:
         }
 
 
+def _scan_axes(cls, statics_arrays) -> dict:
+    """The ``prepare`` span's account of the axes the scan will see, beside
+    ``n_slots``: the class, group and member-list axes as padded (``m_padded``
+    0: no lists kept, ``ops.solve.member_index``), and the largest member
+    count of a class."""
+    g1 = np.shape(statics_arrays.grp_skew)[0]
+    lists = np.asarray(cls.member_idx) < g1 - 1
+    members = lists if lists.shape[1] else np.asarray(statics_arrays.grp_member)
+    return {
+        "c_padded": np.shape(cls.count)[0],
+        "g_padded": g1,
+        "m_padded": lists.shape[1],
+        "members_max": int(members.sum(axis=1).max(initial=0)),
+    }
+
+
 def _attach_pol(snapshot, statics_arrays):
     """The snapshot's policy objective planes (policy.planes.planes_of),
     catalog-padded to the prep's instance-type extent.  The pol planes share
@@ -1000,9 +1016,7 @@ class TPUSolver:
                 count = solve_ops._pad_axis(
                     np.asarray(snapshot.cls_count, dtype=np.int32), 0, c_pad, 0
                 )
-                tracing.set_attrs(
-                    c_padded=c_pad, g_padded=np.shape(prev.statics_arrays.grp_skew)[0]
-                )
+                tracing.set_attrs(**_scan_axes(prev.cls, prev.statics_arrays))
                 return SolvePrep(
                     cls=prev.cls._replace(count=count),
                     statics_arrays=prev.statics_arrays,
@@ -1025,11 +1039,7 @@ class TPUSolver:
                     cls, statics_arrays, key_has_bounds, ex_state, ex_static
                 )
             )
-        # the class and group axes as the scan will see them, beside n_slots
-        tracing.set_attrs(
-            c_padded=np.shape(cls.count)[0],
-            g_padded=np.shape(statics_arrays.grp_skew)[0],
-        )
+        tracing.set_attrs(**_scan_axes(cls, statics_arrays))
         prep = SolvePrep(
             cls=cls, statics_arrays=statics_arrays, key_has_bounds=key_has_bounds,
             ex_state=ex_state, ex_static=ex_static, n_slots=n_slots,

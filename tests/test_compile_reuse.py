@@ -120,6 +120,97 @@ class TestSteadyStateCompileReuse:
         assert sum(len(n.pods) for n in results.new_nodes) == len(pods)
         assert compilecache.stats()["builds"] == 0, "real batch recompiled after warmup"
 
+    def test_member_count_wobble_reuses_executable(self):
+        """The member list's width M is a SHAPE (``ops.solve.member_index``:
+        the bucket of the largest member count, floor 8), so a batch whose
+        classes sit in one group each and a batch whose classes sit in three
+        run the same executable."""
+        import numpy as np
+
+        zone, host = labels_api.LABEL_TOPOLOGY_ZONE, labels_api.LABEL_HOSTNAME
+
+        def batch(shared: bool):
+            pods = [make_pod(requests={"cpu": "500m"}, labels={"app": "gen"}) for _ in range(20)]
+            for app, key, skew in (("s1", zone, 1), ("s2", host, 4), ("s3", zone, 2)):
+                labels = {"app": app, "tier": "t"}
+                selector = {"tier": "t"} if shared else {"app": app}
+                pods += [
+                    make_pod(
+                        labels=labels, requests={"cpu": "250m"},
+                        topology_spread=[TopologySpreadConstraint(
+                            max_skew=skew, topology_key=key,
+                            label_selector=LabelSelector(match_labels=selector),
+                        )],
+                    )
+                    for _ in range(6)
+                ]
+            return pods
+
+        provider = fake_cp.FakeCloudProvider(fake_cp.instance_types(24))
+        solver = TPUSolver(provider, [make_provisioner()])
+        widths = []
+        for shared in (False, True):
+            snapshot = solver.encode(batch(shared))
+            widths.append((
+                int(np.asarray(snapshot.grp_member).sum(axis=1).max()),
+                np.shape(solver.prepare_encoded(snapshot).cls.member_idx)[1],
+            ))
+        assert widths == [(1, 8), (3, 8)]
+        compilecache.reset_stats()
+        solver.solve(batch(False))
+        first = compilecache.stats()["builds"]
+        assert first >= 1
+        solver.solve(batch(True))
+        assert compilecache.stats()["builds"] == first, "a member count of 3 recompiled"
+
+    def test_suite_sizes_compile_what_they_compiled(self):
+        """The upstream suite's seven batch sizes (benchmark/configs/
+        upstream-suite-400.json, its own pod mix) build seven executables
+        through the library path at 24 types — the count before the member
+        list existed: one member per class, so M is 8 at every size and adds
+        no shape of its own.  Counted in a process of its own: the memo of
+        this one holds whatever earlier tests built."""
+        import json
+        import os
+        import pathlib
+        import subprocess
+        import sys
+
+        root = pathlib.Path(__file__).parent.parent
+        script = (
+            "import json, numpy as np\n"
+            "from benchmark.harness.podmix import pod_mix, seeded\n"
+            "from karpenter_core_tpu.cloudprovider import fake as fake_cp\n"
+            "from karpenter_core_tpu.solver.tpu import TPUSolver\n"
+            "from karpenter_core_tpu.testing import make_provisioner\n"
+            "from karpenter_core_tpu.utils import compilecache\n"
+            "config = json.load(open('benchmark/configs/upstream-suite-400.json'))\n"
+            "solver = TPUSolver(fake_cp.FakeCloudProvider(fake_cp.instance_types(24)),"
+            " [make_provisioner()])\n"
+            "widths, placed = set(), []\n"
+            "for j, n in enumerate(config['batch_sizes']):\n"
+            "    pods = pod_mix(n, seeded(11, f'batch{j}'), config['pod_mix'])\n"
+            "    prep = solver.prepare_encoded(solver.encode(pods))\n"
+            "    widths.add(int(np.shape(prep.cls.member_idx)[1]))\n"
+            "    placed.append(sum(len(x.pods) for x in solver.solve(pods).new_nodes))\n"
+            "print(json.dumps({'builds': compilecache.stats()['builds'],"
+            " 'widths': sorted(widths), 'placed': placed,"
+            " 'sizes': config['batch_sizes']}))\n"
+        )
+        env = dict(
+            os.environ, JAX_PLATFORMS="cpu", KC_TPU_WARMUP="0", KC_SOLVER_MESH="0",
+            PYTHONPATH=str(root), KC_TPU_COMPILE_CACHE=str(root / ".kc_cache" / "suite_sizes"),
+        )
+        proc = subprocess.run(
+            [sys.executable, "-c", script], cwd=root, env=env,
+            capture_output=True, text=True, timeout=600,
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        seen = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert seen["placed"] == seen["sizes"]
+        assert seen["widths"] == [8]
+        assert seen["builds"] == len(seen["sizes"]) == 7
+
     def test_bucket_grid_is_stable(self):
         from karpenter_core_tpu.ops.solve import bucket
 
