@@ -25,7 +25,7 @@ is a distinct production bug:
   uncached-jit      ``jax.jit(...)`` constructed inside a function that is
                     not memoized (lru_cache): every call builds a fresh
                     wrapper with an empty jit cache, so every call retraces
-                    (the bug class ops.consolidate._lane_sweep_fn's
+                    (the bug class ops.consolidate.lane_sweep_fn's
                     docstring describes)
   donated-read      a buffer passed to a donating dispatch site is read
                     again afterwards in the same function — the classic

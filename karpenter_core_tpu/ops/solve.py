@@ -457,6 +457,20 @@ def _offering_ok(zone_ok: jnp.ndarray, ct_ok: jnp.ndarray, statics) -> jnp.ndarr
     )
 
 
+def _any_lane(pred: jnp.ndarray, statics: "Statics") -> jnp.ndarray:
+    """The predicate of a "has anything to place" ``cond``.  Under the
+    consolidation sweep's ``vmap`` over prefix lanes (``statics.lane_axis``
+    names it) it is true where ANY lane's is: one decision for all lanes keeps
+    the ``cond`` a ``cond``, where a predicate that differs by lane would turn
+    it into a ``select`` that runs both sides in every lane — every phase kind
+    of every class, padding rows included.  A lane whose own predicate is
+    false then runs the placing side with nothing to place, which leaves its
+    state as the skipping side would.  Everywhere else: ``pred``."""
+    if statics.lane_axis is None:
+        return pred
+    return jax.lax.pmax(pred.astype(jnp.int32), statics.lane_axis) > 0
+
+
 def _fill_by_priority(
     quota: jnp.ndarray, cap: jnp.ndarray, priority: jnp.ndarray
 ) -> jnp.ndarray:
@@ -468,6 +482,28 @@ def _fill_by_priority(
     before = jnp.cumsum(cap_sorted) - cap_sorted
     assigned_sorted = jnp.clip(quota - before, 0, cap_sorted)
     return jnp.zeros_like(cap).at[order].set(assigned_sorted)
+
+
+def _fill_in_order(quota: jnp.ndarray, cap: jnp.ndarray) -> jnp.ndarray:
+    """``_fill_by_priority`` where the priority is the index itself among the
+    nodes with room (existing nodes: the reference takes the first that
+    accepts, scheduler.go:176-180): a node without room has ``cap`` 0, adds
+    nothing to the running sum and takes nothing, so no sort is needed — the
+    same integers, without the argsort, the gather and the scatter over E (on
+    the chip a gather of E elements runs element by element; under the
+    consolidation sweep's ``vmap`` it was 64 × E of them a call)."""
+    before = jnp.cumsum(cap) - cap
+    return jnp.clip(quota - before, 0, cap)
+
+
+def _fill_existing(quota, cap, pref):
+    """``_fill_with_pref`` over existing nodes, whose priority is always the
+    index among the nodes with room: this class's freed holes first (warm
+    repair, ``pref``), then what is left, both rounds ``_fill_in_order``."""
+    if pref is None:
+        return _fill_in_order(quota, cap)
+    refilled = _fill_in_order(quota, jnp.minimum(cap, pref))
+    return refilled + _fill_in_order(quota - jnp.sum(refilled), cap - refilled)
 
 
 def _fill_with_pref(quota, cap, priority, pref):
@@ -519,6 +555,8 @@ class Statics(NamedTuple):
     # mesh axis name the catalog (I) planes are sharded over inside a
     # shard_map body (parallel.mesh); None = unsharded, no collectives traced
     catalog_axis: "Optional[str]" = None
+    # the consolidation sweep's vmap axis over prefix lanes (``_any_lane``)
+    lane_axis: "Optional[str]" = None
 
 
 class StaticArrays(NamedTuple):
@@ -700,8 +738,7 @@ def _phase_existing(
         first = jnp.argmax(cap > 0)
         cap = jnp.where(jnp.arange(n_ex) == first, cap, 0)
 
-    priority = jnp.where(cap > 0, jnp.arange(n_ex, dtype=jnp.int32), jnp.iinfo(jnp.int32).max)
-    assigned = _fill_with_pref(quota, cap, priority, pref)
+    assigned = _fill_existing(quota, cap, pref)
     placed = jnp.sum(assigned)
 
     took = assigned > 0
@@ -1211,7 +1248,7 @@ def _class_step(
                 rem_i,
             )
 
-        return jax.lax.cond(quota > 0, do, skip, (state, ex, remaining))
+        return jax.lax.cond(_any_lane(quota > 0, statics), do, skip, (state, ex, remaining))
 
     def committal_block(state, ex, remaining, quota_z, cap_total):
         """All Z zone-committal phases of one family (zone spread quotas /
@@ -1318,8 +1355,7 @@ def _class_step(
                 q = jnp.clip(jnp.minimum(quota, cap_total - placed), 0, None)
                 # existing nodes first, in index order (scheduler.go:176-180)
                 cap_e = jnp.where(~taken_ex & zh_ex, ex_cap, 0)
-                pri_e = jnp.where(cap_e > 0, jnp.arange(n_ex, dtype=jnp.int32), i32max)
-                a_ex = _fill_with_pref(q, cap_e, pri_e, pref_ex)
+                a_ex = _fill_existing(q, cap_e, pref_ex)
                 placed_ex = jnp.sum(a_ex)
                 took_e = a_ex > 0
                 taken_ex = taken_ex | took_e
@@ -1494,7 +1530,9 @@ def _class_step(
                 rem_i,
             )
 
-        return jax.lax.cond(jnp.sum(quota_z) > 0, do, skip, (state, ex, remaining))
+        return jax.lax.cond(
+            _any_lane(jnp.sum(quota_z) > 0, statics), do, skip, (state, ex, remaining)
+        )
 
     def accumulate(results):
         nonlocal state, ex, remaining, assigned_total, assigned_ex_total, placed_total
@@ -1783,6 +1821,7 @@ def solve_core(
     warm_carry: "Optional[WarmCarry]" = None,
     repair_plan: "Optional[RepairPlan]" = None,
     catalog_axis: "Optional[str]" = None,
+    lane_axis: "Optional[str]" = None,
 ):
     """Unjitted kernel core — jit/vmap/shard_map-composable (the parallel layer
     vmaps this over snapshot replicas and consolidation subsets;
@@ -1797,6 +1836,11 @@ def solve_core(
     single device) traces no collectives at all, while a FORCED 1-device
     mesh keeps them as singleton no-ops — the degenerate case is the same
     code either way.
+
+    ``lane_axis`` (static) names the ``vmap`` axis of the consolidation sweep
+    (ops.consolidate.sweep: one lane a prefix size): the step's "anything to
+    place?" conds then decide once for all lanes (``_any_lane``) and stay
+    conds.  None (every other caller) traces nothing.
 
     ``n_passes`` > 1 re-scans still-failed pods seeded by earlier passes'
     topology counts — the kernel's equivalent of the host queue re-pushing
@@ -1836,7 +1880,7 @@ def solve_core(
     sa, class_tensors = pack_masks(sa, class_tensors)
     statics = Statics(
         *sa, key_has_bounds=key_has_bounds, mask_v=width,
-        catalog_axis=catalog_axis,
+        catalog_axis=catalog_axis, lane_axis=lane_axis,
     )
     n_zones = statics.tmpl_zone.shape[-1]
     n_res = statics.it_alloc.shape[-1]
@@ -1940,7 +1984,7 @@ def solve_core(
                 jnp.array(False),
             )
 
-        return jax.lax.cond(cls.count > 0, do, skip, carry)
+        return jax.lax.cond(_any_lane(cls.count > 0, statics), do, skip, carry)
 
     cls_indices = jnp.arange(n_classes, dtype=jnp.int32)
     if warm_carry is None:

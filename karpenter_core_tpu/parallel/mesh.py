@@ -63,7 +63,7 @@ TENANT_AXIS = "tenant"
 # outputs alike, which is what lets the warm-start repair reuse the same
 # rule set for its carry pytrees.  (The lane sweep's per-lane outputs add a
 # leading lane axis and build their specs by hand —
-# ops.consolidate._lane_sweep_fn.)
+# ops.consolidate.lane_sweep_fn.)
 CATALOG_PARTITION_RULES: Tuple[Tuple[str, int], ...] = (
     (r"\.it\.(mask|defined|negative|gt|lt)$", 0),
     (r"\.(it_alloc|it_avail|it_capacity|it_price)$", 0),
@@ -588,7 +588,7 @@ def _crossed_grid_fn(mesh, key_has_bounds, n_slots: int, n_passes: int, avail_id
     """Cached jitted crossed grid — a fresh closure per call would defeat
     JAX's compile cache (keyed on callable identity) and recompile the whole
     vmap-of-vmap solve every study (same pattern as
-    ops.consolidate._lane_sweep_fn)."""
+    ops.consolidate.lane_sweep_fn)."""
     rep, lane = mesh.axis_names
 
     def one_cell(avail, k, cls, statics_arrays, ex_state, ex_static, rank, counts):

@@ -757,11 +757,31 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
         nodes shipped in the ``nodes`` envelope by name; replacements come
         back as launchable entries whose pods are (nodeName, podIndex) refs
         into the shipped per-node pod lists."""
-        from karpenter_core_tpu.controllers.deprovisioning import CandidateNode
-        from karpenter_core_tpu.solver.consolidation import TPUConsolidationSearch
-
         partial = self._rpc_chaos(context, "Consolidate")
-        try:
+        # the handler's root span; below it one span per PHASE (decode, the
+        # search's encode / split / per-pass sweep and decode, payload, pack),
+        # never per node or pod (docs/OBSERVABILITY.md)
+        with tracing.span("service.consolidate",
+                          request_bytes=len(request)) as root:
+            try:
+                payload = self._consolidate_request(request, root)
+            except KernelUnsupported as e:
+                context.abort(grpc.StatusCode.FAILED_PRECONDITION, f"kernel unsupported: {e}")
+            except Exception as e:  # noqa: BLE001 - surface as INTERNAL
+                log.exception("consolidate request failed")
+                context.abort(grpc.StatusCode.INTERNAL, str(e))
+        if partial is not None:
+            context.abort(grpc.StatusCode.UNAVAILABLE, partial.describe())
+        return payload
+
+    def _consolidate_request(self, request: bytes, root) -> bytes:
+        from karpenter_core_tpu.apis import labels as labels_api
+        from karpenter_core_tpu.controllers.deprovisioning import CandidateNode
+        from karpenter_core_tpu.policy import PolicyConfig
+        from karpenter_core_tpu.solver.consolidation import TPUConsolidationSearch
+        from karpenter_core_tpu.utils import pod as pod_util
+
+        with tracing.span("service.decode", request_bytes=len(request)) as sp:
             req = msgpack.unpackb(request)
             provisioners, daemonset_pods, state_nodes, bound, resolver, node_pods = (
                 self._decode_common(req)
@@ -770,7 +790,6 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
             by_name = {sn.node.name: sn for sn in state_nodes}
             prov_by_name = {p.name: p for p in provisioners}
             its = {it.name: it for it in self.cloud_provider.get_instance_types(None)}
-            from karpenter_core_tpu.utils import pod as pod_util
 
             def reschedulable(pods):
                 # node_util.get_node_pods parity: the envelope ships ALL pods
@@ -802,9 +821,6 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     disruption_cost=float(c.get("disruptionCost", 0.0)),
                     pods=reschedulable(node_pods.get(c["name"], [])),
                 ))
-
-            from karpenter_core_tpu.policy import PolicyConfig
-
             search = TPUConsolidationSearch(
                 self.cloud_provider, provisioners,
                 # the requesting replica's resolved policy config rides the
@@ -813,24 +829,29 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                 # pre-policy behavior, serving-side KC_POLICY=0 still wins
                 policy=PolicyConfig.from_wire(req.get("policy")),
             )
-            cmd = search.compute_command(
-                candidates, pending_pods=pending,
-                state_nodes=state_nodes, bound_pods=bound,
-            )
+            sp.set(candidates=len(candidates), state_nodes=len(state_nodes),
+                   pods=len(bound))
 
-            pod_ref = {}
-            for name, pods in node_pods.items():
-                for i, pod in enumerate(pods):
-                    pod_ref[id(pod)] = (name, i)
+        cmd = search.compute_command(
+            candidates, pending_pods=pending,
+            state_nodes=state_nodes, bound_pods=bound,
+        )
 
-            def domain_of(replacement, key) -> list:
-                requirements = replacement.requirements
-                if requirements.has(key):
-                    return list(requirements.get(key).values_list())
-                return []
+        def domain_of(replacement, key) -> list:
+            requirements = replacement.requirements
+            if requirements.has(key):
+                return list(requirements.get(key).values_list())
+            return []
 
-            from karpenter_core_tpu.apis import labels as labels_api
-
+        with tracing.span("service.payload"):
+            # a replacement's pods are its candidates' — named by where they
+            # were shipped: (node, index in that node's shipped list)
+            pod_ref = {
+                id(pod): (name, i)
+                for r in cmd.replacement_nodes
+                for name in {p.spec.node_name for p in r.pods}
+                for i, pod in enumerate(node_pods.get(name, ()))
+            }
             response = {
                 "action": cmd.action.value,
                 "nodesToRemove": [n.name for n in cmd.nodes_to_remove],
@@ -852,15 +873,10 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     for r in cmd.replacement_nodes
                 ],
             }
-            payload = msgpack.packb(response)
-        except KernelUnsupported as e:
-            context.abort(grpc.StatusCode.FAILED_PRECONDITION, f"kernel unsupported: {e}")
-        except Exception as e:  # noqa: BLE001 - surface as INTERNAL
-            log.exception("consolidate request failed")
-            context.abort(grpc.StatusCode.INTERNAL, str(e))
-        if partial is not None:
-            context.abort(grpc.StatusCode.UNAVAILABLE, partial.describe())
-        return payload
+        root.set(candidates=len(candidates), nodes=len(state_nodes),
+                 pods=len(bound), passes=search.last_passes,
+                 action=cmd.action.value, removed=len(cmd.nodes_to_remove))
+        return self._pack_reply(response)
 
     def _lease_get(self, request: bytes, context) -> bytes:
         req = msgpack.unpackb(request)
@@ -1640,17 +1656,24 @@ class SnapshotSolverClient:
         replacements: [{provisioner, instanceTypes, zones, capacityTypes,
         requests, podRefs: [[nodeName, podIndex]]}]}."""
         self._client_chaos("Consolidate")
-        request = msgpack.packb(
-            {
-                "candidates": candidates,
-                "pendingPods": [codec.pod_to_dict(p) for p in pending_pods],
-                "provisioners": [codec.provisioner_to_dict(p) for p in provisioners],
-                "nodes": nodes or [],
-                "claimDrivers": claim_drivers or {},
-                "policy": _policy_wire(policy),
-            }
-        )
-        return msgpack.unpackb(self._consolidate(request, timeout=timeout))
+        # the client's phases, as /SolveClasses names them
+        with tracing.span("client.pack", candidates=len(candidates)) as sp:
+            request = msgpack.packb(
+                {
+                    "candidates": candidates,
+                    "pendingPods": [codec.pod_to_dict(p) for p in pending_pods],
+                    "provisioners": [codec.provisioner_to_dict(p) for p in provisioners],
+                    "nodes": nodes or [],
+                    "claimDrivers": claim_drivers or {},
+                    "policy": _policy_wire(policy),
+                }
+            )
+            sp.set(request_bytes=len(request))
+        with tracing.span("client.rpc", request_bytes=len(request)) as sp:
+            reply = self._consolidate(request, timeout=timeout)
+            sp.set(reply_bytes=len(reply))
+        with tracing.span("client.unpack", reply_bytes=len(reply)):
+            return msgpack.unpackb(reply)
 
     def lease_get(self, name: str, namespace: str = "", timeout: float = 5.0):
         response = msgpack.unpackb(

@@ -10,6 +10,7 @@ prefix — the result the binary search converges to, computed in one pass.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -25,12 +26,28 @@ from karpenter_core_tpu.controllers.deprovisioning import (
     filter_by_price,
     MultiNodeConsolidation,
 )
+from karpenter_core_tpu import tracing
+from karpenter_core_tpu.metrics import REGISTRY
 from karpenter_core_tpu.models.snapshot import KernelUnsupported
 from karpenter_core_tpu.ops import consolidate as consolidate_ops
+from karpenter_core_tpu.ops import solve as solve_ops
 from karpenter_core_tpu.scheduling import Requirement, Requirements
 from karpenter_core_tpu.solver.tpu import TPUSolver
 
-MAX_LANES = 64
+MAX_LANES = consolidate_ops.LANE_LADDER[-1]
+
+CONSOLIDATE_PASSES = REGISTRY.counter(
+    "karpenter_solver_consolidate_passes_total",
+    "Device passes of the multi-node consolidation sweep (one coarse pass over "
+    "the candidates, then one per re-grid of the bracket it leaves).",
+)
+CONSOLIDATE_SECONDS = REGISTRY.histogram(
+    "karpenter_solver_consolidate_seconds",
+    "Wall of one multi-node consolidation search (encode, split, every pass "
+    "and its decode), by the action it returned.",
+    ("action",),
+    buckets=(0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0, 40.0, 80.0),
+)
 
 
 def search_largest_prefix(n, evaluate, refine: bool = True):
@@ -41,7 +58,7 @@ def search_largest_prefix(n, evaluate, refine: bool = True):
     to MAX_LANES sizes cover [1, n] per pass; when the coarse grid leaves a
     gap between the best lane and the next, further passes re-grid the
     bracket, shrinking it ~MAX_LANES× each time — the boundary pins exactly
-    in ceil(log64(n)) passes (2 up to 4096 candidates, 3 to 256k) vs the
+    in ceil(log72(n)) passes (2 up to 5 184 candidates, 3 to 373k) vs the
     reference's ~log2(n) sequential full simulations
     (multinodeconsolidation.go:86-113).
 
@@ -104,6 +121,7 @@ class TPUConsolidationSearch:
         # smaller prefix removes fewer nodes (docs/POLICY.md).  None/disabled
         # keeps the reference behavior: the largest valid prefix wins.
         self.policy = policy
+        self.last_passes = 0  # device passes of the newest compute_command
         self.solver = TPUSolver(cloud_provider, provisioners, policy=policy)
         self.it_by_name = {
             it.name: it
@@ -120,57 +138,84 @@ class TPUConsolidationSearch:
     ) -> Command:
         """candidates must be disruption-cost sorted.  Raises KernelUnsupported
         when the pod shapes need the host path."""
+        self.last_passes = 0
         if not candidates:
             return Command(Action.DO_NOTHING)
 
-        candidate_pods = [p for c in candidates for p in c.pods]
-        all_pods = list(pending_pods) + candidate_pods
-        if not all_pods:
-            # no pods anywhere: every candidate is empty, deleting all is
-            # trivially valid (the simulation would open zero new nodes)
-            return Command(Action.DELETE, [c.node for c in candidates])
-        snapshot = self.solver.encode(all_pods, state_nodes, bound_pods)
-        ex_state, ex_static = self.solver.encode_existing(
-            snapshot, state_nodes, bound_pods
-        )
-        # encode_existing returns host numpy (so the provisioning path can
-        # bucket-pad before upload); the sweep runs up to twice (coarse +
-        # refine) on the same planes, so pin them device-resident once here
-        import jax
+        t0 = time.perf_counter()
+        with tracing.span("consolidate.encode",
+                          state_nodes=len(state_nodes)) as sp:
+            # the host's queue pops pods by cpu, memory, creation time, uid
+            # (queue.go:74-110) and the class scan takes equal-sized classes
+            # in the order their first pod shows: shown in the queue's own
+            # order, a size's workloads are simulated in the order the host's
+            # re-simulation (validate_command) will take them
+            all_pods = sorted(
+                list(pending_pods) + [p for c in candidates for p in c.pods],
+                key=lambda p: (p.metadata.creation_timestamp, p.uid),
+            )
+            sp.set(pods=len(all_pods))
+            if not all_pods:
+                # no pods anywhere: every candidate is empty, deleting all is
+                # trivially valid (the simulation would open zero new nodes)
+                return Command(Action.DELETE, [c.node for c in candidates])
+            snapshot = self.solver.encode(all_pods, state_nodes, bound_pods)
+            with tracing.span(
+                "encode.existing", state_nodes=len(state_nodes),
+                bound_pods=len(bound_pods or ()), classes=len(snapshot.classes),
+                e_padded=solve_ops.bucket(len(state_nodes), floor=8),
+            ):
+                ex_state, ex_static = self.solver.encode_existing(
+                    snapshot, state_nodes, bound_pods, count_scheduling=True
+                )
+            sp.set(classes=len(snapshot.classes))
 
-        ex_state, ex_static = jax.device_put((ex_state, ex_static))
-
-        # split class counts: pending (base) vs on-candidate (per-node)
-        node_index = {n.node.name: e for e, n in enumerate(state_nodes)}
-        candidate_names = {c.node.name for c in candidates}
-        E = max(len(state_nodes), 1)
-        C = len(snapshot.classes)
-        ex_cls_count = np.zeros((C, E), dtype=np.int32)
-        base_counts = np.zeros(C, dtype=np.int32)
-        for c, cls in enumerate(snapshot.classes):
-            if cls.is_ladder_variant:
-                continue  # variants hold one representative copy, not real pods
-            for pod in cls.pods:
-                if pod.spec.node_name and pod.spec.node_name in candidate_names:
-                    ex_cls_count[c, node_index[pod.spec.node_name]] += 1
-                else:
-                    base_counts[c] += 1
-        snapshot.cls_count = base_counts
-
-        rank = np.full(E, 1 << 30, dtype=np.int32)
-        for i, candidate in enumerate(candidates):
-            rank[node_index[candidate.node.name]] = i
+        with tracing.span("consolidate.split", classes=len(snapshot.classes)) as sp:
+            # split class counts: pending (base) vs on-candidate (per-node),
+            # one scatter over (class, node) index pairs
+            node_index = {n.node.name: e for e, n in enumerate(state_nodes)}
+            E = max(len(state_nodes), 1)
+            C = len(snapshot.classes)
+            rank = np.full(E, consolidate_ops.NOT_A_CANDIDATE, dtype=np.int32)
+            for i, candidate in enumerate(candidates):
+                rank[node_index[candidate.node.name]] = i
+            pod_class, pod_node = [], []
+            for c, cls in enumerate(snapshot.classes):
+                if cls.is_ladder_variant:
+                    continue  # variants hold one representative copy, not real pods
+                pod_class += [c] * len(cls.pods)
+                pod_node += [node_index.get(pod.spec.node_name, -1) for pod in cls.pods]
+            pod_class = np.asarray(pod_class, dtype=np.intp)
+            pod_node = np.asarray(pod_node, dtype=np.intp)
+            on_candidate = pod_node >= 0
+            on_candidate[on_candidate] = (
+                rank[pod_node[on_candidate]] != consolidate_ops.NOT_A_CANDIDATE
+            )
+            ex_cls_count = np.zeros((C, E), dtype=np.int32)
+            np.add.at(ex_cls_count, (pod_class[on_candidate], pod_node[on_candidate]), 1)
+            snapshot.cls_count = np.bincount(
+                pod_class[~on_candidate], minlength=C
+            ).astype(np.int32)
+            with tracing.span("prepare"):
+                # padded on the /SolveClasses ladder and uploaded once: every
+                # pass of the search ships its lane sizes alone
+                planes = consolidate_ops.prepare_sweep(
+                    snapshot, ex_state, ex_static, rank, ex_cls_count
+                )
+            c_padded, e_padded = planes.args[3].tol.shape
+            sp.set(c_padded=int(c_padded), e_padded=int(e_padded))
 
         best = search_largest_prefix(
             len(candidates),
-            lambda sizes: self._evaluate_sweep(
-                snapshot, ex_state, ex_static, rank, ex_cls_count, sizes, candidates
-            ),
+            lambda sizes: self._evaluate_sweep(snapshot, planes, sizes, candidates),
             refine=not (
                 self.policy is not None and getattr(self.policy, "enabled", False)
             ),
         )
-        return best if best is not None else Command(Action.DO_NOTHING)
+        cmd = best if best is not None else Command(Action.DO_NOTHING)
+        CONSOLIDATE_PASSES.labels().inc(self.last_passes)
+        CONSOLIDATE_SECONDS.labels(cmd.action.value).observe(time.perf_counter() - t0)
+        return cmd
 
     def _candidate_price_cumsum(self, candidates) -> np.ndarray:
         """Cumulative current-offering price of the first-k candidates
@@ -183,10 +228,10 @@ class TPUConsolidationSearch:
                 prices[i] = offering.price
         return np.cumsum(prices)
 
-    def _evaluate_sweep(
-        self, snapshot, ex_state, ex_static, rank, ex_cls_count, sizes, candidates
-    ):
-        """(best command, its prefix size) across the given lane sizes.
+    def _evaluate_sweep(self, snapshot, planes, sizes, candidates):
+        """(best command, its prefix size) across the given lane sizes: one
+        device pass (``consolidate.sweep``) and its decode
+        (``consolidate.decode``: lanes → commands under the price rules).
 
         Default scoring is the reference's: the LARGEST valid prefix wins
         (most nodes removed).  With the policy objective enabled, lanes are
@@ -205,28 +250,25 @@ class TPUConsolidationSearch:
         # across programs), so a razor-thin cost-delta tie can in principle
         # resolve differently with the mesh on vs off — same caveat as any
         # recompile (docs/KERNEL_PERF.md "Layer 5")
-        from karpenter_core_tpu import tracing
-        from karpenter_core_tpu.parallel import mesh as mesh_mod
-        from karpenter_core_tpu.utils import pipeline as pipeline_mod
-
-        mesh_axes = mesh_mod.lane_mesh_axes()
+        self.last_passes += 1
         with tracing.span(
             "consolidate.sweep", lanes=len(sizes),
-            mesh=repr(mesh_axes) if mesh_axes else None,
+            lanes_padded=consolidate_ops.lane_rung(len(sizes)),
+            lo=int(sizes[0]), hi=int(sizes[-1]), **{"pass": self.last_passes},
         ):
-            out = consolidate_ops.run_sweep(
-                snapshot, ex_state, ex_static, rank, ex_cls_count, sizes,
-                mesh_axes=mesh_axes,
+            out = consolidate_ops.sweep_pass(planes, sizes)
+        # "decode" inside it is the decode layer's own span name (the fetched
+        # planes → objects), so the layer's shared metric reads a sweep too
+        with tracing.span("consolidate.decode", lanes=len(sizes)) as sp, \
+                tracing.span("decode"):
+            best, best_k, lanes_valid = self._decode_lanes(
+                snapshot, out, sizes, candidates
             )
-            # ONE batched device→host fetch of every sweep plane (async
-            # copies started up front) instead of eight serial np.asarray
-            # transfers — the coarse sweep's fetch no longer serializes
-            # array-by-array ahead of the refine sweep's dispatch
-            # structure-preserving; the sweep's barrier budgets under its
-            # own watchdog site (a hung lane sweep must not wedge the
-            # deprovisioner — it surfaces as a SolveTimeout the breaker
-            # counts)
-            out = pipeline_mod.fetch_tree(out, site="consolidate.sweep")
+            sp.set(lanes_valid=lanes_valid, best_k=best_k)
+        return best, best_k
+
+    def _decode_lanes(self, snapshot, out, sizes, candidates):
+        """(best command, its prefix size, lanes that gave a command)."""
         n_new = np.asarray(out.n_new)
         failed = np.asarray(out.failed)
         uninit = np.asarray(out.used_uninitialized)
@@ -241,17 +283,22 @@ class TPUConsolidationSearch:
         )
         old_cum = self._candidate_price_cumsum(candidates) if cost_scoring else None
 
+        # what the simulation alone decides: every pod placed, no
+        # uninitialized node relied on, at most one node opened
+        accepted = (failed == 0) & ~uninit & (n_new <= 1)
         best: Optional[Command] = None
         best_k = 0
         best_saving = -np.inf
-        for lane, k in enumerate(sizes.tolist()):
-            if failed[lane] > 0 or uninit[lane]:
-                continue
+        # largest first: where the largest valid prefix wins (no cost scoring)
+        # the first lane that yields a command is the answer, and the O(k)
+        # build of every smaller lane's command is never paid
+        for lane in np.flatnonzero(accepted)[::-1].tolist():
+            k = int(sizes[lane])
             subset = candidates[:k]
             if int(n_new[lane]) == 0:
                 cmd = Command(Action.DELETE, [c.node for c in subset])
                 lane_cost = 0.0
-            elif int(n_new[lane]) == 1:
+            else:
                 replacement = self._decode_replacement(
                     snapshot, viable[lane, 0], zone[lane, 0], ct[lane, 0],
                     used[lane, 0], int(tmpl_id[lane, 0]), subset,
@@ -262,19 +309,15 @@ class TPUConsolidationSearch:
                     Action.REPLACE, [c.node for c in subset], [replacement]
                 )
                 lane_cost = float(new_cost[lane])
-            else:
-                continue
-            if cost_scoring:
-                saving = float(old_cum[k - 1]) - lane_cost if k >= 1 else 0.0
-                if np.isnan(saving):
-                    saving = -np.inf  # unpriceable subset: never preferred
-                if saving > best_saving or (
-                    saving == best_saving and k > best_k
-                ):
-                    best, best_k, best_saving = cmd, k, saving
-            else:
+            if not cost_scoring:
                 best, best_k = cmd, k
-        return best, best_k
+                break
+            saving = float(old_cum[k - 1]) - lane_cost if k >= 1 else 0.0
+            if np.isnan(saving):
+                saving = -np.inf  # unpriceable subset: never preferred
+            if saving > best_saving or (saving == best_saving and k > best_k):
+                best, best_k, best_saving = cmd, k, saving
+        return best, best_k, int(accepted.sum())
 
     def _decode_replacement(
         self, snapshot, viable_row, zone_row, ct_row, used_row, tmpl_idx, subset

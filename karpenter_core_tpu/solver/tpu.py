@@ -598,9 +598,17 @@ class TPUSolver:
         snapshot: EncodedSnapshot,
         state_nodes: list,
         bound_pods: Optional[List[Pod]] = None,
+        count_scheduling: bool = False,
     ):
         """(ExistingState, ExistingStatic) numpy planes for the kernel; the
         per-group member/owner node counts seed the kernel's topology counts.
+
+        ``count_scheduling``: the consolidation sweep's classes hold every
+        candidate's bound pods, displaced in some lanes and not in others — a
+        lane drops the pods of the nodes it closes through the open mask, so
+        the seeds must count them where they sit (excluded, a pod that stays
+        on an open candidate stops holding its hostname, its zone's count and
+        its ports, and the lane admits what the host's re-simulation refuses).
 
         Mirrors ExistingNode construction (existingnode.go:43-75): available
         capacity, remaining daemonset overhead, label requirements, ephemeral-
@@ -728,7 +736,10 @@ class TPUSolver:
         # (inverse); pods being scheduled this solve are excluded
         node_index = {n.node.name: e for e, n in enumerate(state_nodes)}
         group_of = {spec: g for g, spec in enumerate(snapshot.groups)}
-        scheduling_uids = {p.uid for cls in snapshot.classes for p in cls.pods}
+        scheduling_uids = (
+            set() if count_scheduling
+            else {p.uid for cls in snapshot.classes for p in cls.pods}
+        )
         # what GroupScope.matches_pod reads of a pod: namespace and labels
         pod_signatures: Dict[tuple, tuple] = {}
         bound_sig: List[int] = []  # per bound pod that counts: its signature,

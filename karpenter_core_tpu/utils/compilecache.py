@@ -224,10 +224,12 @@ def enable() -> None:
     with _lock:
         if _registered:
             return
+        from karpenter_core_tpu.ops import consolidate as consolidate_ops
         from karpenter_core_tpu.ops import masks as mask_ops
         from karpenter_core_tpu.ops import solve as solve_ops
 
         for t in (
+            consolidate_ops.SweepOutputs,
             solve_ops.ClassTensors,
             solve_ops.Statics,
             solve_ops.StaticArrays,
@@ -275,22 +277,23 @@ def _resolved_backend() -> str:
     return backend
 
 
-_kernel_hash: Optional[str] = None
+_kernel_hash: Dict[tuple, str] = {}
 
 
-def _kernel_src_hash() -> str:
-    global _kernel_hash
-    if _kernel_hash is None:
-        # every module traced into solve_core must invalidate the cache
+def _kernel_src_hash(*also) -> str:
+    """Hash of every module traced into solve_core — and of ``also``, the
+    modules a family traces around it — so an edit invalidates the cache."""
+    key = tuple(m.__name__ for m in also)
+    if key not in _kernel_hash:
         from karpenter_core_tpu.ops import masks as mask_ops
         from karpenter_core_tpu.ops import solve as solve_ops
 
         digest = hashlib.sha256()
-        for module in (solve_ops, mask_ops):
+        for module in (solve_ops, mask_ops) + also:
             with open(module.__file__, "rb") as f:
                 digest.update(f.read())
-        _kernel_hash = digest.hexdigest()[:16]
-    return _kernel_hash
+        _kernel_hash[key] = digest.hexdigest()[:16]
+    return _kernel_hash[key]
 
 
 _relax_hash: Optional[str] = None
@@ -399,10 +402,42 @@ def solve_callable(
         leaf_sig(warm_carry) if has_warm else None,
         leaf_sig(repair_plan) if has_repair else None,
     )
-    # in-flight dedup: the warmup thread and the first real batch race to
-    # build the same key; the loser waits on the winner's build instead of
-    # lowering+compiling the identical program twice (and, if the winner's
-    # build raised, becomes the builder and raises the same error itself)
+    if has_warm:
+        struct_args = (cls, statics_arrays, ex_static, warm_carry, repair_plan)
+    elif has_ex:
+        struct_args = (cls, statics_arrays, ex_state, ex_static)
+    else:
+        struct_args = (cls, statics_arrays)
+    base = _base_solve_fn(
+        has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
+    )
+    # the warm signature's donated argument (see _base_solve_fn)
+    donate_argnums = (3,) if donate_carry else ()
+    sharded = None
+    if mesh_axes is not None:
+        def sharded(structs):
+            from karpenter_core_tpu.parallel import mesh as mesh_mod
+
+            base_axis = _base_solve_fn(
+                has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
+                catalog_axis=mesh_axes[0][0],
+            )
+            return mesh_mod.sharded_solve_callable(
+                mesh_axes, base_axis, base, structs,
+                donate_argnums=donate_argnums,
+            )
+    return _memoized(key, lambda: _build_and_memo(
+        key, "solve", base, struct_args, sharded=sharded,
+        donate_argnums=donate_argnums,
+    ))
+
+
+def _memoized(key, build):
+    """The executable memoized under ``key``, built at most once at a time.
+    In-flight dedup: the warmup thread and the first real batch race to build
+    the same key; the loser waits on the winner's build instead of
+    lowering+compiling the identical program twice (and, if the winner's
+    build raised, becomes the builder and raises the same error itself)."""
     while True:
         with _lock:
             fn = _memo.get(key)
@@ -416,10 +451,7 @@ def solve_callable(
         building.wait(timeout=600.0)
 
     try:
-        return _build_and_memo(key, cls, statics_arrays, n_slots,
-                               key_has_bounds, ex_state, ex_static, n_passes,
-                               features, warm_carry, repair_plan, mesh_axes,
-                               donate_carry)
+        return build()
     finally:
         with _lock:
             _in_flight.pop(key, None)
@@ -454,48 +486,25 @@ def _base_solve_fn(has_warm, has_ex, n_slots, key_has_bounds, n_passes,
     )
 
 
-def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
-                    ex_state, ex_static, n_passes, features=None,
-                    warm_carry=None, repair_plan=None, mesh_axes=None,
-                    donate_carry=False):
-    """Build one executable for ``key``: export-cache load (or trace+export),
-    then AOT compile, then memoize.  Callers hold the key's in-flight slot.
-    Mesh variants (``mesh_axes``) build jit(shard_map(...)) instead and skip
-    the export cache — the memo (and XLA's persistent cache) keep them warm.
-    ``donate_carry`` variants (warm only) also skip the export cache and
-    build the jit with the warm-carry argument donated (position 3 of the
-    warm signature ``(cls, statics, ex_static, warm_carry, repair_plan)``)."""
+def _build_and_memo(key, family: str, base, struct_args, sharded=None,
+                    donate_argnums=()):
+    """Build one executable for ``key``: export-cache load (or trace+export)
+    of ``base`` — the family's positional-signature body — at
+    ``struct_args``' shapes, then AOT compile, then memoize.  Callers hold the
+    key's in-flight slot (``_memoized``).  ``sharded(structs)`` builds the
+    mesh variant (a jit(shard_map(...))) instead and skips the export cache —
+    the memo (and XLA's persistent cache) keep it warm.  ``donate_argnums``
+    variants also skip the export cache and build the jit with those
+    arguments donated."""
     import jax
 
-    has_ex = ex_state is not None
-    has_warm = warm_carry is not None
-    # the warm signature's donated argument index (see _base_solve_fn)
-    donate_argnums = (3,) if (donate_carry and has_warm) else ()
     digest = hashlib.sha256(repr(key).encode()).hexdigest()[:24]
-    path = os.path.join(cache_dir(), f"solve-{digest}.stablehlo")
-    if has_warm:
-        struct_args = (cls, statics_arrays, ex_static, warm_carry, repair_plan)
-    elif has_ex:
-        struct_args = (cls, statics_arrays, ex_state, ex_static)
-    else:
-        struct_args = (cls, statics_arrays)
+    path = os.path.join(cache_dir(), f"{family}-{digest}.stablehlo")
     structs = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), struct_args
     )
-    if mesh_axes is not None:
-        from karpenter_core_tpu.parallel import mesh as mesh_mod
-
-        base_axis = _base_solve_fn(
-            has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-            catalog_axis=mesh_axes[0][0],
-        )
-        base_plain = _base_solve_fn(
-            has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-        )
-        fn = mesh_mod.sharded_solve_callable(
-            mesh_axes, base_axis, base_plain, structs,
-            donate_argnums=donate_argnums,
-        )
+    if sharded is not None:
+        fn = sharded(structs)
         with _lock:
             _memo[key] = fn
             _stats["builds"] += 1
@@ -504,10 +513,7 @@ def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
         # donation is a lowering property, not part of an exported StableHLO
         # module — build the donating jit directly and AOT-compile it; the
         # memo + XLA persistent cache keep it warm
-        base = jax.jit(_base_solve_fn(
-            has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-        ), donate_argnums=donate_argnums)
-        compiled = base.lower(*structs).compile()
+        compiled = jax.jit(base, donate_argnums=donate_argnums).lower(*structs).compile()
         with _lock:
             _memo[key] = compiled
             _stats["builds"] += 1
@@ -522,10 +528,7 @@ def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
             log.warning("export cache load failed (%s), re-exporting", e)
             fn = None
     if fn is None:
-        base = jax.jit(_base_solve_fn(
-            has_warm, has_ex, n_slots, key_has_bounds, n_passes, features,
-        ))
-        exported = jax.export.export(base)(*structs)
+        exported = jax.export.export(jax.jit(base))(*structs)
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.tmp.{os.getpid()}"
         with open(tmp, "wb") as f:
@@ -539,6 +542,65 @@ def _build_and_memo(key, cls, statics_arrays, n_slots, key_has_bounds,
         _memo[key] = compiled
         _stats["builds"] += 1
     return compiled
+
+
+def sweep_callable(
+    planes,
+    lanes: int,
+    n_slots: int,
+    key_has_bounds,
+    n_passes: int = 1,
+    features=None,
+    mesh_axes=None,
+):
+    """The consolidation sweep's executable (``ops.consolidate.sweep``:
+    ``solve_core`` vmapped over ``lanes`` prefix sizes), served like a solve's:
+    keyed on the kernel sources, the backend, the static config, the lane
+    count and the planes' shape signature, exported to the StableHLO cache,
+    AOT-compiled, memoized with in-flight dedup and counted in ``builds``.
+    ``planes`` is the sweep body's argument tuple after the lane sizes —
+    ``(cls, statics_arrays, ex_state, ex_static, rank, counts, it_price)``,
+    host or device; the callable takes ``(sizes, *planes)``.  ``mesh_axes``
+    (``parallel.mesh.lane_mesh_axes``) selects the 2-D catalog × lane
+    shard_map variant, memoized under its own key and not exported."""
+    import numpy as np
+
+    from karpenter_core_tpu.ops import consolidate as consolidate_ops
+
+    enable()
+    features = snap_features(features)
+    key_has_bounds = tuple(key_has_bounds)
+    key = (
+        "sweep",
+        _kernel_src_hash(consolidate_ops),
+        _resolved_backend(),
+        int(lanes),
+        int(n_slots),
+        key_has_bounds,
+        int(n_passes),
+        tuple(features),
+        mesh_axes,
+        leaf_sig(planes),
+    )
+
+    def base(sizes, cls, statics_arrays, ex_state, ex_static, rank, counts, price):
+        return consolidate_ops.sweep(
+            cls, statics_arrays, key_has_bounds, ex_state, ex_static, rank,
+            counts, sizes, price, n_slots=int(n_slots), n_passes=int(n_passes),
+            features=features,
+        )
+
+    sharded = None
+    if mesh_axes is not None:
+        def sharded(structs):
+            return consolidate_ops.lane_sweep_fn(
+                tuple(mesh_axes), key_has_bounds, int(n_slots), int(n_passes),
+                features, structs[1], structs[2],
+            )
+    struct_args = (np.zeros(int(lanes), dtype=np.int32),) + tuple(planes)
+    return _memoized(key, lambda: _build_and_memo(
+        key, "sweep", base, struct_args, sharded=sharded,
+    ))
 
 
 def relax_callable(

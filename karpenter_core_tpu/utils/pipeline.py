@@ -179,20 +179,22 @@ def start_host_copy(tree) -> None:
             pass
 
 
-def fetch_tree(tree, site: str = "pipeline.fetch"):
+def fetch_tree(tree, site: str = "pipeline.fetch", key=None):
     """The batched serial-path fetch: start async copies on every leaf, then
     ONE ``jax.device_get`` over the whole tree — no array-by-array blocking
     (the ``decode.fetch`` contract, now shared by the tenant coalescer and
     the consolidation sweep).  The blocking ``device_get`` runs under the
     watchdog (utils/watchdog.py) so a hung device→host copy raises a bounded
     SolveTimeout instead of wedging the caller; ``site`` labels the deadline
-    bucket (the consolidation sweep and tenant coalescer pass their own)."""
+    bucket (the consolidation sweep and tenant coalescer pass their own) and
+    ``key`` splits it by executable, where one site fetches programs of
+    different sizes (the sweep's lane rungs)."""
     import jax
 
     from karpenter_core_tpu.utils import watchdog
 
     start_host_copy(tree)
-    return watchdog.run(site, jax.device_get, tree)
+    return watchdog.run(site, jax.device_get, tree, key=key)
 
 
 class HostStagingRing:

@@ -422,12 +422,11 @@ class TestConsolidationCostDelta:
         search, snapshot, candidates = self._fixture(policy)
         fake = self._fake_sweep(snapshot)
         monkeypatch.setattr(
-            consolidation_mod.consolidate_ops, "run_sweep",
+            consolidation_mod.consolidate_ops, "sweep_pass",
             lambda *a, **k: fake,
         )
         return search._evaluate_sweep(
-            snapshot, None, None, None, None,
-            np.array([1, 2], dtype=np.int32), candidates,
+            snapshot, None, np.array([1, 2], dtype=np.int32), candidates,
         )
 
     def test_node_count_scoring_takes_the_largest_prefix(self, monkeypatch):
@@ -450,12 +449,11 @@ class TestConsolidationCostDelta:
         # make the replacement nearly free: REPLACE saving 11 - 0.5 = 10.5
         fake = fake._replace(new_cost=np.array([0.0, 0.5], dtype=np.float32))
         monkeypatch.setattr(
-            consolidation_mod.consolidate_ops, "run_sweep",
+            consolidation_mod.consolidate_ops, "sweep_pass",
             lambda *a, **k: fake,
         )
         best, best_k = search._evaluate_sweep(
-            snapshot, None, None, None, None,
-            np.array([1, 2], dtype=np.int32), candidates,
+            snapshot, None, np.array([1, 2], dtype=np.int32), candidates,
         )
         assert best_k == 2 and best.action == Action.REPLACE
 

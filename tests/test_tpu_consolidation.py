@@ -9,7 +9,7 @@ from karpenter_core_tpu.controllers.deprovisioning import (
     Action,
     candidate_nodes,
 )
-from karpenter_core_tpu.solver.consolidation import TPUConsolidationSearch
+from karpenter_core_tpu.solver.consolidation import MAX_LANES, TPUConsolidationSearch
 from karpenter_core_tpu.testing import make_pod, make_provisioner
 from karpenter_core_tpu.testing.harness import expect_provisioned, make_environment
 
@@ -138,7 +138,7 @@ class TestTPUConsolidation:
             raise AssertionError("full node must not be deleted")
 
 class TestSearchLargestPrefix:
-    """The lane-sweep search must pin the exact boundary in ceil(log64(n))
+    """The lane-sweep search must pin the exact boundary in ceil(log72(n))
     passes, whatever the candidate count."""
 
     def _run(self, n, boundary):
@@ -170,7 +170,7 @@ class TestSearchLargestPrefix:
         best, passes = self._run(300_000, boundary=123_456)
         assert best == ("cmd", 123_456)
         assert len(passes) <= 4
-        assert all(p <= 64 for p in passes)
+        assert all(p <= MAX_LANES for p in passes)
 
     def test_no_valid_prefix(self):
         best, passes = self._run(100_000, boundary=0)
