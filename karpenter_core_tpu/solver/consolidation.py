@@ -1,11 +1,13 @@
 """TPU-accelerated consolidation search.
 
 Couples the kernel subset sweep (ops.consolidate) with the reference's
-validity rules (consolidation.go:190-290): every prefix of the disruption-
-sorted candidate list is simulated in parallel on device; the host then
+validity rules (consolidation.go:190-290): prefixes of the disruption-sorted
+candidate list are simulated side by side on device, one lane each; the host
 applies price filtering, the spot→spot prohibition, and the same-type price
-sanity filter to each lane's decoded replacement, and picks the largest valid
-prefix — the result the binary search converges to, computed in one pass.
+sanity filter to a lane's decoded replacement.  A small cluster has every
+prefix simulated in one pass and the largest valid one wins; above the lane
+ladder the passes are the reference's own binary search, three levels each
+(``search_largest_prefix``).
 """
 
 from __future__ import annotations
@@ -35,11 +37,26 @@ from karpenter_core_tpu.scheduling import Requirement, Requirements
 from karpenter_core_tpu.solver.tpu import TPUSolver
 
 MAX_LANES = consolidate_ops.LANE_LADDER[-1]
+# binary-search levels one pass speculates: the deepest tree of probes,
+# 2**LEVELS - 1 sizes, that the ladder's low rung holds (3 levels, 7 sizes on
+# 8 lanes).  A lane is not free on the chip — a scan step costs ~0.12 ms and
+# ~0.04 ms more per lane at 6 144 nodes — so passes x step is least on the
+# low rung, not on the widest one (docs/KERNEL_PERF.md "What a lane costs")
+LEVELS = (consolidate_ops.LANE_LADDER[0] + 1).bit_length() - 1
 
 CONSOLIDATE_PASSES = REGISTRY.counter(
     "karpenter_solver_consolidate_passes_total",
-    "Device passes of the multi-node consolidation sweep (one coarse pass over "
-    "the candidates, then one per re-grid of the bracket it leaves).",
+    "Device passes of the multi-node consolidation sweep (one over every "
+    "prefix of a small cluster; above the lane ladder one per three levels "
+    "of the binary search).",
+)
+CONSOLIDATE_PROBES = REGISTRY.counter(
+    "karpenter_solver_consolidate_probes_total",
+    "Prefix sizes the multi-node consolidation sweep simulated (real lanes, "
+    "not a rung's padding), by the form of the search: levels (the binary "
+    "search, three levels a pass), exhaustive (every prefix, one pass), "
+    "scored (one coarse pass under the policy objective).",
+    ("search",),
 )
 CONSOLIDATE_SECONDS = REGISTRY.histogram(
     "karpenter_solver_consolidate_seconds",
@@ -50,48 +67,82 @@ CONSOLIDATE_SECONDS = REGISTRY.histogram(
 )
 
 
+def search_form(n: int, refine: bool = True) -> str:
+    """Which search ``n`` candidates get — decided by what can be observed, n
+    against the lane ladder, and by the objective: ``scored`` (policy
+    objective: one coarse pass), ``exhaustive`` (n fits the top rung: every
+    prefix in one pass), else ``levels``."""
+    if not refine:
+        return "scored"
+    return "exhaustive" if n <= MAX_LANES else "levels"
+
+
+def reachable_sizes(lo_idx: int, hi_idx: int, levels: int = LEVELS) -> np.ndarray:
+    """The prefix sizes (``mid + 1``) that the next ``levels`` levels of
+    ``first_n_consolidation_option``'s binary search can probe from the state
+    ``(lo_idx, hi_idx)``, ascending: 1 + 2 + 4 tree nodes, fewer near the
+    leaves."""
+    sizes, frontier = [], [(lo_idx, hi_idx)]
+    for _ in range(levels):
+        children = []
+        for lo, hi in frontier:
+            if lo <= hi:
+                mid = (lo + hi) // 2
+                sizes.append(mid + 1)
+                children += [(lo, mid - 1), (mid + 1, hi)]
+        frontier = children
+    return np.array(sorted(sizes), dtype=np.int32)
+
+
 def search_largest_prefix(n, evaluate, refine: bool = True):
-    """Largest valid consolidation prefix via batched lane sweeps.
+    """The consolidation command for ``n`` disruption-sorted candidates.
 
-    ``evaluate(sizes) -> (best_command_or_None, best_k)`` runs one device
-    sweep over the given prefix sizes and reports the largest valid one.  Up
-    to MAX_LANES sizes cover [1, n] per pass; when the coarse grid leaves a
-    gap between the best lane and the next, further passes re-grid the
-    bracket, shrinking it ~MAX_LANES× each time — the boundary pins exactly
-    in ceil(log72(n)) passes (2 up to 5 184 candidates, 3 to 373k) vs the
-    reference's ~log2(n) sequential full simulations
-    (multinodeconsolidation.go:86-113).
+    ``evaluate(sizes, levels=0)`` runs one device pass over the given prefix
+    sizes and returns its verdicts: ``.command(k)`` — the command that closes
+    the first ``k`` candidates, None where the simulation or a price rule
+    refuses it — and ``.best()`` — ``(command, k)`` by the pass's own scoring.
 
-    ``refine=False`` stops after the coarse pass — cost-delta scoring
-    (policy objective) picks its optimum WITHIN a pass, and the bracket
-    refinement's larger-k-wins assumption would let a worse-saving larger
-    prefix displace it."""
-    if n <= MAX_LANES:
-        sizes = np.arange(1, n + 1, dtype=np.int32)
-    else:
-        sizes = np.unique(np.round(np.linspace(1, n, MAX_LANES)).astype(np.int32))
-    best, best_k = evaluate(sizes)
-    if n <= MAX_LANES or best is None or not refine:
-        return best
+    By ``search_form``:
 
-    lo = best_k
-    hi = int(sizes[np.searchsorted(sizes, best_k) + 1]) if best_k < int(sizes[-1]) else None
-    while hi is not None and hi - lo > 1:
-        span = np.arange(lo + 1, hi, dtype=np.int32)
-        if len(span) > MAX_LANES:
-            span = np.unique(
-                np.round(np.linspace(lo + 1, hi - 1, MAX_LANES)).astype(np.int32)
-            )
-        refined, refined_k = evaluate(span)
-        if refined is not None and refined_k > lo:
-            best, best_k = refined, refined_k
-            lo = refined_k
-            if refined_k < int(span[-1]):
-                hi = int(span[np.searchsorted(span, refined_k) + 1])
-            # else: the bracket (refined_k, hi) is already one grid interval
+    * ``exhaustive`` (n fits the top rung): every prefix is simulated in one
+      pass and the largest valid one wins — the one search that assumes
+      nothing about the shape of feasibility.
+    * ``levels``: the reference's binary search
+      (multinodeconsolidation.go:86-113; here
+      ``MultiNodeConsolidation.first_n_consolidation_option``), LEVELS levels
+      a pass.  A pass simulates the sizes those levels can reach from
+      ``(lo_idx, hi_idx)`` on the low rung; the host walks them with the
+      lanes' verdicts and keeps the command of the last valid probe on its
+      path.  Probe for probe the host search's — so its answer too, whatever
+      the shape of feasibility (valid sizes above an invalid one exist on
+      live clusters) — in ceil(levels / LEVELS) passes: 3 at 300 candidates,
+      4 or 5 at 5 000.
+    * ``scored`` (``refine=False``): the policy objective scores the lanes of
+      ONE coarse pass of the top rung; larger-k-wins does not hold for a
+      saving, so no pass follows it."""
+    form = search_form(n, refine)
+    if form != "levels":
+        if n <= MAX_LANES:
+            sizes = np.arange(1, n + 1, dtype=np.int32)
         else:
-            hi = int(span[0])
-    return best
+            sizes = np.unique(np.round(np.linspace(1, n, MAX_LANES)).astype(np.int32))
+        return evaluate(sizes).best()[0]
+
+    lo_idx, hi_idx, last_saved = 1, n - 1, None
+    while lo_idx <= hi_idx:
+        sizes = reachable_sizes(lo_idx, hi_idx)
+        # a probe tree d levels deep holds 2**(d-1) to 2**d - 1 sizes
+        verdicts = evaluate(sizes, levels=len(sizes).bit_length())
+        for _ in range(LEVELS):
+            if lo_idx > hi_idx:
+                break
+            mid = (lo_idx + hi_idx) // 2
+            command = verdicts.command(mid + 1)
+            if command is not None:
+                last_saved, lo_idx = command, mid + 1
+            else:
+                hi_idx = mid - 1
+    return last_saved
 
 
 @dataclass
@@ -114,6 +165,21 @@ class TPUReplacement:
 
 
 class TPUConsolidationSearch:
+    """Multi-node consolidation on the device: ``compute_command`` encodes the
+    cluster once, uploads the sweep's planes once, and runs
+    ``search_largest_prefix`` over them — each pass one ``sweep_pass`` of
+    ``solve_core`` lanes, each lane one prefix of the candidates closed.
+
+    With no policy objective the command is the one
+    ``MultiNodeConsolidation.first_n_consolidation_option`` returns on the
+    host, in action and in the number of nodes removed, wherever a lane and
+    the host's simulation of the same prefix agree: up to the lane ladder's
+    top rung (72 candidates) every prefix is simulated, so the largest valid
+    one is found even where the binary search would miss it; above it the
+    search probes the sizes the host's binary search probes.  ``last_passes``
+    and ``last_probes`` count the newest command's device passes and the
+    prefix sizes they simulated."""
+
     def __init__(self, cloud_provider, provisioners, policy=None) -> None:
         # policy (policy.PolicyConfig): with the objective enabled, lanes are
         # scored by FLEET COST DELTA (old subset price minus replacement
@@ -121,7 +187,8 @@ class TPUConsolidationSearch:
         # smaller prefix removes fewer nodes (docs/POLICY.md).  None/disabled
         # keeps the reference behavior: the largest valid prefix wins.
         self.policy = policy
-        self.last_passes = 0  # device passes of the newest compute_command
+        self.cost_scoring = policy is not None and getattr(policy, "enabled", False)
+        self.last_passes = self.last_probes = 0
         self.solver = TPUSolver(cloud_provider, provisioners, policy=policy)
         self.it_by_name = {
             it.name: it
@@ -138,7 +205,7 @@ class TPUConsolidationSearch:
     ) -> Command:
         """candidates must be disruption-cost sorted.  Raises KernelUnsupported
         when the pod shapes need the host path."""
-        self.last_passes = 0
+        self.last_passes = self.last_probes = 0
         if not candidates:
             return Command(Action.DO_NOTHING)
 
@@ -207,13 +274,16 @@ class TPUConsolidationSearch:
 
         best = search_largest_prefix(
             len(candidates),
-            lambda sizes: self._evaluate_sweep(snapshot, planes, sizes, candidates),
-            refine=not (
-                self.policy is not None and getattr(self.policy, "enabled", False)
+            lambda sizes, levels=0: self._evaluate_sweep(
+                snapshot, planes, sizes, candidates, levels
             ),
+            refine=not self.cost_scoring,
         )
         cmd = best if best is not None else Command(Action.DO_NOTHING)
         CONSOLIDATE_PASSES.labels().inc(self.last_passes)
+        CONSOLIDATE_PROBES.labels(
+            search_form(len(candidates), not self.cost_scoring)
+        ).inc(self.last_probes)
         CONSOLIDATE_SECONDS.labels(cmd.action.value).observe(time.perf_counter() - t0)
         return cmd
 
@@ -228,19 +298,9 @@ class TPUConsolidationSearch:
                 prices[i] = offering.price
         return np.cumsum(prices)
 
-    def _evaluate_sweep(self, snapshot, planes, sizes, candidates):
-        """(best command, its prefix size) across the given lane sizes: one
-        device pass (``consolidate.sweep``) and its decode
-        (``consolidate.decode``: lanes → commands under the price rules).
-
-        Default scoring is the reference's: the LARGEST valid prefix wins
-        (most nodes removed).  With the policy objective enabled, lanes are
-        scored by fleet-cost saving — old subset price minus the lane's
-        replacement cost (the kernel's ``new_cost``) — and the largest
-        saving wins, node count breaking ties; fewest-nodes and
-        cheapest-fleet genuinely disagree when a large prefix forces a
-        pricey replacement while a smaller one deletes outright
-        (tests/test_policy.py pins both directions)."""
+    def _evaluate_sweep(self, snapshot, planes, sizes, candidates, levels=0):
+        """One device pass (``consolidate.sweep``) over the given prefix sizes
+        and its verdicts, decoded on demand (``LaneVerdicts``)."""
         # the sweep auto-routes onto the 2D (catalog × lane) mesh when
         # KC_SOLVER_MESH enables it (parallel.mesh.lane_mesh_axes): prefix
         # lanes split across the lane axis, the catalog shards within each
@@ -251,73 +311,14 @@ class TPUConsolidationSearch:
         # resolve differently with the mesh on vs off — same caveat as any
         # recompile (docs/KERNEL_PERF.md "Layer 5")
         self.last_passes += 1
+        self.last_probes += len(sizes)
         with tracing.span(
             "consolidate.sweep", lanes=len(sizes),
-            lanes_padded=consolidate_ops.lane_rung(len(sizes)),
+            lanes_padded=consolidate_ops.lane_rung(len(sizes)), levels=levels,
             lo=int(sizes[0]), hi=int(sizes[-1]), **{"pass": self.last_passes},
         ):
             out = consolidate_ops.sweep_pass(planes, sizes)
-        # "decode" inside it is the decode layer's own span name (the fetched
-        # planes → objects), so the layer's shared metric reads a sweep too
-        with tracing.span("consolidate.decode", lanes=len(sizes)) as sp, \
-                tracing.span("decode"):
-            best, best_k, lanes_valid = self._decode_lanes(
-                snapshot, out, sizes, candidates
-            )
-            sp.set(lanes_valid=lanes_valid, best_k=best_k)
-        return best, best_k
-
-    def _decode_lanes(self, snapshot, out, sizes, candidates):
-        """(best command, its prefix size, lanes that gave a command)."""
-        n_new = np.asarray(out.n_new)
-        failed = np.asarray(out.failed)
-        uninit = np.asarray(out.used_uninitialized)
-        viable = np.asarray(out.new_viable)
-        zone = np.asarray(out.new_zone)
-        ct = np.asarray(out.new_ct)
-        used = np.asarray(out.new_used)
-        tmpl_id = np.asarray(out.new_tmpl)
-        new_cost = np.asarray(out.new_cost)
-        cost_scoring = self.policy is not None and getattr(
-            self.policy, "enabled", False
-        )
-        old_cum = self._candidate_price_cumsum(candidates) if cost_scoring else None
-
-        # what the simulation alone decides: every pod placed, no
-        # uninitialized node relied on, at most one node opened
-        accepted = (failed == 0) & ~uninit & (n_new <= 1)
-        best: Optional[Command] = None
-        best_k = 0
-        best_saving = -np.inf
-        # largest first: where the largest valid prefix wins (no cost scoring)
-        # the first lane that yields a command is the answer, and the O(k)
-        # build of every smaller lane's command is never paid
-        for lane in np.flatnonzero(accepted)[::-1].tolist():
-            k = int(sizes[lane])
-            subset = candidates[:k]
-            if int(n_new[lane]) == 0:
-                cmd = Command(Action.DELETE, [c.node for c in subset])
-                lane_cost = 0.0
-            else:
-                replacement = self._decode_replacement(
-                    snapshot, viable[lane, 0], zone[lane, 0], ct[lane, 0],
-                    used[lane, 0], int(tmpl_id[lane, 0]), subset,
-                )
-                if replacement is None:
-                    continue
-                cmd = Command(
-                    Action.REPLACE, [c.node for c in subset], [replacement]
-                )
-                lane_cost = float(new_cost[lane])
-            if not cost_scoring:
-                best, best_k = cmd, k
-                break
-            saving = float(old_cum[k - 1]) - lane_cost if k >= 1 else 0.0
-            if np.isnan(saving):
-                saving = -np.inf  # unpriceable subset: never preferred
-            if saving > best_saving or (saving == best_saving and k > best_k):
-                best, best_k, best_saving = cmd, k, saving
-        return best, best_k, int(accepted.sum())
+        return LaneVerdicts(self, snapshot, out, sizes, candidates)
 
     def _decode_replacement(
         self, snapshot, viable_row, zone_row, ct_row, used_row, tmpl_idx, subset
@@ -383,3 +384,91 @@ class TPUConsolidationSearch:
             if not replacement.instance_type_options:
                 return None
         return replacement
+
+
+class LaneVerdicts:
+    """One pass's lanes → commands under the price rules, built when asked
+    for (``consolidate.decode``; a command lists its k nodes, so none is built
+    for a size the search does not reach)."""
+
+    def __init__(self, search, snapshot, out, sizes, candidates) -> None:
+        self.search, self.snapshot, self.out = search, snapshot, out
+        self.sizes, self.candidates = np.asarray(sizes), candidates
+        # what the simulation alone decides: every pod placed, no
+        # uninitialized node relied on, at most one node opened
+        self.accepted = (out.failed == 0) & ~out.used_uninitialized & (out.n_new <= 1)
+        self.lanes_valid = int(self.accepted.sum())
+
+    def _decoding(self, **attrs):
+        # callers open a "decode" span inside it: the decode layer's own span
+        # name (the fetched planes → objects), so the layer's shared metric
+        # reads a sweep too
+        return tracing.span(
+            "consolidate.decode", lanes=len(self.sizes),
+            lanes_valid=self.lanes_valid, **attrs,
+        )
+
+    def _lane_command(self, lane: int, k: int):
+        """(command, replacement cost) of one accepted lane — None where a
+        price rule refuses its replacement."""
+        out, subset = self.out, self.candidates[:k]
+        if int(out.n_new[lane]) == 0:
+            return Command(Action.DELETE, [c.node for c in subset]), 0.0
+        replacement = self.search._decode_replacement(
+            self.snapshot, out.new_viable[lane, 0], out.new_zone[lane, 0],
+            out.new_ct[lane, 0], out.new_used[lane, 0],
+            int(out.new_tmpl[lane, 0]), subset,
+        )
+        if replacement is None:
+            return None, 0.0
+        return (Command(Action.REPLACE, [c.node for c in subset], [replacement]),
+                float(out.new_cost[lane]))
+
+    def command(self, k: int) -> Optional[Command]:
+        """The command that closes the first ``k`` candidates — the reference's
+        verdict on one probe (consolidation.go:190-290)."""
+        lane = int(np.searchsorted(self.sizes, k))
+        with self._decoding(k=k) as sp, tracing.span("decode"):
+            command = None
+            if self.accepted[lane]:
+                command, _ = self._lane_command(lane, k)
+            sp.set(valid=command is not None)
+        return command
+
+    def best(self):
+        """(best command, its prefix size) across the pass.
+
+        Default scoring is the reference's: the LARGEST valid prefix wins
+        (most nodes removed).  With the policy objective enabled, lanes are
+        scored by fleet-cost saving — old subset price minus the lane's
+        replacement cost (the kernel's ``new_cost``) — and the largest
+        saving wins, node count breaking ties; fewest-nodes and
+        cheapest-fleet genuinely disagree when a large prefix forces a
+        pricey replacement while a smaller one deletes outright
+        (tests/test_policy.py pins both directions)."""
+        cost_scoring = self.search.cost_scoring
+        old_cum = (
+            self.search._candidate_price_cumsum(self.candidates) if cost_scoring else None
+        )
+        best: Optional[Command] = None
+        best_k = 0
+        best_saving = -np.inf
+        with self._decoding() as sp, tracing.span("decode"):
+            # largest first: where the largest valid prefix wins (no cost
+            # scoring) the first lane that yields a command is the answer, and
+            # the O(k) build of every smaller lane's command is never paid
+            for lane in np.flatnonzero(self.accepted)[::-1].tolist():
+                k = int(self.sizes[lane])
+                cmd, lane_cost = self._lane_command(lane, k)
+                if cmd is None:
+                    continue
+                if not cost_scoring:
+                    best, best_k = cmd, k
+                    break
+                saving = float(old_cum[k - 1]) - lane_cost if k >= 1 else 0.0
+                if np.isnan(saving):
+                    saving = -np.inf  # unpriceable subset: never preferred
+                if saving > best_saving or (saving == best_saving and k > best_k):
+                    best, best_k, best_saving = cmd, k, saving
+            sp.set(best_k=best_k)
+        return best, best_k

@@ -4,11 +4,12 @@ bound pods of five kinds dealt to 60 %), every node a candidate.
 
   right      the host's own simulation accepts every command the sweep
              returns (``validate_command``, what the controller runs before it
-             acts), and the sweep's prefix is never shorter than the host
-             binary search's
+             acts); above the lane ladder the sweep's command is the host
+             binary search's in action and size, up to the top rung (every
+             prefix simulated) never shorter
   normal     the sweep's executables come from the compile cache, a cluster
-             uses at most one per rung of the lane ladder whatever brackets
-             the search leaves, a repeat builds none
+             uses one rung of the lane ladder whatever its answer, a repeat
+             builds none; a request counts the sizes it simulated
   watched    deadlines are kept per executable: a short pass never sets a
              wide pass's deadline
 """
@@ -20,10 +21,13 @@ import numpy as np
 import pytest
 
 from benchmark.traffic.kinds import consolidate_cycle, consolidate_reference
+from karpenter_core_tpu import tracing
 from karpenter_core_tpu.cloudprovider import fake as fake_cp
 from karpenter_core_tpu.controllers.deprovisioning import Action
 from karpenter_core_tpu.ops import consolidate as consolidate_ops
-from karpenter_core_tpu.solver.consolidation import TPUConsolidationSearch
+from karpenter_core_tpu.solver.consolidation import (
+    CONSOLIDATE_PROBES, LEVELS, MAX_LANES, TPUConsolidationSearch,
+)
 from karpenter_core_tpu.testing import make_pod, make_provisioner
 from karpenter_core_tpu.utils import compilecache, watchdog
 
@@ -57,9 +61,16 @@ def sweep(env, candidates, pending=()):
     return search, command
 
 
-@pytest.mark.parametrize("seed,pending", [(1, 0), (2, 0), (3, 0), (4, 8)])
-def test_the_host_accepts_the_sweeps_command(seed, pending):
-    env, candidates, waiting = live_cluster(40, seed, pending)
+@pytest.mark.parametrize("n_nodes,seed,pending", [
+    (40, 1, 0), (40, 2, 0), (40, 3, 0), (40, 4, 8),
+    # above the top rung: the host's probes, so the host's command
+    (150, 1, 0), (150, 2, 0),
+    # k = 208 is invalid and k = 209 a valid REPLACE: feasibility is not
+    # monotone at the boundary, and host and sweep both probe 209
+    (300, 3, 0),
+])
+def test_the_host_accepts_the_sweeps_command(n_nodes, seed, pending):
+    env, candidates, waiting = live_cluster(n_nodes, seed, pending)
     host = env.deprovisioning.multi_node_consolidation
     assert len(env.provisioning.get_pending_pods()) == pending
     _, command = sweep(env, candidates, waiting)
@@ -67,27 +78,48 @@ def test_the_host_accepts_the_sweeps_command(seed, pending):
     assert command.action in (Action.DELETE, Action.REPLACE)
     assert host.validate_command(command, candidates)
     assert len(command.nodes_to_remove) >= len(wanted.nodes_to_remove) >= 2
+    if n_nodes > MAX_LANES:
+        assert len(command.nodes_to_remove) == len(wanted.nodes_to_remove)
     if len(command.nodes_to_remove) == len(wanted.nodes_to_remove):
         assert command.action == wanted.action
+    if (n_nodes, seed) == (300, 3):
+        assert (command.action, len(command.nodes_to_remove)) == (Action.REPLACE, 209)
 
 
-@pytest.mark.parametrize("n_nodes,passes,rungs", [
-    (5, 1, 1),     # one pass on the low rung
-    (40, 1, 1),    # one pass on the top rung
-    (70, 1, 1),    # still one pass on the top rung
-    (130, 2, 2),   # the coarse pass, then a bracket of one size
+@pytest.mark.parametrize("n_nodes,passes", [
+    (5, 1),     # one pass on the low rung
+    (40, 1),    # one pass on the top rung
+    (70, 1),    # still one pass on the top rung
+    (130, 3),   # 7 or 8 levels of the binary search, three a pass, on the low rung
+    (300, 3),   # 8 or 9 levels
 ])
-def test_a_clusters_sweeps_stay_on_the_lane_ladder(n_nodes, passes, rungs):
+def test_a_clusters_sweeps_stay_on_the_lane_ladder(n_nodes, passes):
     env, candidates, _ = live_cluster(n_nodes, seed=5)
     compilecache.reset_memo()
     search, first = sweep(env, candidates)
     builds = compilecache.stats()["builds"]
-    assert search.last_passes >= passes
-    assert 0 < builds <= rungs <= len(consolidate_ops.LANE_LADDER)
+    assert search.last_passes == passes
+    assert builds == 1  # one rung a cluster, whatever its answer
     search, again = sweep(env, candidates)
     assert compilecache.stats()["builds"] == builds  # none on a repeat
     assert [n.name for n in again.nodes_to_remove] == [n.name for n in first.nodes_to_remove]
     assert again.action == first.action
+
+
+def test_a_request_counts_the_sizes_it_simulates(traced):
+    env, candidates, _ = live_cluster(150, seed=1)
+    counted = CONSOLIDATE_PROBES.labels("levels").value
+    elsewhere = sum(CONSOLIDATE_PROBES.labels(form).value for form in ("exhaustive", "scored"))
+    search, _ = sweep(env, candidates)
+    passes = [s["attrs"] for trace in tracing.TRACE_STORE.last(16) for s in trace.spans
+              if s["name"] == "consolidate.sweep"]
+    assert [a["pass"] for a in passes] == [1, 2, 3]
+    assert all(a["lanes"] <= 2 ** LEVELS - 1 and a["lanes_padded"] == 8 for a in passes)
+    assert [a["levels"] for a in passes] == [3, 3, 1]  # 149 interior sizes: 7 levels
+    assert search.last_probes == sum(a["lanes"] for a in passes) == 15
+    assert CONSOLIDATE_PROBES.labels("levels").value - counted == search.last_probes
+    assert elsewhere == sum(
+        CONSOLIDATE_PROBES.labels(form).value for form in ("exhaustive", "scored"))
 
 
 def test_lane_rungs():

@@ -427,7 +427,7 @@ class TestConsolidationCostDelta:
         )
         return search._evaluate_sweep(
             snapshot, None, np.array([1, 2], dtype=np.int32), candidates,
-        )
+        ).best()
 
     def test_node_count_scoring_takes_the_largest_prefix(self, monkeypatch):
         best, best_k = self._evaluate(None, monkeypatch)
@@ -454,7 +454,7 @@ class TestConsolidationCostDelta:
         )
         best, best_k = search._evaluate_sweep(
             snapshot, None, np.array([1, 2], dtype=np.int32), candidates,
-        )
+        ).best()
         assert best_k == 2 and best.action == Action.REPLACE
 
 

@@ -137,46 +137,121 @@ class TestTPUConsolidation:
         if cmd.action == Action.DELETE:
             raise AssertionError("full node must not be deleted")
 
-class TestSearchLargestPrefix:
-    """The lane-sweep search must pin the exact boundary in ceil(log72(n))
-    passes, whatever the candidate count."""
+class _Verdicts:
+    """What ``evaluate`` returns, over a plain validity function."""
 
-    def _run(self, n, boundary):
+    def __init__(self, sizes, valid):
+        self.sizes, self.valid = [int(k) for k in sizes], valid
+
+    def command(self, k):
+        assert k in self.sizes, "the walk asked for a size the pass did not simulate"
+        return ("cmd", k) if self.valid(k) else None
+
+    def best(self):
+        k = max((k for k in self.sizes if self.valid(k)), default=0)
+        return (("cmd", k) if k else None), k
+
+
+def host_binary_search(n, valid):
+    """``first_n_consolidation_option`` over a validity function:
+    (answer, the sizes it probed in order)."""
+    lo_idx, hi_idx, last_saved, probed = 1, n - 1, None, []
+    while lo_idx <= hi_idx:
+        mid = (lo_idx + hi_idx) // 2
+        probed.append(mid + 1)
+        if valid(mid + 1):
+            last_saved, lo_idx = ("cmd", mid + 1), mid + 1
+        else:
+            hi_idx = mid - 1
+    return last_saved, probed
+
+
+class TestSearchLargestPrefix:
+    """Above the lane ladder the search is the host's binary search, LEVELS
+    levels a pass on the low rung: its probes, so its answer, whatever the
+    shape of feasibility.  Up to the top rung one pass simulates every
+    prefix."""
+
+    def _run(self, n, valid, refine=True):
         from karpenter_core_tpu.solver.consolidation import search_largest_prefix
 
         passes = []
 
-        def evaluate(sizes):
-            passes.append(len(sizes))
-            valid = [int(k) for k in sizes if k <= boundary]
-            if not valid:
-                return None, 0
-            return ("cmd", max(valid)), max(valid)
+        def evaluate(sizes, levels=0):
+            assert list(sizes) == sorted(set(int(k) for k in sizes))
+            passes.append(([int(k) for k in sizes], levels))
+            return _Verdicts(sizes, valid)
 
-        best = search_largest_prefix(n, evaluate)
-        return best, passes
+        return search_largest_prefix(n, evaluate, refine=refine), passes
 
-    def test_small_exact_single_pass(self):
-        best, passes = self._run(40, boundary=17)
-        assert best == ("cmd", 17)
-        assert len(passes) == 1
+    def _holds_against_the_host(self, n, valid):
+        from karpenter_core_tpu.ops.consolidate import LANE_LADDER
+        from karpenter_core_tpu.solver.consolidation import LEVELS
 
-    def test_coarse_gap_refined_exactly(self):
-        best, passes = self._run(500, boundary=123)
-        assert best == ("cmd", 123)
-        assert len(passes) <= 2
+        best, passes = self._run(n, valid)
+        wanted, probed = host_binary_search(n, valid)
+        assert best == wanted
+        assert LEVELS == 3 and all(len(sizes) <= 7 <= LANE_LADDER[0] for sizes, _ in passes)
+        assert len(passes) == -(-len(probed) // LEVELS)
+        # pass i simulated the host's probes 3i .. 3i + 2, and says how many
+        # levels it speculated
+        for i, (sizes, levels) in enumerate(passes):
+            mine = probed[LEVELS * i: LEVELS * (i + 1)]
+            assert set(mine) <= set(sizes)
+            assert len(mine) <= levels <= LEVELS
+        return best, len(passes)
 
-    def test_beyond_4096_multi_round(self):
-        best, passes = self._run(300_000, boundary=123_456)
-        assert best == ("cmd", 123_456)
-        assert len(passes) <= 4
-        assert all(p <= MAX_LANES for p in passes)
+    @pytest.mark.parametrize("n,boundary,passes", [
+        (40, 17, 1),                  # small: one exhaustive pass
+        (72, 72, 1),                  # the top rung itself
+        (500, 123, 3),
+        (300_000, 123_456, 7),
+        (100_000, 0, 6),              # no valid prefix
+        (100_000, 100_000, 6),        # all valid
+        (73, 2, 2),                   # the first size past the ladder
+        (300, 1, 3), (300, 150, 3), (300, 209, 3), (300, 300, 3),
+        (5_000, 2, 4), (5_000, 3_133, None), (5_000, 4_999, None), (5_000, 5_000, 5),
+    ])
+    def test_pins_the_boundary(self, n, boundary, passes):
+        valid = lambda k: k <= boundary  # noqa: E731
+        if n <= MAX_LANES:
+            best, seen = self._run(n, valid)
+            assert best == (("cmd", boundary) if boundary else None)
+            assert seen == [(list(range(1, n + 1)), 0)]
+            return
+        best, took = self._holds_against_the_host(n, valid)
+        assert took == passes if passes else took in (4, 5)
+        # a monotone boundary is pinned exactly (size 1 is no multi-node command)
+        assert best == (("cmd", boundary) if boundary >= 2 else None)
 
-    def test_no_valid_prefix(self):
-        best, passes = self._run(100_000, boundary=0)
-        assert best is None
-        assert len(passes) == 1
+    @pytest.mark.parametrize("n", [300, 5_000])
+    def test_every_boundary_of_a_cluster(self, n):
+        counts = {}
+        for boundary in range(0, n + 1, 1 if n <= 300 else 37):
+            _, took = self._holds_against_the_host(n, lambda k: k <= boundary)
+            counts[took] = counts.get(took, 0) + 1
+        assert set(counts) == ({3} if n == 300 else {4, 5})
 
-    def test_all_valid(self):
-        best, _ = self._run(100_000, boundary=100_000)
-        assert best == ("cmd", 100_000)
+    @pytest.mark.parametrize("seed", range(24))
+    def test_non_monotone_feasibility_is_the_hosts_answer(self, seed):
+        """Holes below the boundary and islands above it (a REPLACE that is
+        valid one size past an invalid one): a grid answers by where its points
+        fall, the host's probes answer as the host does."""
+        import random
+
+        rng = random.Random(seed)
+        n = rng.choice([73, 150, 300, 1_000, 5_000])
+        boundary = rng.randrange(2, n)
+        holes = {rng.randrange(2, boundary + 1) for _ in range(rng.randrange(1, 12))}
+        islands = {
+            min(n, boundary + rng.randrange(1, 40)) for _ in range(rng.randrange(1, 12))
+        }
+        self._holds_against_the_host(
+            n, lambda k: (k <= boundary and k not in holes) or k in islands
+        )
+
+    def test_scored_search_is_one_coarse_pass(self):
+        best, passes = self._run(5_000, lambda k: k <= 3_000, refine=False)
+        (sizes, levels), = passes
+        assert len(sizes) == MAX_LANES and levels == 0
+        assert best == ("cmd", max(k for k in sizes if k <= 3_000))
