@@ -70,28 +70,30 @@ def sweep(
     over that axis (parallel.mesh; bit-identical to unsharded)."""
 
     def one_prefix(k):
-        subset = candidate_rank < k  # bool[E]
-        # close the subset's nodes; the topology count seeds derive from
-        # grp_node_member/owner masked by open_, so pre-existing pods on
-        # removed nodes stop counting automatically (excludedPods semantics)
-        ex = ex_state._replace(open_=ex_state.open_ & ~subset)
-        # displaced pods join their classes
-        displaced = jnp.sum(
-            ex_cls_count * subset[None, :].astype(jnp.int32), axis=-1
-        )  # [C]
-        cls = class_tensors._replace(count=class_tensors.count + displaced)
+        with jax.named_scope("kc.sweep.seed"):
+            subset = candidate_rank < k  # bool[E]
+            # close the subset's nodes; the topology count seeds derive from
+            # grp_node_member/owner masked by open_, so pre-existing pods on
+            # removed nodes stop counting automatically (excludedPods semantics)
+            ex = ex_state._replace(open_=ex_state.open_ & ~subset)
+            # displaced pods join their classes
+            displaced = jnp.sum(
+                ex_cls_count * subset[None, :].astype(jnp.int32), axis=-1
+            )  # [C]
+            cls = class_tensors._replace(count=class_tensors.count + displaced)
         out = solve_ops.solve_core(
             cls, statics_arrays, n_slots, key_has_bounds, ex, ex_static,
             n_passes=n_passes, features=features, catalog_axis=catalog_axis,
             lane_axis=LANES,
         )
-        n_new = out.state.n_next
-        failed = jnp.sum(out.failed)
-        uninit = jnp.any(
-            (out.assign_existing > 0) & ~ex_static.init[None, :]
-        )
-        prices = solve_ops.node_prices(out.state, it_price, catalog_axis)
-        cost = jnp.sum(jnp.where(jnp.isfinite(prices), prices, 0.0))
+        with jax.named_scope("kc.sweep.reduce"):
+            n_new = out.state.n_next
+            failed = jnp.sum(out.failed)
+            uninit = jnp.any(
+                (out.assign_existing > 0) & ~ex_static.init[None, :]
+            )
+            prices = solve_ops.node_prices(out.state, it_price, catalog_axis)
+            cost = jnp.sum(jnp.where(jnp.isfinite(prices), prices, 0.0))
         return (
             n_new,
             failed,
@@ -325,8 +327,7 @@ def sweep_pass(planes: SweepPlanes, prefix_sizes: np.ndarray,
         # "dispatch" covers the executable lookup (a build on first use) and
         # the async launch; "solve" blocks on the outputs (tracing only) so
         # device compute is the solve's, as in compilecache.run_solve
-        with tracing.span("dispatch", n_slots=n_slots, n_passes=planes.n_passes,
-                          lanes=lanes,
+        with tracing.span("dispatch", n_passes=planes.n_passes, lanes=lanes,
                           mesh=repr(planes.mesh_axes) if planes.mesh_axes else None):
             fn = compilecache.sweep_callable(
                 planes.args, lanes, n_slots, planes.key_has_bounds,
@@ -342,6 +343,6 @@ def sweep_pass(planes: SweepPlanes, prefix_sizes: np.ndarray,
     # started up front); the barrier budgets under its own watchdog site (a
     # hung lane sweep must not wedge the deprovisioner — it surfaces as a
     # SolveTimeout the breaker counts)
-    with tracing.span("decode.fetch", arrays=len(SweepOutputs._fields), batched=True):
+    with tracing.span("decode.fetch"):
         out = pipeline_mod.fetch_tree(out, site="consolidate.sweep", key=key)
     return SweepOutputs(*(np.asarray(plane)[:n_sizes] for plane in out))

@@ -96,50 +96,51 @@ def select_offerings(
     is_spot: jnp.ndarray,  # bool[CT]
     weights: ObjectiveWeights,
 ) -> ObjectiveSelection:
-    n = viable.shape[0]
-    n_zct = zone.shape[1] * ct.shape[1]
-    n_ct = ct.shape[1]
-    expected, score = cell_scores(price, risk, throughput, weights)
-    allowed = (
-        viable[:, :, None, None]
-        & zone[:, None, :, None]
-        & ct[:, None, None, :]
-        & jnp.isfinite(price)[None, :, :, :]
-    )
-    scored = jnp.where(allowed, score[None], jnp.inf).reshape(n, -1)
-    best = jnp.min(scored, axis=1)
-    has_any = jnp.isfinite(best)
-    # exact-tie set, then the spot-preference filter: among tied cells keep
-    # the spot ones when any exist (and the knob is on); argmax then takes
-    # the FIRST tied cell in (it, zone, ct) row-major order — deterministic,
-    # and matching the catalog's stable index order on full ties
-    is_best = scored == best[:, None]
-    spot_flat = jnp.broadcast_to(
-        is_spot[None, None, :], price.shape
-    ).reshape(-1)
-    spot_ties = is_best & spot_flat[None, :]
-    use_spot = weights.spot_preference & jnp.any(spot_ties, axis=1)
-    candidates = jnp.where(use_spot[:, None], spot_ties, is_best)
-    sel = jnp.argmax(candidates, axis=1).astype(jnp.int32)
-    sel_it = sel // n_zct
-    sel_zone = (sel % n_zct) // n_ct
-    sel_ct = sel % n_ct
-    sel_price = price.reshape(-1)[sel]
-    sel_expected = expected.reshape(-1)[sel]
-    active = open_ & (pod_count > 0) & has_any
-    zero = jnp.float32(0.0)
-    fleet_cost = jnp.sum(jnp.where(active, sel_price, zero))
-    fleet_expected = jnp.sum(jnp.where(active, sel_expected, zero))
-    return ObjectiveSelection(
-        sel_it=sel_it,
-        sel_zone=sel_zone,
-        sel_ct=sel_ct,
-        price=sel_price,
-        expected=sel_expected,
-        active=active,
-        fleet_cost=fleet_cost,
-        fleet_expected=fleet_expected,
-    )
+    with jax.named_scope("kc.finish"):
+        n = viable.shape[0]
+        n_zct = zone.shape[1] * ct.shape[1]
+        n_ct = ct.shape[1]
+        expected, score = cell_scores(price, risk, throughput, weights)
+        allowed = (
+            viable[:, :, None, None]
+            & zone[:, None, :, None]
+            & ct[:, None, None, :]
+            & jnp.isfinite(price)[None, :, :, :]
+        )
+        scored = jnp.where(allowed, score[None], jnp.inf).reshape(n, -1)
+        best = jnp.min(scored, axis=1)
+        has_any = jnp.isfinite(best)
+        # exact-tie set, then the spot-preference filter: among tied cells keep
+        # the spot ones when any exist (and the knob is on); argmax then takes
+        # the FIRST tied cell in (it, zone, ct) row-major order — deterministic,
+        # and matching the catalog's stable index order on full ties
+        is_best = scored == best[:, None]
+        spot_flat = jnp.broadcast_to(
+            is_spot[None, None, :], price.shape
+        ).reshape(-1)
+        spot_ties = is_best & spot_flat[None, :]
+        use_spot = weights.spot_preference & jnp.any(spot_ties, axis=1)
+        candidates = jnp.where(use_spot[:, None], spot_ties, is_best)
+        sel = jnp.argmax(candidates, axis=1).astype(jnp.int32)
+        sel_it = sel // n_zct
+        sel_zone = (sel % n_zct) // n_ct
+        sel_ct = sel % n_ct
+        sel_price = price.reshape(-1)[sel]
+        sel_expected = expected.reshape(-1)[sel]
+        active = open_ & (pod_count > 0) & has_any
+        zero = jnp.float32(0.0)
+        fleet_cost = jnp.sum(jnp.where(active, sel_price, zero))
+        fleet_expected = jnp.sum(jnp.where(active, sel_expected, zero))
+        return ObjectiveSelection(
+            sel_it=sel_it,
+            sel_zone=sel_zone,
+            sel_ct=sel_ct,
+            price=sel_price,
+            expected=sel_expected,
+            active=active,
+            fleet_cost=fleet_cost,
+            fleet_expected=fleet_expected,
+        )
 
 
 def select_for_state(state, planes, config, capacity_types) -> ObjectiveSelection:

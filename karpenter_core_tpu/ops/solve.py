@@ -500,10 +500,11 @@ def _fill_existing(quota, cap, pref):
     """``_fill_with_pref`` over existing nodes, whose priority is always the
     index among the nodes with room: this class's freed holes first (warm
     repair, ``pref``), then what is left, both rounds ``_fill_in_order``."""
-    if pref is None:
-        return _fill_in_order(quota, cap)
-    refilled = _fill_in_order(quota, jnp.minimum(cap, pref))
-    return refilled + _fill_in_order(quota - jnp.sum(refilled), cap - refilled)
+    with jax.named_scope("kc.fill"):
+        if pref is None:
+            return _fill_in_order(quota, cap)
+        refilled = _fill_in_order(quota, jnp.minimum(cap, pref))
+        return refilled + _fill_in_order(quota - jnp.sum(refilled), cap - refilled)
 
 
 def _fill_with_pref(quota, cap, priority, pref):
@@ -515,17 +516,18 @@ def _fill_with_pref(quota, cap, priority, pref):
     like the departures) the holes absorb the whole quota and the repair's
     final placements are IDENTICAL to a from-scratch solve; without holes
     (``pref`` None or zero) this is exactly ``_fill_by_priority``."""
-    if pref is None:
-        return _fill_by_priority(quota, cap, priority)
-    i32max = jnp.iinfo(jnp.int32).max
-    idx = jnp.arange(cap.shape[0], dtype=jnp.int32)
-    hole_cap = jnp.minimum(cap, pref)
-    a0 = _fill_by_priority(quota, hole_cap, jnp.where(hole_cap > 0, idx, i32max))
-    cap_rest = cap - a0
-    a1 = _fill_by_priority(
-        quota - jnp.sum(a0), cap_rest, jnp.where(cap_rest > 0, priority, i32max)
-    )
-    return a0 + a1
+    with jax.named_scope("kc.fill"):
+        if pref is None:
+            return _fill_by_priority(quota, cap, priority)
+        i32max = jnp.iinfo(jnp.int32).max
+        idx = jnp.arange(cap.shape[0], dtype=jnp.int32)
+        hole_cap = jnp.minimum(cap, pref)
+        a0 = _fill_by_priority(quota, hole_cap, jnp.where(hole_cap > 0, idx, i32max))
+        cap_rest = cap - a0
+        a1 = _fill_by_priority(
+            quota - jnp.sum(a0), cap_rest, jnp.where(cap_rest > 0, priority, i32max)
+        )
+        return a0 + a1
 
 
 class Statics(NamedTuple):
@@ -1032,7 +1034,15 @@ def _class_step(
     The zone-committal phases (zone spread, required zonal anti) run as one
     batched multi-zone block (``committal_block``) that shares a single dense
     prep and resolves shared-node conflicts by zone order with cumulative
-    caps."""
+    caps.
+
+    The step's blocks open ``jax.named_scope`` names (``kc.step.*``,
+    ``kc.phase.<family>``, ``kc.existing`` / ``kc.new`` / ``kc.committal``,
+    ``kc.fill``): one per BLOCK of the program, never per class, group, zone
+    or lane, so a program carries the same names whatever its shapes.  They
+    add no operation — a profiler capture's op events carry them, and the
+    benchmark's ``kernel_*_s`` metrics read device time by them
+    (docs/OBSERVABILITY.md "Scopes in a profiler capture")."""
     ft = features
     state, ex, topo, remaining = carry
     cls, cls_index = cls_with_index
@@ -1063,150 +1073,152 @@ def _class_step(
     has_haf = g_haf < g_dummy
     has_zan = g_zan < g_dummy
 
-    # -- derived per-zone counts (TopoCounts docstring): positive groups
-    # count pods on zone-COMMITTED (singleton-mask) nodes, the committed-zone
-    # rule of topology.go:231-276; anti groups count every zone a resident
-    # node could still be in (pessimistic).  Reading the CURRENT masks — not
-    # record-time snapshots — replays the host's retroactive narrowing.
-    any_zone_groups = ft.zone_spread or ft.zone_affinity or ft.zone_anti
-    if any_zone_groups or ft.inv_zone_anti:
-        ex_zone_i = ex.zone.astype(jnp.int32) * ex.open_.astype(jnp.int32)[:, None]
-        new_zone_i = state.zone.astype(jnp.int32) * state.open_.astype(jnp.int32)[:, None]
-    # forward counts per zone of the zone groups this class OWNS — the only
-    # rows of a [G1, Z] table a step ever read
-    zone_fwd = {}
-    own_zone = [
-        (kind, g) for kind, g, on in (
-            ("zs", g_zs, ft.zone_spread),
-            ("zaf", g_zaf, ft.zone_affinity),
-            ("zan", g_zan, ft.zone_anti),
-        ) if on
-    ]
-    if own_zone:
-        # K <= 3 rows, each a dynamic slice (a vector-index gather of three
-        # rows costs XLA:TPU several times as much, PERF.md §6 PR 32)
-        own_rows = [g for _, g in own_zone]
-        own_fwd_ex = jnp.stack([topo.fwd_ex[g] for g in own_rows])  # [K, E]
-        own_fwd_new = jnp.stack([topo.fwd_new[g] for g in own_rows])  # [K, N]
-        ex_sing_zone = jnp.where(
-            jnp.sum(ex_zone_i, axis=-1, keepdims=True) == 1, ex_zone_i, 0
-        )
-        new_sing_zone = jnp.where(
-            jnp.sum(new_zone_i, axis=-1, keepdims=True) == 1, new_zone_i, 0
-        )
-        own_zone_fwd = jnp.einsum("ke,ez->kz", own_fwd_ex, ex_sing_zone) + jnp.einsum(
-            "kn,nz->kz", own_fwd_new, new_sing_zone
-        )  # [K, Z]
-        if topo_base is not None:
-            own_zone_fwd = own_zone_fwd + jnp.stack([topo_base[0][g] for g in own_rows])
-        if ft.zone_anti:
-            own_zone_full = jnp.einsum("ke,ez->kz", own_fwd_ex, ex_zone_i) + jnp.einsum(
-                "kn,nz->kz", own_fwd_new, new_zone_i
+    with jax.named_scope("kc.step.derive"):
+        # -- derived per-zone counts (TopoCounts docstring): positive groups
+        # count pods on zone-COMMITTED (singleton-mask) nodes, the committed-zone
+        # rule of topology.go:231-276; anti groups count every zone a resident
+        # node could still be in (pessimistic).  Reading the CURRENT masks — not
+        # record-time snapshots — replays the host's retroactive narrowing.
+        any_zone_groups = ft.zone_spread or ft.zone_affinity or ft.zone_anti
+        if any_zone_groups or ft.inv_zone_anti:
+            ex_zone_i = ex.zone.astype(jnp.int32) * ex.open_.astype(jnp.int32)[:, None]
+            new_zone_i = state.zone.astype(jnp.int32) * state.open_.astype(jnp.int32)[:, None]
+        # forward counts per zone of the zone groups this class OWNS — the only
+        # rows of a [G1, Z] table a step ever read
+        zone_fwd = {}
+        own_zone = [
+            (kind, g) for kind, g, on in (
+                ("zs", g_zs, ft.zone_spread),
+                ("zaf", g_zaf, ft.zone_affinity),
+                ("zan", g_zan, ft.zone_anti),
+            ) if on
+        ]
+        if own_zone:
+            # K <= 3 rows, each a dynamic slice (a vector-index gather of three
+            # rows costs XLA:TPU several times as much, PERF.md §6 PR 32)
+            own_rows = [g for _, g in own_zone]
+            own_fwd_ex = jnp.stack([topo.fwd_ex[g] for g in own_rows])  # [K, E]
+            own_fwd_new = jnp.stack([topo.fwd_new[g] for g in own_rows])  # [K, N]
+            ex_sing_zone = jnp.where(
+                jnp.sum(ex_zone_i, axis=-1, keepdims=True) == 1, ex_zone_i, 0
             )
+            new_sing_zone = jnp.where(
+                jnp.sum(new_zone_i, axis=-1, keepdims=True) == 1, new_zone_i, 0
+            )
+            own_zone_fwd = jnp.einsum("ke,ez->kz", own_fwd_ex, ex_sing_zone) + jnp.einsum(
+                "kn,nz->kz", own_fwd_new, new_sing_zone
+            )  # [K, Z]
             if topo_base is not None:
-                own_zone_full = own_zone_full + jnp.stack(
-                    [topo_base[1][g] for g in own_rows]
+                own_zone_fwd = own_zone_fwd + jnp.stack([topo_base[0][g] for g in own_rows])
+            if ft.zone_anti:
+                own_zone_full = jnp.einsum("ke,ez->kz", own_fwd_ex, ex_zone_i) + jnp.einsum(
+                    "kn,nz->kz", own_fwd_new, new_zone_i
                 )
-            own_is_anti = jnp.stack([statics.grp_is_anti[g] for g in own_rows])
-            own_zone_fwd = jnp.where(own_is_anti[:, None], own_zone_full, own_zone_fwd)
-        zone_fwd = {kind: own_zone_fwd[k] for k, (kind, _) in enumerate(own_zone)}
-
-    # -- inverse anti-affinity blocks (topology.go:44-47): members of anti
-    # groups avoid every domain the group's owners could occupy
-    blocked_z = ok_ex = ok_new = None
-    if by_row and (ft.inv_zone_anti or ft.inv_host_anti):
-        # one member group a turn: its two inverse rows, nothing wider
-        def member_blocks(k, blocks):
-            blocked_z, bad_ex, bad_new = blocks
-            g = mem_idx[k]
-            inv_ex_g, inv_new_g = topo.inv_ex[g], topo.inv_new[g]
-            anti_zone = statics.grp_is_anti[g] & statics.grp_is_zone[g]
-            anti_host = statics.grp_is_anti[g] & ~statics.grp_is_zone[g]
-            if ft.inv_zone_anti:
-                zone_inv_g = inv_ex_g @ ex_zone_i + inv_new_g @ new_zone_i  # [Z]
                 if topo_base is not None:
-                    zone_inv_g = zone_inv_g + topo_base[2][g]
-                blocked_z = blocked_z | (anti_zone & (zone_inv_g > 0))
+                    own_zone_full = own_zone_full + jnp.stack(
+                        [topo_base[1][g] for g in own_rows]
+                    )
+                own_is_anti = jnp.stack([statics.grp_is_anti[g] for g in own_rows])
+                own_zone_fwd = jnp.where(own_is_anti[:, None], own_zone_full, own_zone_fwd)
+            zone_fwd = {kind: own_zone_fwd[k] for k, (kind, _) in enumerate(own_zone)}
+
+        # -- inverse anti-affinity blocks (topology.go:44-47): members of anti
+        # groups avoid every domain the group's owners could occupy
+        blocked_z = ok_ex = ok_new = None
+        if by_row and (ft.inv_zone_anti or ft.inv_host_anti):
+            # one member group a turn: its two inverse rows, nothing wider
+            def member_blocks(k, blocks):
+                blocked_z, bad_ex, bad_new = blocks
+                g = mem_idx[k]
+                inv_ex_g, inv_new_g = topo.inv_ex[g], topo.inv_new[g]
+                anti_zone = statics.grp_is_anti[g] & statics.grp_is_zone[g]
+                anti_host = statics.grp_is_anti[g] & ~statics.grp_is_zone[g]
+                if ft.inv_zone_anti:
+                    zone_inv_g = inv_ex_g @ ex_zone_i + inv_new_g @ new_zone_i  # [Z]
+                    if topo_base is not None:
+                        zone_inv_g = zone_inv_g + topo_base[2][g]
+                    blocked_z = blocked_z | (anti_zone & (zone_inv_g > 0))
+                if ft.inv_host_anti:
+                    bad_ex = bad_ex | (anti_host & (inv_ex_g > 0))
+                    bad_new = bad_new | (anti_host & (inv_new_g > 0))
+                return blocked_z, bad_ex, bad_new
+
+            blocked_z, bad_ex, bad_new = jax.lax.fori_loop(0, n_members, member_blocks, (
+                jnp.zeros(n_zones, dtype=bool),
+                jnp.zeros(n_ex, dtype=bool),
+                jnp.zeros(n_new_slots, dtype=bool),
+            ))
             if ft.inv_host_anti:
-                bad_ex = bad_ex | (anti_host & (inv_ex_g > 0))
-                bad_new = bad_new | (anti_host & (inv_new_g > 0))
-            return blocked_z, bad_ex, bad_new
+                ok_ex, ok_new = ~bad_ex, ~bad_new
+        else:
+            if ft.inv_zone_anti:
+                zone_inv_full = jnp.einsum("ge,ez->gz", topo.inv_ex, ex_zone_i) + jnp.einsum(
+                    "gn,nz->gz", topo.inv_new, new_zone_i
+                )
+                if topo_base is not None:
+                    zone_inv_full = zone_inv_full + topo_base[2]
+                mem_anti_zone = member_row & statics.grp_is_anti & statics.grp_is_zone
+                blocked_z = jnp.any(mem_anti_zone[:, None] & (zone_inv_full > 0), axis=0)  # [Z]
+            if ft.inv_host_anti:
+                mem_anti_host = member_row & statics.grp_is_anti & ~statics.grp_is_zone
+                ok_ex = ~jnp.any(mem_anti_host[:, None] & (topo.inv_ex > 0), axis=0)  # [E]
+                ok_new = ~jnp.any(mem_anti_host[:, None] & (topo.inv_new > 0), axis=0)  # [N]
+        allowed_zone = cls.zone & ~blocked_z if ft.inv_zone_anti else cls.zone
 
-        blocked_z, bad_ex, bad_new = jax.lax.fori_loop(0, n_members, member_blocks, (
-            jnp.zeros(n_zones, dtype=bool),
-            jnp.zeros(n_ex, dtype=bool),
-            jnp.zeros(n_new_slots, dtype=bool),
-        ))
-        if ft.inv_host_anti:
-            ok_ex, ok_new = ~bad_ex, ~bad_new
-    else:
-        if ft.inv_zone_anti:
-            zone_inv_full = jnp.einsum("ge,ez->gz", topo.inv_ex, ex_zone_i) + jnp.einsum(
-                "gn,nz->gz", topo.inv_new, new_zone_i
-            )
-            if topo_base is not None:
-                zone_inv_full = zone_inv_full + topo_base[2]
-            mem_anti_zone = member_row & statics.grp_is_anti & statics.grp_is_zone
-            blocked_z = jnp.any(mem_anti_zone[:, None] & (zone_inv_full > 0), axis=0)  # [Z]
-        if ft.inv_host_anti:
-            mem_anti_host = member_row & statics.grp_is_anti & ~statics.grp_is_zone
-            ok_ex = ~jnp.any(mem_anti_host[:, None] & (topo.inv_ex > 0), axis=0)  # [E]
-            ok_new = ~jnp.any(mem_anti_host[:, None] & (topo.inv_new > 0), axis=0)  # [N]
-    allowed_zone = cls.zone & ~blocked_z if ft.inv_zone_anti else cls.zone
-
-    # -- per-node caps from hostname groups -----------------------------------
-    # spread (topologygroup.go:184-188: hostname min-count is 0, so cap=skew):
-    # members consume cap; non-members only need count <= skew
-    cap_parts_ex = []
-    cap_parts_new = []
-    fresh_parts = []
-    if ft.host_spread:
-        skew_hs = statics.grp_skew[g_hs]
-        member_hs = member_row[g_hs]
-        hs_fwd_ex = topo.fwd_ex[g_hs]
-        hs_fwd_new = topo.fwd_new[g_hs]
-        cap_parts_ex.append(jnp.where(
-            member_hs,
-            jnp.maximum(skew_hs - hs_fwd_ex, 0),
-            jnp.where(hs_fwd_ex <= skew_hs, UNLIMITED, 0),
-        ))
-        cap_parts_new.append(jnp.where(
-            member_hs,
-            jnp.maximum(skew_hs - hs_fwd_new, 0),
-            jnp.where(hs_fwd_new <= skew_hs, UNLIMITED, 0),
-        ))
-        fresh_parts.append(jnp.where(member_hs, skew_hs, UNLIMITED))
-    if ft.host_anti:
-        # owned hostname anti-affinity: only zero-count nodes; self-members cap 1
-        han_fwd_ex = topo.fwd_ex[g_han]
-        han_fwd_new = topo.fwd_new[g_han]
-        member_han = member_row[g_han]
-        cap_parts_ex.append(jnp.where(
-            g_han < g_dummy,
-            jnp.where(han_fwd_ex == 0, jnp.where(member_han, 1, UNLIMITED), 0),
-            UNLIMITED,
-        ))
-        cap_parts_new.append(jnp.where(
-            g_han < g_dummy,
-            jnp.where(han_fwd_new == 0, jnp.where(member_han, 1, UNLIMITED), 0),
-            UNLIMITED,
-        ))
-        fresh_parts.append(jnp.where((g_han < g_dummy) & member_han, 1, UNLIMITED))
-    if cap_parts_ex:
-        host_cap_ex = functools.reduce(jnp.minimum, cap_parts_ex).astype(jnp.int32)
-        host_cap_new = functools.reduce(jnp.minimum, cap_parts_new).astype(jnp.int32)
-        fresh_host_cap = functools.reduce(jnp.minimum, fresh_parts).astype(jnp.int32)
-    else:
-        host_cap_ex = jnp.full((n_ex,), UNLIMITED, dtype=jnp.int32)
-        host_cap_new = jnp.full((n_new_slots,), UNLIMITED, dtype=jnp.int32)
-        fresh_host_cap = jnp.int32(UNLIMITED)
+        # -- per-node caps from hostname groups -----------------------------------
+        # spread (topologygroup.go:184-188: hostname min-count is 0, so cap=skew):
+        # members consume cap; non-members only need count <= skew
+        cap_parts_ex = []
+        cap_parts_new = []
+        fresh_parts = []
+        if ft.host_spread:
+            skew_hs = statics.grp_skew[g_hs]
+            member_hs = member_row[g_hs]
+            hs_fwd_ex = topo.fwd_ex[g_hs]
+            hs_fwd_new = topo.fwd_new[g_hs]
+            cap_parts_ex.append(jnp.where(
+                member_hs,
+                jnp.maximum(skew_hs - hs_fwd_ex, 0),
+                jnp.where(hs_fwd_ex <= skew_hs, UNLIMITED, 0),
+            ))
+            cap_parts_new.append(jnp.where(
+                member_hs,
+                jnp.maximum(skew_hs - hs_fwd_new, 0),
+                jnp.where(hs_fwd_new <= skew_hs, UNLIMITED, 0),
+            ))
+            fresh_parts.append(jnp.where(member_hs, skew_hs, UNLIMITED))
+        if ft.host_anti:
+            # owned hostname anti-affinity: only zero-count nodes; self-members cap 1
+            han_fwd_ex = topo.fwd_ex[g_han]
+            han_fwd_new = topo.fwd_new[g_han]
+            member_han = member_row[g_han]
+            cap_parts_ex.append(jnp.where(
+                g_han < g_dummy,
+                jnp.where(han_fwd_ex == 0, jnp.where(member_han, 1, UNLIMITED), 0),
+                UNLIMITED,
+            ))
+            cap_parts_new.append(jnp.where(
+                g_han < g_dummy,
+                jnp.where(han_fwd_new == 0, jnp.where(member_han, 1, UNLIMITED), 0),
+                UNLIMITED,
+            ))
+            fresh_parts.append(jnp.where((g_han < g_dummy) & member_han, 1, UNLIMITED))
+        if cap_parts_ex:
+            host_cap_ex = functools.reduce(jnp.minimum, cap_parts_ex).astype(jnp.int32)
+            host_cap_new = functools.reduce(jnp.minimum, cap_parts_new).astype(jnp.int32)
+            fresh_host_cap = functools.reduce(jnp.minimum, fresh_parts).astype(jnp.int32)
+        else:
+            host_cap_ex = jnp.full((n_ex,), UNLIMITED, dtype=jnp.int32)
+            host_cap_new = jnp.full((n_new_slots,), UNLIMITED, dtype=jnp.int32)
+            fresh_host_cap = jnp.int32(UNLIMITED)
 
     # step-wide existing-node intake/merge tensors (valid across this step's
     # phases — they touch disjoint node sets; see ExClassPrep)
-    ex_prep = _prep_existing(
-        ex, ex_static, cls, statics, host_cap_ex, tol_row,
-        vol_add_row, vol_per_pod_row, ft,
-    )
+    with jax.named_scope("kc.step.prep_existing"):
+        ex_prep = _prep_existing(
+            ex, ex_static, cls, statics, host_cap_ex, tol_row,
+            vol_add_row, vol_per_pod_row, ft,
+        )
 
     assigned_total = jnp.zeros_like(state.pod_count)
     assigned_ex_total = jnp.zeros_like(ex.pod_count)
@@ -1222,19 +1234,21 @@ def _class_step(
             state_i, ex_i, rem_i = operand
             extra_ex = _and_opt(ok_ex, targets_ex)
             extra_new = _and_opt(ok_new, targets_new)
-            ex_o, a_ex, placed_ex = _phase_existing(
-                ex_i, ex_prep, cls, quota, restrict,
-                extra_elig=extra_ex, single_node=single_node, ft=ft,
-                pref=pref_ex,
-            )
+            with jax.named_scope("kc.existing"):
+                ex_o, a_ex, placed_ex = _phase_existing(
+                    ex_i, ex_prep, cls, quota, restrict,
+                    extra_elig=extra_ex, single_node=single_node, ft=ft,
+                    pref=pref_ex,
+                )
             q_new = quota - placed_ex
             if single_node:
                 q_new = jnp.where(placed_ex > 0, 0, q_new)
-            state_o, a_new, placed_new, rem_o = _phase(
-                state_i, cls, statics, q_new, restrict,
-                host_cap_new, fresh_host_cap, rem_i, extra_elig=extra_new,
-                max_new_nodes=max_new_nodes, ft=ft, pref=pref_new,
-            )
+            with jax.named_scope("kc.new"):
+                state_o, a_new, placed_new, rem_o = _phase(
+                    state_i, cls, statics, q_new, restrict,
+                    host_cap_new, fresh_host_cap, rem_i, extra_elig=extra_new,
+                    max_new_nodes=max_new_nodes, ft=ft, pref=pref_new,
+                )
             return state_o, ex_o, a_new, a_ex, placed_ex + placed_new, rem_o
 
         def skip(operand):
@@ -1269,7 +1283,7 @@ def _class_step(
         Parity with the sequential path is fuzzed in
         tests/test_kernel_fusion_parity.py."""
 
-        def do(operand):
+        def block(operand):
             state_i, ex_i, rem_i = operand
             i32max = jnp.iinfo(jnp.int32).max
             # ---- dense prep shared by every zone --------------------------
@@ -1519,6 +1533,10 @@ def _class_step(
             )
             return state_o, ex_o, a_open + fresh_a, a_ex, placed, rem_o
 
+        def do(operand):
+            with jax.named_scope("kc.committal"):
+                return block(operand)
+
         def skip(operand):
             state_i, ex_i, rem_i = operand
             return (
@@ -1545,116 +1563,119 @@ def _class_step(
     # existing node with intake left sits in) — used by spread quotas and the
     # affinity bootstrap below
     if ft.zone_spread or ft.zone_affinity:
-        # the einsum's i-contraction is partial per catalog shard; psum of the
-        # integer-valued f32 partials is exact, so the >0.5 test is unmoved
-        tmpl_offers = _isum(jnp.einsum(
-            "ti,izc,tz,tc->z",
-            statics.tmpl_it.astype(jnp.bfloat16),
-            (statics.it_avail & cls.it[:, None, None]).astype(jnp.bfloat16),
-            statics.tmpl_zone.astype(jnp.bfloat16),
-            (statics.tmpl_ct & cls.ct[None, :]).astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        ), statics) > 0.5  # [Z]
-        ex_cap_spread = ex_prep.cap if ok_ex is None else jnp.where(ok_ex, ex_prep.cap, 0)
-        # per-zone intake for this class: existing nodes contribute their
-        # remaining intake; template zones open new nodes on demand (unbounded).
-        # A multi-zone (unknown-zone) node's intake deliberately counts into
-        # EVERY zone of its mask: the estimate must be optimistic, because an
-        # over-grant surfaces as a phase shortfall (the spread_suspect sentinel
-        # below routes it to the host oracle), whereas pinning the intake to
-        # one zone would under-estimate the others and under-place with no
-        # detectable signal — the host can commit such a node to whichever
-        # zone the fill needs.
-        # (the second bound keeps the running sum over nodes inside int32)
-        ex_cap_ez = (
-            jnp.minimum(jnp.minimum(ex_cap_spread, m), (2**31 - 1) // n_ex)[:, None]
-            * ex_prep.zone_full.astype(jnp.int32)
-        )  # i32[E, Z]
-        ex_cap_z = jnp.sum(ex_cap_ez, axis=0)  # i32[Z]
-        fillable = tmpl_offers | (ex_cap_z > 0)
+        with jax.named_scope("kc.step.zone_intake"):
+            # the einsum's i-contraction is partial per catalog shard; psum of the
+            # integer-valued f32 partials is exact, so the >0.5 test is unmoved
+            tmpl_offers = _isum(jnp.einsum(
+                "ti,izc,tz,tc->z",
+                statics.tmpl_it.astype(jnp.bfloat16),
+                (statics.it_avail & cls.it[:, None, None]).astype(jnp.bfloat16),
+                statics.tmpl_zone.astype(jnp.bfloat16),
+                (statics.tmpl_ct & cls.ct[None, :]).astype(jnp.bfloat16),
+                preferred_element_type=jnp.float32,
+            ), statics) > 0.5  # [Z]
+            ex_cap_spread = ex_prep.cap if ok_ex is None else jnp.where(ok_ex, ex_prep.cap, 0)
+            # per-zone intake for this class: existing nodes contribute their
+            # remaining intake; template zones open new nodes on demand (unbounded).
+            # A multi-zone (unknown-zone) node's intake deliberately counts into
+            # EVERY zone of its mask: the estimate must be optimistic, because an
+            # over-grant surfaces as a phase shortfall (the spread_suspect sentinel
+            # below routes it to the host oracle), whereas pinning the intake to
+            # one zone would under-estimate the others and under-place with no
+            # detectable signal — the host can commit such a node to whichever
+            # zone the fill needs.
+            # (the second bound keeps the running sum over nodes inside int32)
+            ex_cap_ez = (
+                jnp.minimum(jnp.minimum(ex_cap_spread, m), (2**31 - 1) // n_ex)[:, None]
+                * ex_prep.zone_full.astype(jnp.int32)
+            )  # i32[E, Z]
+            ex_cap_z = jnp.sum(ex_cap_ez, axis=0)  # i32[Z]
+            fillable = tmpl_offers | (ex_cap_z > 0)
 
     # -- zone spread phases (one committed zone per phase) --------------------
     spread_suspect = jnp.array(False)
     if ft.zone_spread:
-        counts_zs = zone_fwd["zs"]  # [Z]
-        member_zs = member_row[g_zs]
-        cap_pods_z = jnp.where(tmpl_offers, UNLIMITED, jnp.minimum(ex_cap_z, UNLIMITED))
+        with jax.named_scope("kc.phase.zone_spread"):
+            counts_zs = zone_fwd["zs"]  # [Z]
+            member_zs = member_row[g_zs]
+            cap_pods_z = jnp.where(tmpl_offers, UNLIMITED, jnp.minimum(ex_cap_z, UNLIMITED))
 
-        # the reference's per-pod skew check measures against the min over ALL
-        # the pod's domains, including zones that cannot take this class —
-        # their counts stay frozen, capping every fillable zone at
-        # frozen_min + maxSkew (topology_test.go:124-162 "existing pod" case).
-        # A zone whose intake runs out MID-fill freezes the same way
-        # (nextDomainTopologySpread keeps measuring it,
-        # topologygroup.go:155-182), so the water-fill proceeds in rounds:
-        # each round fills min-first up to the nearest saturation level, then
-        # the saturated zone joins the frozen set and bounds the rest.
-        unreachable = allowed_zone & ~fillable
-        skew_zs = statics.grp_skew[g_zs]
-        BIGI = jnp.int32(1 << 30)
-        finite_cap = cap_pods_z < UNLIMITED
-        quotas = jnp.zeros(n_zones, dtype=jnp.int32)
-        sat = jnp.zeros(n_zones, dtype=bool)
-        m_rem = m
-        # which tied zone the reference reaches first (_water_fill), in the
-        # first round: a later one runs only once a zone that no template
-        # offers ran out of existing intake, and keeps the order of the counts
-        ex_cum_z = jnp.cumsum(ex_cap_ez, axis=0)
-        # worst case: one round per sequentially-saturating finite-cap zone,
-        # plus a final redistribution round for the unbounded zones
-        for fill_round in range(n_zones + 1):
-            counts_now = counts_zs + quotas
-            min_frozen = jnp.min(jnp.where(unreachable | sat, counts_now, BIGI))
-            skew_cap = jnp.clip(min_frozen + skew_zs - counts_now, 0, UNLIMITED)
-            active = allowed_zone & fillable & ~sat
-            cap_rem = jnp.clip(cap_pods_z - quotas, 0, UNLIMITED)
-            # level where the nearest capacity-bounded active zone saturates;
-            # fills stop there so its frozen count bounds the next round
-            lvl_sat = jnp.min(jnp.where(active & finite_cap, counts_now + cap_rem, BIGI))
-            q = _water_fill(
-                counts_now, active, m_rem, ex_cum_z if fill_round == 0 else None
+            # the reference's per-pod skew check measures against the min over ALL
+            # the pod's domains, including zones that cannot take this class —
+            # their counts stay frozen, capping every fillable zone at
+            # frozen_min + maxSkew (topology_test.go:124-162 "existing pod" case).
+            # A zone whose intake runs out MID-fill freezes the same way
+            # (nextDomainTopologySpread keeps measuring it,
+            # topologygroup.go:155-182), so the water-fill proceeds in rounds:
+            # each round fills min-first up to the nearest saturation level, then
+            # the saturated zone joins the frozen set and bounds the rest.
+            unreachable = allowed_zone & ~fillable
+            skew_zs = statics.grp_skew[g_zs]
+            BIGI = jnp.int32(1 << 30)
+            finite_cap = cap_pods_z < UNLIMITED
+            quotas = jnp.zeros(n_zones, dtype=jnp.int32)
+            sat = jnp.zeros(n_zones, dtype=bool)
+            m_rem = m
+            # which tied zone the reference reaches first (_water_fill), in the
+            # first round: a later one runs only once a zone that no template
+            # offers ran out of existing intake, and keeps the order of the counts
+            ex_cum_z = jnp.cumsum(ex_cap_ez, axis=0)
+            # worst case: one round per sequentially-saturating finite-cap zone,
+            # plus a final redistribution round for the unbounded zones
+            for fill_round in range(n_zones + 1):
+                counts_now = counts_zs + quotas
+                min_frozen = jnp.min(jnp.where(unreachable | sat, counts_now, BIGI))
+                skew_cap = jnp.clip(min_frozen + skew_zs - counts_now, 0, UNLIMITED)
+                active = allowed_zone & fillable & ~sat
+                cap_rem = jnp.clip(cap_pods_z - quotas, 0, UNLIMITED)
+                # level where the nearest capacity-bounded active zone saturates;
+                # fills stop there so its frozen count bounds the next round
+                lvl_sat = jnp.min(jnp.where(active & finite_cap, counts_now + cap_rem, BIGI))
+                with jax.named_scope("kc.fill"):
+                    q = _water_fill(
+                        counts_now, active, m_rem, ex_cum_z if fill_round == 0 else None
+                    )
+                q = jnp.minimum(q, jnp.clip(lvl_sat - counts_now, 0, UNLIMITED))
+                q = jnp.minimum(q, jnp.minimum(skew_cap, cap_rem))
+                q = jnp.where(active, q, 0)
+                quotas = quotas + q
+                m_rem = m_rem - jnp.sum(q)
+                sat = sat | (active & finite_cap & (quotas >= cap_pods_z))
+            quotas = jnp.where(member_zs, quotas, 0)
+            # under-placement sentinel (host-oracle parity,
+            # topologygroup.go:155-182): the round bound can exhaust with quota
+            # still unallocated while some active zone retains both skew and
+            # capacity headroom — the shape ROADMAP gap 5 documented as silent.
+            # Flag it; the shell re-routes the class's leftover pods through the
+            # host path instead of quietly failing them.
+            counts_end = counts_zs + quotas
+            min_frozen_end = jnp.min(jnp.where(unreachable | sat, counts_end, BIGI))
+            skew_headroom = (counts_end - min_frozen_end) < skew_zs
+            cap_headroom = (cap_pods_z - quotas) > 0
+            fill_residual = (m_rem > 0) & jnp.any(
+                allowed_zone & fillable & ~sat & skew_headroom & cap_headroom
             )
-            q = jnp.minimum(q, jnp.clip(lvl_sat - counts_now, 0, UNLIMITED))
-            q = jnp.minimum(q, jnp.minimum(skew_cap, cap_rem))
-            q = jnp.where(active, q, 0)
-            quotas = quotas + q
-            m_rem = m_rem - jnp.sum(q)
-            sat = sat | (active & finite_cap & (quotas >= cap_pods_z))
-        quotas = jnp.where(member_zs, quotas, 0)
-        # under-placement sentinel (host-oracle parity,
-        # topologygroup.go:155-182): the round bound can exhaust with quota
-        # still unallocated while some active zone retains both skew and
-        # capacity headroom — the shape ROADMAP gap 5 documented as silent.
-        # Flag it; the shell re-routes the class's leftover pods through the
-        # host path instead of quietly failing them.
-        counts_end = counts_zs + quotas
-        min_frozen_end = jnp.min(jnp.where(unreachable | sat, counts_end, BIGI))
-        skew_headroom = (counts_end - min_frozen_end) < skew_zs
-        cap_headroom = (cap_pods_z - quotas) > 0
-        fill_residual = (m_rem > 0) & jnp.any(
-            allowed_zone & fillable & ~sat & skew_headroom & cap_headroom
-        )
-        quotas_gated = jnp.where(has_zs, quotas, 0)
-        results_zs = committal_block(
-            state, ex, remaining, quotas_gated, jnp.int32(UNLIMITED)
-        )
-        placed_zs = results_zs[4]
-        accumulate(results_zs)
-        # quota granted but not realized in-phase: the water-fill's per-zone
-        # intake estimate (ex_cap_z) is optimistic — e.g. a multi-zone node's
-        # capacity counts into every zone of its mask — so a phase can place
-        # fewer pods than its quota with no later round to redistribute them
-        quota_shortfall = placed_zs < jnp.sum(quotas)
-        spread_suspect = has_zs & member_zs & (fill_residual | quota_shortfall)
+            quotas_gated = jnp.where(has_zs, quotas, 0)
+            results_zs = committal_block(
+                state, ex, remaining, quotas_gated, jnp.int32(UNLIMITED)
+            )
+            placed_zs = results_zs[4]
+            accumulate(results_zs)
+            # quota granted but not realized in-phase: the water-fill's per-zone
+            # intake estimate (ex_cap_z) is optimistic — e.g. a multi-zone node's
+            # capacity counts into every zone of its mask — so a phase can place
+            # fewer pods than its quota with no later round to redistribute them
+            quota_shortfall = placed_zs < jnp.sum(quotas)
+            spread_suspect = has_zs & member_zs & (fill_residual | quota_shortfall)
 
-        # non-self-selecting zone spread: the pod never increments its own
-        # group's counts, so the skew formula (count + 0 - min <= maxSkew,
-        # topologygroup.go:155-182) yields a STATIC admissible-zone mask — one
-        # plain phase over it, no per-zone quotas or committal needed
-        min_zs = jnp.min(jnp.where(cls.zone, counts_zs, jnp.int32(1 << 30)))
-        admissible_zs = allowed_zone & (counts_zs - min_zs <= statics.grp_skew[g_zs])
-        q_nm = jnp.where(has_zs & ~member_zs & jnp.any(admissible_zs), m, 0)
-        accumulate(run_phase(state, ex, remaining, q_nm, admissible_zs))
+            # non-self-selecting zone spread: the pod never increments its own
+            # group's counts, so the skew formula (count + 0 - min <= maxSkew,
+            # topologygroup.go:155-182) yields a STATIC admissible-zone mask — one
+            # plain phase over it, no per-zone quotas or committal needed
+            min_zs = jnp.min(jnp.where(cls.zone, counts_zs, jnp.int32(1 << 30)))
+            admissible_zs = allowed_zone & (counts_zs - min_zs <= statics.grp_skew[g_zs])
+            q_nm = jnp.where(has_zs & ~member_zs & jnp.any(admissible_zs), m, 0)
+            accumulate(run_phase(state, ex, remaining, q_nm, admissible_zs))
 
     # -- owned zone anti-affinity: zero-forward-count zones only --------------
     # self-members place one pod per currently-unpoisoned zone, each phase
@@ -1672,26 +1693,27 @@ def _class_step(
     # required anti commits because the reference CONVERGES to one-per-zone
     # over batches (pods stay pending until zones register)
     if ft.zone_anti:
-        zero_zones = allowed_zone & (zone_fwd["zan"] == 0)
-        anti_member = member_row[g_zan]
-        anti_required = has_zan & anti_member & ~cls.anti_soft[0]
-        # the committal phases are only reachable for required-anti members;
-        # when the snapshot statically has none (features.required_zone_anti
-        # False, from encode_snapshot), they are never traced — formerly the
-        # single largest per-class phase block, all compile + per-step cost
-        if ft.required_zone_anti:
-            anti_quota_z = (anti_required & zero_zones).astype(jnp.int32)
-            accumulate(committal_block(state, ex, remaining, anti_quota_z, m))
-        anti_quota = jnp.where(
-            has_zan & jnp.any(zero_zones),
-            jnp.where(
-                anti_member,
-                jnp.where(cls.anti_soft[0], jnp.minimum(m, 1), 0),
-                m,
-            ),
-            0,
-        )
-        accumulate(run_phase(state, ex, remaining, anti_quota, zero_zones))
+        with jax.named_scope("kc.phase.zone_anti"):
+            zero_zones = allowed_zone & (zone_fwd["zan"] == 0)
+            anti_member = member_row[g_zan]
+            anti_required = has_zan & anti_member & ~cls.anti_soft[0]
+            # the committal phases are only reachable for required-anti members;
+            # when the snapshot statically has none (features.required_zone_anti
+            # False, from encode_snapshot), they are never traced — formerly the
+            # single largest per-class phase block, all compile + per-step cost
+            if ft.required_zone_anti:
+                anti_quota_z = (anti_required & zero_zones).astype(jnp.int32)
+                accumulate(committal_block(state, ex, remaining, anti_quota_z, m))
+            anti_quota = jnp.where(
+                has_zan & jnp.any(zero_zones),
+                jnp.where(
+                    anti_member,
+                    jnp.where(cls.anti_soft[0], jnp.minimum(m, 1), 0),
+                    m,
+                ),
+                0,
+            )
+            accumulate(run_phase(state, ex, remaining, anti_quota, zero_zones))
 
     # -- zone affinity: nonzero-count zones (the selected pods' locations),
     # else self-members bootstrap one allowed zone (topologygroup.go:202-233).
@@ -1699,58 +1721,61 @@ def _class_step(
     # lands where a node is viable): restrict to zones some template offers
     # for this class, or where an open existing node sits
     if ft.zone_affinity:
-        bootstrap_allowed = allowed_zone & fillable
-        nonzero_zones = allowed_zone & (zone_fwd["zaf"] > 0)
-        # the reference tries existing nodes in index order before any new
-        # node, and its bootstrap admits the zone of whichever node it is
-        # trying (topologygroup.go:210-231): the first existing node with
-        # intake for the class names the zone; with none, the first allowed
-        ex_zone_ok = ex_prep.zone_full & bootstrap_allowed[None, :]  # [E, Z]
-        ex_boot = (ex_cap_spread > 0) & jnp.any(ex_zone_ok, axis=-1)  # [E]
-        boot_from = jnp.where(
-            jnp.any(ex_boot), ex_zone_ok[jnp.argmax(ex_boot)], bootstrap_allowed
-        )
-        bootstrap_zone = (
-            jnp.zeros(n_zones, dtype=bool)
-            .at[jnp.argmax(boot_from)]
-            .set(jnp.any(bootstrap_allowed) & member_row[g_zaf])
-        )
-        zone_aff_restrict = jnp.where(
-            jnp.any(nonzero_zones), nonzero_zones, bootstrap_zone
-        )
-        zone_aff_quota = jnp.where(has_zaf & ~has_haf & jnp.any(zone_aff_restrict), m, 0)
-        accumulate(run_phase(state, ex, remaining, zone_aff_quota, zone_aff_restrict))
+        with jax.named_scope("kc.phase.zone_affinity"):
+            bootstrap_allowed = allowed_zone & fillable
+            nonzero_zones = allowed_zone & (zone_fwd["zaf"] > 0)
+            # the reference tries existing nodes in index order before any new
+            # node, and its bootstrap admits the zone of whichever node it is
+            # trying (topologygroup.go:210-231): the first existing node with
+            # intake for the class names the zone; with none, the first allowed
+            ex_zone_ok = ex_prep.zone_full & bootstrap_allowed[None, :]  # [E, Z]
+            ex_boot = (ex_cap_spread > 0) & jnp.any(ex_zone_ok, axis=-1)  # [E]
+            boot_from = jnp.where(
+                jnp.any(ex_boot), ex_zone_ok[jnp.argmax(ex_boot)], bootstrap_allowed
+            )
+            bootstrap_zone = (
+                jnp.zeros(n_zones, dtype=bool)
+                .at[jnp.argmax(boot_from)]
+                .set(jnp.any(bootstrap_allowed) & member_row[g_zaf])
+            )
+            zone_aff_restrict = jnp.where(
+                jnp.any(nonzero_zones), nonzero_zones, bootstrap_zone
+            )
+            zone_aff_quota = jnp.where(has_zaf & ~has_haf & jnp.any(zone_aff_restrict), m, 0)
+            accumulate(run_phase(state, ex, remaining, zone_aff_quota, zone_aff_restrict))
 
     # -- hostname affinity: fill target nodes (forward count > 0) on both
     # planes; else self-members bootstrap exactly one node
     all_zones = jnp.ones(n_zones, dtype=bool)
     if ft.host_affinity:
-        if ft.zone_affinity:
-            host_restrict = jnp.where(has_zaf, zone_aff_restrict, all_zones) & allowed_zone
-        else:
-            host_restrict = all_zones & allowed_zone
-        targets_ex = (topo.fwd_ex[g_haf] > 0) & ex.open_
-        targets_new = (topo.fwd_new[g_haf] > 0) & state.open_
-        targets_exist = jnp.any(targets_ex) | jnp.any(targets_new)
-        host_quota = jnp.where(has_haf, m, 0)
-        q_targets = jnp.where(targets_exist, host_quota, 0)
-        accumulate(
-            run_phase(
-                state, ex, remaining, q_targets, host_restrict,
-                targets_ex=targets_ex, targets_new=targets_new, max_new_nodes=0,
+        with jax.named_scope("kc.phase.host_affinity"):
+            if ft.zone_affinity:
+                host_restrict = jnp.where(has_zaf, zone_aff_restrict, all_zones) & allowed_zone
+            else:
+                host_restrict = all_zones & allowed_zone
+            targets_ex = (topo.fwd_ex[g_haf] > 0) & ex.open_
+            targets_new = (topo.fwd_new[g_haf] > 0) & state.open_
+            targets_exist = jnp.any(targets_ex) | jnp.any(targets_new)
+            host_quota = jnp.where(has_haf, m, 0)
+            q_targets = jnp.where(targets_exist, host_quota, 0)
+            accumulate(
+                run_phase(
+                    state, ex, remaining, q_targets, host_restrict,
+                    targets_ex=targets_ex, targets_new=targets_new, max_new_nodes=0,
+                )
             )
-        )
-        q_boot = jnp.where(targets_exist | ~member_row[g_haf], 0, host_quota)
-        accumulate(
-            run_phase(
-                state, ex, remaining, q_boot, host_restrict,
-                single_node=True, max_new_nodes=1,
+            q_boot = jnp.where(targets_exist | ~member_row[g_haf], 0, host_quota)
+            accumulate(
+                run_phase(
+                    state, ex, remaining, q_boot, host_restrict,
+                    single_node=True, max_new_nodes=1,
+                )
             )
-        )
 
     # -- unconstrained phase for plain classes --------------------------------
-    any_quota = jnp.where(has_zs | has_zan | has_zaf | has_haf, 0, m)
-    accumulate(run_phase(state, ex, remaining, any_quota, allowed_zone))
+    with jax.named_scope("kc.phase.plain"):
+        any_quota = jnp.where(has_zs | has_zan | has_zaf | has_haf, 0, m)
+        accumulate(run_phase(state, ex, remaining, any_quota, allowed_zone))
 
     # -- record (topology.go:120-143): update shared PER-NODE counts ----------
     # zone projections happen at read time from live masks (derivation above),
@@ -1758,39 +1783,40 @@ def _class_step(
     # membership/ownership to its node's row in every relevant group.
     # No class can own or match a group when no feature family exists, so the
     # whole record step prunes away with them.
-    if (ft.zone_spread or ft.host_spread or ft.zone_affinity or ft.host_affinity
-            or ft.zone_anti or ft.host_anti or ft.inv_zone_anti or ft.inv_host_anti):
-        a_ex_f = assigned_ex_total.astype(jnp.int32)
-        a_new_f = assigned_total.astype(jnp.int32)
-        # preferred-anti owners register no inverse counts (the reference skips
-        # inverse tracking for preferences, topology.go:203-206)
-        anti_rows = jnp.stack([g_zan, g_han])
-        anti_on = (anti_rows < g_dummy) & ~cls.anti_soft
-        if by_row:
-            # the member rows and the two owned anti rows, updated in place in
-            # the carry; the dummy row is never among them (TopoCounts)
-            fwd_ex = _add_to_rows(topo.fwd_ex, mem_idx, n_members, a_ex_f)
-            fwd_new = _add_to_rows(topo.fwd_new, mem_idx, n_members, a_new_f)
-            inv_ex, inv_new = topo.inv_ex, topo.inv_new
-            if ft.zone_anti or ft.host_anti:
-                anti_first = jnp.where(anti_on[0], anti_rows, anti_rows[::-1])
-                n_anti = jnp.sum(anti_on)
-                inv_ex = _add_to_rows(inv_ex, anti_first, n_anti, a_ex_f)
-                inv_new = _add_to_rows(inv_new, anti_first, n_anti, a_new_f)
-            topo = TopoCounts(fwd_ex=fwd_ex, inv_ex=inv_ex, fwd_new=fwd_new, inv_new=inv_new)
-        else:
-            member_i = member_row.astype(jnp.int32)
-            own_inv = jnp.sum(
-                (jnp.arange(g1)[None, :] == anti_rows[:, None]) & anti_on[:, None], axis=0
-            ).astype(jnp.int32)
-            topo = TopoCounts(
-                fwd_ex=topo.fwd_ex + member_i[:, None] * a_ex_f[None, :],
-                inv_ex=topo.inv_ex + own_inv[:, None] * a_ex_f[None, :],
-                fwd_new=topo.fwd_new + member_i[:, None] * a_new_f[None, :],
-                inv_new=topo.inv_new + own_inv[:, None] * a_new_f[None, :],
-            )
+    with jax.named_scope("kc.step.record"):
+        if (ft.zone_spread or ft.host_spread or ft.zone_affinity or ft.host_affinity
+                or ft.zone_anti or ft.host_anti or ft.inv_zone_anti or ft.inv_host_anti):
+            a_ex_f = assigned_ex_total.astype(jnp.int32)
+            a_new_f = assigned_total.astype(jnp.int32)
+            # preferred-anti owners register no inverse counts (the reference skips
+            # inverse tracking for preferences, topology.go:203-206)
+            anti_rows = jnp.stack([g_zan, g_han])
+            anti_on = (anti_rows < g_dummy) & ~cls.anti_soft
+            if by_row:
+                # the member rows and the two owned anti rows, updated in place in
+                # the carry; the dummy row is never among them (TopoCounts)
+                fwd_ex = _add_to_rows(topo.fwd_ex, mem_idx, n_members, a_ex_f)
+                fwd_new = _add_to_rows(topo.fwd_new, mem_idx, n_members, a_new_f)
+                inv_ex, inv_new = topo.inv_ex, topo.inv_new
+                if ft.zone_anti or ft.host_anti:
+                    anti_first = jnp.where(anti_on[0], anti_rows, anti_rows[::-1])
+                    n_anti = jnp.sum(anti_on)
+                    inv_ex = _add_to_rows(inv_ex, anti_first, n_anti, a_ex_f)
+                    inv_new = _add_to_rows(inv_new, anti_first, n_anti, a_new_f)
+                topo = TopoCounts(fwd_ex=fwd_ex, inv_ex=inv_ex, fwd_new=fwd_new, inv_new=inv_new)
+            else:
+                member_i = member_row.astype(jnp.int32)
+                own_inv = jnp.sum(
+                    (jnp.arange(g1)[None, :] == anti_rows[:, None]) & anti_on[:, None], axis=0
+                ).astype(jnp.int32)
+                topo = TopoCounts(
+                    fwd_ex=topo.fwd_ex + member_i[:, None] * a_ex_f[None, :],
+                    inv_ex=topo.inv_ex + own_inv[:, None] * a_ex_f[None, :],
+                    fwd_new=topo.fwd_new + member_i[:, None] * a_new_f[None, :],
+                    inv_new=topo.inv_new + own_inv[:, None] * a_new_f[None, :],
+                )
 
-    failed = m - placed_total
+        failed = m - placed_total
     return (
         (state, ex, topo, remaining),
         (assigned_total, assigned_ex_total, failed, spread_suspect),
@@ -1877,7 +1903,8 @@ def solve_core(
     ft = ft.canonical()
     sa = StaticArrays(*statics_arrays)
     width = sa.valid.shape[-1]  # semantic slot count V+1, pre-packing
-    sa, class_tensors = pack_masks(sa, class_tensors)
+    with jax.named_scope("kc.init"):
+        sa, class_tensors = pack_masks(sa, class_tensors)
     statics = Statics(
         *sa, key_has_bounds=key_has_bounds, mask_v=width,
         catalog_axis=catalog_axis, lane_axis=lane_axis,
@@ -1891,61 +1918,62 @@ def solve_core(
 
     g1 = statics.grp_skew.shape[0]
     n_ports = class_tensors.ports.shape[-1] if n_classes else 1
-    if warm_carry is not None:
-        # warm-start repair: resume from the previous solve's final carry.
-        # The carry's planes already went through this function once — masks
-        # are packed, topology counts and the limit budget are live — so all
-        # of the seeding below is skipped (it would double-count).
-        wc = WarmCarry(*warm_carry)
-        state = NodeState(*wc.state)
-        existing_state = ExistingState(*wc.ex_state)
-        n_slots = state.pod_count.shape[0]
-        if existing_static is None:
-            existing_static = empty_existing_static(n_res, n_classes, g1)
-        topo = TopoCounts(*wc.topo)
-        remaining0 = wc.remaining
-    else:
-        kmask0 = jnp.broadcast_to(
-            jnp.asarray(mask_ops.full_words(width)),
-            (n_slots, n_keys, mask_ops.words_for(width)),
-        )
-        state = NodeState(
-            used=jnp.zeros((n_slots, n_res), dtype=jnp.float32),
-            kmask=kmask0,
-            kdef=jnp.zeros((n_slots, n_keys), dtype=bool),
-            kneg=jnp.zeros((n_slots, n_keys), dtype=bool),
-            kgt=jnp.full((n_slots, n_keys), -jnp.inf, dtype=jnp.float32),
-            klt=jnp.full((n_slots, n_keys), jnp.inf, dtype=jnp.float32),
-            zone=jnp.ones((n_slots, n_zones), dtype=bool),
-            ct=jnp.ones((n_slots, n_ct), dtype=bool),
-            viable=jnp.ones((n_slots, n_it), dtype=bool),
-            ports=jnp.zeros((n_slots, n_ports), dtype=bool),
-            pod_count=jnp.zeros(n_slots, dtype=jnp.int32),
-            tmpl_id=jnp.zeros(n_slots, dtype=jnp.int32),
-            open_=jnp.zeros(n_slots, dtype=bool),
-            n_next=jnp.int32(0),
-        )
-        if existing_state is None:
-            existing_state = empty_existing_state(n_res, n_keys, width, n_zones, n_ct, n_ports)
-            existing_static = empty_existing_static(n_res, n_classes, g1)
-        if existing_state.kmask.dtype != jnp.uint32:
-            existing_state = existing_state._replace(
-                kmask=mask_ops.pack_mask(existing_state.kmask)
+    with jax.named_scope("kc.init"):
+        if warm_carry is not None:
+            # warm-start repair: resume from the previous solve's final carry.
+            # The carry's planes already went through this function once — masks
+            # are packed, topology counts and the limit budget are live — so all
+            # of the seeding below is skipped (it would double-count).
+            wc = WarmCarry(*warm_carry)
+            state = NodeState(*wc.state)
+            existing_state = ExistingState(*wc.ex_state)
+            n_slots = state.pod_count.shape[0]
+            if existing_static is None:
+                existing_static = empty_existing_static(n_res, n_classes, g1)
+            topo = TopoCounts(*wc.topo)
+            remaining0 = wc.remaining
+        else:
+            kmask0 = jnp.broadcast_to(
+                jnp.asarray(mask_ops.full_words(width)),
+                (n_slots, n_keys, mask_ops.words_for(width)),
             )
+            state = NodeState(
+                used=jnp.zeros((n_slots, n_res), dtype=jnp.float32),
+                kmask=kmask0,
+                kdef=jnp.zeros((n_slots, n_keys), dtype=bool),
+                kneg=jnp.zeros((n_slots, n_keys), dtype=bool),
+                kgt=jnp.full((n_slots, n_keys), -jnp.inf, dtype=jnp.float32),
+                klt=jnp.full((n_slots, n_keys), jnp.inf, dtype=jnp.float32),
+                zone=jnp.ones((n_slots, n_zones), dtype=bool),
+                ct=jnp.ones((n_slots, n_ct), dtype=bool),
+                viable=jnp.ones((n_slots, n_it), dtype=bool),
+                ports=jnp.zeros((n_slots, n_ports), dtype=bool),
+                pod_count=jnp.zeros(n_slots, dtype=jnp.int32),
+                tmpl_id=jnp.zeros(n_slots, dtype=jnp.int32),
+                open_=jnp.zeros(n_slots, dtype=bool),
+                n_next=jnp.int32(0),
+            )
+            if existing_state is None:
+                existing_state = empty_existing_state(n_res, n_keys, width, n_zones, n_ct, n_ports)
+                existing_static = empty_existing_static(n_res, n_classes, g1)
+            if existing_state.kmask.dtype != jnp.uint32:
+                existing_state = existing_state._replace(
+                    kmask=mask_ops.pack_mask(existing_state.kmask)
+                )
 
-        # seed topology counts from pre-existing pods (topology.go:231-276
-        # countDomains): forward from selector-matching pods, inverse from
-        # anti-term owners — closed nodes (consolidation subsets) drop out at
-        # derivation time (the zone projection multiplies by the open mask)
-        open_i = existing_state.open_.astype(jnp.int32)
-        member_open = existing_static.grp_node_member * open_i[None, :]
-        owner_open = existing_static.grp_node_owner * open_i[None, :]
-        topo = TopoCounts(
-            fwd_ex=member_open,
-            inv_ex=owner_open,
-            fwd_new=jnp.zeros((g1, n_slots), dtype=jnp.int32),
-            inv_new=jnp.zeros((g1, n_slots), dtype=jnp.int32),
-        )
+            # seed topology counts from pre-existing pods (topology.go:231-276
+            # countDomains): forward from selector-matching pods, inverse from
+            # anti-term owners — closed nodes (consolidation subsets) drop out at
+            # derivation time (the zone projection multiplies by the open mask)
+            open_i = existing_state.open_.astype(jnp.int32)
+            member_open = existing_static.grp_node_member * open_i[None, :]
+            owner_open = existing_static.grp_node_owner * open_i[None, :]
+            topo = TopoCounts(
+                fwd_ex=member_open,
+                inv_ex=owner_open,
+                fwd_new=jnp.zeros((g1, n_slots), dtype=jnp.int32),
+                inv_new=jnp.zeros((g1, n_slots), dtype=jnp.int32),
+            )
 
     def step(carry, cls_with_index):
         # the whole class step is masked behind count > 0: a zero-count class
@@ -1986,25 +2014,26 @@ def solve_core(
 
         return jax.lax.cond(_any_lane(cls.count > 0, statics), do, skip, carry)
 
-    cls_indices = jnp.arange(n_classes, dtype=jnp.int32)
-    if warm_carry is None:
-        # charge open owned nodes' capacity against their provisioner's budget
-        n_tmpl = statics.tmpl_zone.shape[0]
-        tmpl_onehot = (
-            existing_static.node_tmpl[:, None] == jnp.arange(n_tmpl)[None, :]
-        ) & (existing_static.node_owned & existing_state.open_)[:, None]  # [E, T]
-        used_budget = jnp.einsum(
-            "et,er->tr", tmpl_onehot.astype(jnp.float32), existing_static.node_capacity,
-            precision=_EXACT_F32,
-        )
-        remaining0 = statics.tmpl_limits0 - used_budget
-    carry = (state, existing_state, topo, remaining0)
-    assign = jnp.zeros((n_classes, n_slots), dtype=jnp.int32)
-    n_ex = existing_state.pod_count.shape[0]
-    assign_ex = jnp.zeros((n_classes, n_ex), dtype=jnp.int32)
-    count_left = class_tensors.count
-    failed = count_left
-    suspect = jnp.zeros(n_classes, dtype=bool)
+    with jax.named_scope("kc.init"):
+        cls_indices = jnp.arange(n_classes, dtype=jnp.int32)
+        if warm_carry is None:
+            # charge open owned nodes' capacity against their provisioner's budget
+            n_tmpl = statics.tmpl_zone.shape[0]
+            tmpl_onehot = (
+                existing_static.node_tmpl[:, None] == jnp.arange(n_tmpl)[None, :]
+            ) & (existing_static.node_owned & existing_state.open_)[:, None]  # [E, T]
+            used_budget = jnp.einsum(
+                "et,er->tr", tmpl_onehot.astype(jnp.float32), existing_static.node_capacity,
+                precision=_EXACT_F32,
+            )
+            remaining0 = statics.tmpl_limits0 - used_budget
+        carry = (state, existing_state, topo, remaining0)
+        assign = jnp.zeros((n_classes, n_slots), dtype=jnp.int32)
+        n_ex = existing_state.pod_count.shape[0]
+        assign_ex = jnp.zeros((n_classes, n_ex), dtype=jnp.int32)
+        count_left = class_tensors.count
+        failed = count_left
+        suspect = jnp.zeros(n_classes, dtype=bool)
     for p in range(max(n_passes, 1)):
         cls_pass = class_tensors._replace(count=count_left)
         xs = (cls_pass, cls_indices)
@@ -2013,38 +2042,40 @@ def solve_core(
                 repair_plan.pref_new.astype(jnp.int32),
                 repair_plan.pref_ex.astype(jnp.int32),
             )
-        carry, (a, a_ex, failed, suspect_p) = jax.lax.scan(step, carry, xs)
-        assign = assign + a
-        assign_ex = assign_ex + a_ex
-        suspect = suspect | suspect_p
-        # roll failed counts one step down the preference ladder (the host
-        # path's fail -> Preferences.Relax -> re-push round); classes with no
-        # successor retry as themselves (late-affinity re-scan)
-        roll_to = jnp.where(
-            class_tensors.relax_next >= 0, class_tensors.relax_next, cls_indices
-        )
-        count_left = jnp.zeros_like(failed).at[roll_to].add(failed)
-        if p + 1 < n_passes:
-            # shared volume adds are once-per-(LADDER, node): ladder rows
-            # share one claim profile, so a root placing in pass 1 and its
-            # variant landing on the same node in pass 2 must count the claim
-            # set once — collapse placements to the root row before the add
-            state_c, ex_c, topo_c, rem_c = carry
-            placed_any = (assign_ex > 0).astype(jnp.int32)  # [C, E]
-            placed_root = (
-                jnp.zeros_like(placed_any).at[class_tensors.root].max(placed_any)
+        with jax.named_scope("kc.scan"):
+            carry, (a, a_ex, failed, suspect_p) = jax.lax.scan(step, carry, xs)
+        with jax.named_scope("kc.finish"):
+            assign = assign + a
+            assign_ex = assign_ex + a_ex
+            suspect = suspect | suspect_p
+            # roll failed counts one step down the preference ladder (the host
+            # path's fail -> Preferences.Relax -> re-push round); classes with no
+            # successor retry as themselves (late-affinity re-scan)
+            roll_to = jnp.where(
+                class_tensors.relax_next >= 0, class_tensors.relax_next, cls_indices
             )
-            is_root = (class_tensors.root == cls_indices)[:, None].astype(jnp.int32)
-            shared = jnp.sum(
-                (placed_root * is_root)[:, :, None] * existing_static.cls_vol_add,
-                axis=0,
-            )
-            per_pod = jnp.sum(
-                assign_ex[:, :, None] * existing_static.cls_vol_per_pod[:, None, :],
-                axis=0,
-            )
-            ex_c = ex_c._replace(vol_used=existing_state.vol_used + shared + per_pod)
-            carry = (state_c, ex_c, topo_c, rem_c)
+            count_left = jnp.zeros_like(failed).at[roll_to].add(failed)
+            if p + 1 < n_passes:
+                # shared volume adds are once-per-(LADDER, node): ladder rows
+                # share one claim profile, so a root placing in pass 1 and its
+                # variant landing on the same node in pass 2 must count the claim
+                # set once — collapse placements to the root row before the add
+                state_c, ex_c, topo_c, rem_c = carry
+                placed_any = (assign_ex > 0).astype(jnp.int32)  # [C, E]
+                placed_root = (
+                    jnp.zeros_like(placed_any).at[class_tensors.root].max(placed_any)
+                )
+                is_root = (class_tensors.root == cls_indices)[:, None].astype(jnp.int32)
+                shared = jnp.sum(
+                    (placed_root * is_root)[:, :, None] * existing_static.cls_vol_add,
+                    axis=0,
+                )
+                per_pod = jnp.sum(
+                    assign_ex[:, :, None] * existing_static.cls_vol_per_pod[:, None, :],
+                    axis=0,
+                )
+                ex_c = ex_c._replace(vol_used=existing_state.vol_used + shared + per_pod)
+                carry = (state_c, ex_c, topo_c, rem_c)
     final_state, final_ex, final_topo, final_remaining = carry
     return SolveOutputs(
         assign=assign,
@@ -2141,31 +2172,32 @@ def _repair_free_impl(
     only under-place (never corrupt), and it is exactly the accumulated
     optimality drift the fallback policy's periodic full-solve audit resets
     (docs/INCREMENTAL.md)."""
-    wc = WarmCarry(*warm_carry)
-    state = NodeState(*wc.state)
-    ex = ExistingState(*wc.ex_state)
-    topo = TopoCounts(*wc.topo)
-    f_new = free_new.astype(jnp.float32)
-    f_ex = free_ex.astype(jnp.float32)
-    state = state._replace(
-        used=state.used - jnp.einsum(
-            "cn,cr->nr", f_new, cls_requests, precision=_EXACT_F32
-        ),
-        pod_count=jnp.maximum(state.pod_count - jnp.sum(free_new, axis=0), 0),
-    )
-    ex = ex._replace(
-        used=ex.used - jnp.einsum(
-            "ce,cr->er", f_ex, cls_requests, precision=_EXACT_F32
-        ),
-        pod_count=jnp.maximum(ex.pod_count - jnp.sum(free_ex, axis=0), 0),
-    )
-    topo = TopoCounts(
-        fwd_ex=jnp.maximum(topo.fwd_ex - jnp.einsum("cg,ce->ge", member, free_ex), 0),
-        inv_ex=jnp.maximum(topo.inv_ex - jnp.einsum("cg,ce->ge", own_inv, free_ex), 0),
-        fwd_new=jnp.maximum(topo.fwd_new - jnp.einsum("cg,cn->gn", member, free_new), 0),
-        inv_new=jnp.maximum(topo.inv_new - jnp.einsum("cg,cn->gn", own_inv, free_new), 0),
-    )
-    return WarmCarry(state=state, ex_state=ex, topo=topo, remaining=wc.remaining)
+    with jax.named_scope("kc.repair.free"):
+        wc = WarmCarry(*warm_carry)
+        state = NodeState(*wc.state)
+        ex = ExistingState(*wc.ex_state)
+        topo = TopoCounts(*wc.topo)
+        f_new = free_new.astype(jnp.float32)
+        f_ex = free_ex.astype(jnp.float32)
+        state = state._replace(
+            used=state.used - jnp.einsum(
+                "cn,cr->nr", f_new, cls_requests, precision=_EXACT_F32
+            ),
+            pod_count=jnp.maximum(state.pod_count - jnp.sum(free_new, axis=0), 0),
+        )
+        ex = ex._replace(
+            used=ex.used - jnp.einsum(
+                "ce,cr->er", f_ex, cls_requests, precision=_EXACT_F32
+            ),
+            pod_count=jnp.maximum(ex.pod_count - jnp.sum(free_ex, axis=0), 0),
+        )
+        topo = TopoCounts(
+            fwd_ex=jnp.maximum(topo.fwd_ex - jnp.einsum("cg,ce->ge", member, free_ex), 0),
+            inv_ex=jnp.maximum(topo.inv_ex - jnp.einsum("cg,ce->ge", own_inv, free_ex), 0),
+            fwd_new=jnp.maximum(topo.fwd_new - jnp.einsum("cg,cn->gn", member, free_new), 0),
+            inv_new=jnp.maximum(topo.inv_new - jnp.einsum("cg,cn->gn", own_inv, free_new), 0),
+        )
+        return WarmCarry(state=state, ex_state=ex, topo=topo, remaining=wc.remaining)
 
 
 repair_free = jax.jit(_repair_free_impl)
@@ -2191,45 +2223,46 @@ def gather_repair_window(warm_carry: WarmCarry, idx: jnp.ndarray, n_open_w):
     every EXCLUDED open slot, which the windowed solve adds back as constants
     (RepairPlan).  The per-class-step cost of the repair then scales with the
     window, not the fleet (docs/INCREMENTAL.md)."""
-    wc = WarmCarry(*warm_carry)
-    state = NodeState(*wc.state)
-    topo = TopoCounts(*wc.topo)
-    n_slots = state.pod_count.shape[0]
-    excl_open = jnp.ones(n_slots, dtype=bool).at[idx].set(False) & state.open_
-    zone_i = state.zone.astype(jnp.int32) * excl_open.astype(jnp.int32)[:, None]
-    sing = jnp.where(jnp.sum(zone_i, axis=-1, keepdims=True) == 1, zone_i, 0)
-    base = (
-        jnp.einsum("gn,nz->gz", topo.fwd_new, sing),
-        jnp.einsum("gn,nz->gz", topo.fwd_new, zone_i),
-        jnp.einsum("gn,nz->gz", topo.inv_new, zone_i),
-    )
-    w_state = NodeState(
-        used=state.used[idx],
-        kmask=state.kmask[idx],
-        kdef=state.kdef[idx],
-        kneg=state.kneg[idx],
-        kgt=state.kgt[idx],
-        klt=state.klt[idx],
-        zone=state.zone[idx],
-        ct=state.ct[idx],
-        viable=state.viable[idx],
-        ports=state.ports[idx],
-        pod_count=state.pod_count[idx],
-        tmpl_id=state.tmpl_id[idx],
-        open_=state.open_[idx],
-        n_next=jnp.asarray(n_open_w, dtype=jnp.int32),
-    )
-    w_topo = TopoCounts(
-        fwd_ex=topo.fwd_ex,
-        inv_ex=topo.inv_ex,
-        fwd_new=topo.fwd_new[:, idx],
-        inv_new=topo.inv_new[:, idx],
-    )
-    return (
-        WarmCarry(state=w_state, ex_state=wc.ex_state, topo=w_topo,
-                  remaining=wc.remaining),
-        base,
-    )
+    with jax.named_scope("kc.repair.gather"):
+        wc = WarmCarry(*warm_carry)
+        state = NodeState(*wc.state)
+        topo = TopoCounts(*wc.topo)
+        n_slots = state.pod_count.shape[0]
+        excl_open = jnp.ones(n_slots, dtype=bool).at[idx].set(False) & state.open_
+        zone_i = state.zone.astype(jnp.int32) * excl_open.astype(jnp.int32)[:, None]
+        sing = jnp.where(jnp.sum(zone_i, axis=-1, keepdims=True) == 1, zone_i, 0)
+        base = (
+            jnp.einsum("gn,nz->gz", topo.fwd_new, sing),
+            jnp.einsum("gn,nz->gz", topo.fwd_new, zone_i),
+            jnp.einsum("gn,nz->gz", topo.inv_new, zone_i),
+        )
+        w_state = NodeState(
+            used=state.used[idx],
+            kmask=state.kmask[idx],
+            kdef=state.kdef[idx],
+            kneg=state.kneg[idx],
+            kgt=state.kgt[idx],
+            klt=state.klt[idx],
+            zone=state.zone[idx],
+            ct=state.ct[idx],
+            viable=state.viable[idx],
+            ports=state.ports[idx],
+            pod_count=state.pod_count[idx],
+            tmpl_id=state.tmpl_id[idx],
+            open_=state.open_[idx],
+            n_next=jnp.asarray(n_open_w, dtype=jnp.int32),
+        )
+        w_topo = TopoCounts(
+            fwd_ex=topo.fwd_ex,
+            inv_ex=topo.inv_ex,
+            fwd_new=topo.fwd_new[:, idx],
+            inv_new=topo.inv_new[:, idx],
+        )
+        return (
+            WarmCarry(state=w_state, ex_state=wc.ex_state, topo=w_topo,
+                      remaining=wc.remaining),
+            base,
+        )
 
 
 def _scatter_repair_window_impl(
@@ -2239,36 +2272,37 @@ def _scatter_repair_window_impl(
     per-slot planes scatter to their global slots, the existing-node state
     and limit budget are replaced whole (the repair is their only writer),
     and ``n_next`` advances by however many fresh slots the repair opened."""
-    wc = WarmCarry(*warm_carry)
-    ww = WarmCarry(*window_carry)
-    gs = NodeState(*wc.state)
-    ws = NodeState(*ww.state)
-    gt = TopoCounts(*wc.topo)
-    wt = TopoCounts(*ww.topo)
-    state = NodeState(
-        used=gs.used.at[idx].set(ws.used),
-        kmask=gs.kmask.at[idx].set(ws.kmask),
-        kdef=gs.kdef.at[idx].set(ws.kdef),
-        kneg=gs.kneg.at[idx].set(ws.kneg),
-        kgt=gs.kgt.at[idx].set(ws.kgt),
-        klt=gs.klt.at[idx].set(ws.klt),
-        zone=gs.zone.at[idx].set(ws.zone),
-        ct=gs.ct.at[idx].set(ws.ct),
-        viable=gs.viable.at[idx].set(ws.viable),
-        ports=gs.ports.at[idx].set(ws.ports),
-        pod_count=gs.pod_count.at[idx].set(ws.pod_count),
-        tmpl_id=gs.tmpl_id.at[idx].set(ws.tmpl_id),
-        open_=gs.open_.at[idx].set(ws.open_),
-        n_next=gs.n_next + (ws.n_next - jnp.asarray(n_open_w, dtype=jnp.int32)),
-    )
-    topo = TopoCounts(
-        fwd_ex=wt.fwd_ex,
-        inv_ex=wt.inv_ex,
-        fwd_new=gt.fwd_new.at[:, idx].set(wt.fwd_new),
-        inv_new=gt.inv_new.at[:, idx].set(wt.inv_new),
-    )
-    return WarmCarry(state=state, ex_state=ww.ex_state, topo=topo,
-                     remaining=ww.remaining)
+    with jax.named_scope("kc.repair.scatter"):
+        wc = WarmCarry(*warm_carry)
+        ww = WarmCarry(*window_carry)
+        gs = NodeState(*wc.state)
+        ws = NodeState(*ww.state)
+        gt = TopoCounts(*wc.topo)
+        wt = TopoCounts(*ww.topo)
+        state = NodeState(
+            used=gs.used.at[idx].set(ws.used),
+            kmask=gs.kmask.at[idx].set(ws.kmask),
+            kdef=gs.kdef.at[idx].set(ws.kdef),
+            kneg=gs.kneg.at[idx].set(ws.kneg),
+            kgt=gs.kgt.at[idx].set(ws.kgt),
+            klt=gs.klt.at[idx].set(ws.klt),
+            zone=gs.zone.at[idx].set(ws.zone),
+            ct=gs.ct.at[idx].set(ws.ct),
+            viable=gs.viable.at[idx].set(ws.viable),
+            ports=gs.ports.at[idx].set(ws.ports),
+            pod_count=gs.pod_count.at[idx].set(ws.pod_count),
+            tmpl_id=gs.tmpl_id.at[idx].set(ws.tmpl_id),
+            open_=gs.open_.at[idx].set(ws.open_),
+            n_next=gs.n_next + (ws.n_next - jnp.asarray(n_open_w, dtype=jnp.int32)),
+        )
+        topo = TopoCounts(
+            fwd_ex=wt.fwd_ex,
+            inv_ex=wt.inv_ex,
+            fwd_new=gt.fwd_new.at[:, idx].set(wt.fwd_new),
+            inv_new=gt.inv_new.at[:, idx].set(wt.inv_new),
+        )
+        return WarmCarry(state=state, ex_state=ww.ex_state, topo=topo,
+                         remaining=ww.remaining)
 
 
 scatter_repair_window = jax.jit(_scatter_repair_window_impl)
@@ -2287,13 +2321,14 @@ def pack_bool(arr: jnp.ndarray) -> jnp.ndarray:
     """uint8[..., ceil(M/8)] bit-packed bools — the big [N, I] planes cross
     the device→host link packed (8× smaller) and unpack host-side with
     np.unpackbits."""
-    m = arr.shape[-1]
-    pad = (-m) % 8
-    if pad:
-        arr = jnp.pad(arr, [(0, 0)] * (arr.ndim - 1) + [(0, pad)])
-    grouped = arr.reshape(arr.shape[:-1] + (-1, 8)).astype(jnp.uint8)
-    weights = jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], dtype=jnp.uint8)
-    return jnp.sum(grouped * weights, axis=-1, dtype=jnp.uint8)
+    with jax.named_scope("kc.finish"):
+        m = arr.shape[-1]
+        pad = (-m) % 8
+        if pad:
+            arr = jnp.pad(arr, [(0, 0)] * (arr.ndim - 1) + [(0, pad)])
+        grouped = arr.reshape(arr.shape[:-1] + (-1, 8)).astype(jnp.uint8)
+        weights = jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], dtype=jnp.uint8)
+        return jnp.sum(grouped * weights, axis=-1, dtype=jnp.uint8)
 
 
 def unpack_bool(packed: np.ndarray, m: int) -> np.ndarray:

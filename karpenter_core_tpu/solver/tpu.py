@@ -1421,8 +1421,7 @@ class TPUSolver:
         # pipelined settle (exposed wait ≈ 0 here); without an upstream sync
         # (ops/solve.sync_outputs) a cold barrier also absorbs any
         # still-running device compute.
-        with tracing.span("decode.fetch", arrays=9, batched=True,
-                          prefetched=ticket.done(), staged=ticket.staged):
+        with tracing.span("decode.fetch", prefetched=ticket.done(), staged=ticket.staged):
             (assign, assign_ex, failed, suspect, ex_zone, pod_count, tmpl_id,
              open_, n_next) = ticket.wait()
 

@@ -834,8 +834,7 @@ def run_solve(
     # the separate "solve" span blocks on the outputs (tracing only) so device
     # compute is attributed to the solve, not to whichever span first touches
     # the result — the JAX-aware boundary docs/OBSERVABILITY.md describes.
-    with tracing.span("dispatch", n_slots=n_slots, n_passes=n_passes,
-                      warm=warm_carry is not None,
+    with tracing.span("dispatch", n_passes=n_passes, warm=warm_carry is not None,
                       mesh=repr(mesh_axes) if mesh_axes else None):
         if (
             not pre_padded

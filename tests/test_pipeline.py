@@ -441,9 +441,10 @@ class TestPipelinePrimitives:
 
 class TestDecodeFetchSpan:
     def test_serial_decode_fetch_span_attrs_pinned(self):
-        """Satellite: the serial path's decode.fetch is the batched
-        async-copy fetch — 9 arrays, one device_get — and says so on the
-        span (the attrs the overlap triage reads)."""
+        """Satellite: the serial path's decode.fetch is one span whose
+        attributes say what varies — whether the barrier had already run
+        and whether the copy was staged (docs/OBSERVABILITY.md) — and carry
+        no constant."""
         solver = _solver()
         ingest = PodIngest()
         ingest.add_all(_population(16))
@@ -459,10 +460,9 @@ class TestDecodeFetchSpan:
             fetch = [s for s in trace.spans if s["name"] == "decode.fetch"]
             assert len(fetch) == 1
             attrs = fetch[0]["attrs"]
-            assert attrs["arrays"] == 9
-            assert attrs["batched"] is True
             assert attrs["prefetched"] is False  # no caller-side ticket
             assert attrs["staged"] is False
+            assert set(attrs) <= {"prefetched", "staged", "tenant"}
         finally:
             tracing.disable()
             tracing.TRACE_STORE.clear()
