@@ -34,10 +34,11 @@ New with the framework:
                       columnarized the per-pod work into interned fast keys
                       and numpy batch ops, and a new O(pods)-body loop would
                       silently regress the million-pod tick budget.  The
-                      deliberate residual loops (the bulk-add driver whose
-                      body is O(1) dict work, the cold classify_pods batch
-                      path) carry baseline entries with reasons — the rule
-                      exists so NEW ones can't land unexplained.
+                      deliberate residual loops (the bulk-add driver and
+                      the cold batch paths' group_by_signature, whose
+                      bodies are O(1) dict work against the fast key) carry
+                      baseline entries with reasons — the rule exists so
+                      NEW ones can't land unexplained.
 """
 
 from __future__ import annotations

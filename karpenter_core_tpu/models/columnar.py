@@ -28,8 +28,9 @@ construction (tests/test_encode_delta.py fuzzes the guarantee).
 
 from __future__ import annotations
 
+from collections import defaultdict
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -277,6 +278,38 @@ def _fast_sig_key(pod: Pod):
     """Dispatching front door of the fast key (the resolved C-or-Python
     implementation); see ``_fast_sig_key_py`` for the exactness contract."""
     return _sig_key_impl()(pod)
+
+
+def group_by_signature(pods: List[Pod]) -> Tuple[Dict[tuple, List[int]], int, int]:
+    """One batch, grouped by the fast key first: ``(by_sig, fast_keys,
+    punted)``.  ``by_sig`` is exactly the dict the per-pod loop
+    ``by_sig.setdefault(_class_signature(pod), []).append(i)`` builds — the
+    distinct signatures in order of first appearance, each with its ascending
+    member indices — but ``_class_signature`` runs once per distinct fast key
+    (``fast_keys`` of them) and once per pod whose key is ``None`` (``punted``:
+    the shapes ``_fast_sig_key_py`` refuses), not once per pod.  Keys that
+    sort to one signature (label or selector order differs) share its group.
+    Nothing outlives the call: the cold batch paths (``classify_pods``, the
+    channel client's ``solve_classes``) are stateless."""
+    from karpenter_core_tpu.models.snapshot import _class_signature
+
+    by_key: Dict[Optional[tuple], List[int]] = defaultdict(list)
+    for i, key in enumerate(map(_sig_key_impl(), pods)):
+        by_key[key].append(i)
+    punted = by_key.pop(None, [])
+    by_sig: Dict[tuple, List[int]] = {}
+    for idxs in by_key.values():
+        have = by_sig.setdefault(_class_signature(pods[idxs[0]]), idxs)
+        if have is not idxs:
+            have.extend(idxs)
+    for i in punted:
+        by_sig.setdefault(_class_signature(pods[i]), []).append(i)
+    # a signature fed by several keys or punts is out of order until here; the
+    # usual batch (one key a class) is sorted already and costs a linear pass
+    for idxs in by_sig.values():
+        idxs.sort()
+    by_sig = dict(sorted(by_sig.items(), key=lambda kv: kv[1][0]))
+    return by_sig, len(by_key), len(punted)
 
 
 class SignatureInterner:

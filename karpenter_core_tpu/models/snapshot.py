@@ -629,17 +629,17 @@ def classify_pods(pods: List[Pod]) -> List[PodClass]:
     classes (cross-group affinity, inverse anti-affinity).  Raises
     KernelUnsupported for shapes the kernel doesn't model: host ports,
     region/custom-key topologies, multiple same-kind constraints per pod."""
-    groups: Dict[tuple, PodClass] = {}
-    order: List[tuple] = []
-    for pod in pods:
-        sig = _class_signature(pod)
-        cls = groups.get(sig)
-        if cls is None:
-            cls = build_pod_ladder(pod)
-            groups[sig] = cls
-            order.append(sig)
-        cls.pods.append(pod)
-    return finalize_classes([groups[sig] for sig in order])
+    from karpenter_core_tpu.models.columnar import group_by_signature
+
+    with tracing.span("encode.classify", pods=len(pods)) as sp:
+        by_sig, fast_keys, punted = group_by_signature(pods)
+        classes = []
+        for idxs in by_sig.values():
+            cls = build_pod_ladder(pods[idxs[0]])
+            cls.pods = [pods[i] for i in idxs]
+            classes.append(cls)
+        sp.set(classes=len(classes), fast_keys=fast_keys, punted=punted)
+    return finalize_classes(classes)
 
 
 def _group_spec(

@@ -1744,14 +1744,12 @@ class SnapshotSolverClient:
         the objective was on."""
         self._client_chaos("SolveClasses")
         if members is None:
-            from karpenter_core_tpu.models.snapshot import _class_signature
+            from karpenter_core_tpu.models.columnar import group_by_signature
 
             with tracing.span("client.classify", pods=len(pods)) as sp:
-                by_sig: Dict[tuple, List[int]] = {}
-                for i, pod in enumerate(pods):
-                    by_sig.setdefault(_class_signature(pod), []).append(i)
+                by_sig, fast_keys, punted = group_by_signature(pods)
                 members = list(by_sig.values())
-                sp.set(classes=len(members))
+                sp.set(classes=len(members), fast_keys=fast_keys, punted=punted)
         with tracing.span("client.pack", classes=len(members)) as sp:
             request = msgpack.packb(
                 {

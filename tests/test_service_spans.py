@@ -87,8 +87,10 @@ def test_stateless_request_opens_the_same_spans_whatever_its_class_count(
     assert not out["failedPodIndices"]
     spans = _drain()
     assert _phase_counts(spans) == STATELESS
-    # each phase carries the count of the work it did
-    assert _one(spans, "client.classify")["attrs"] == {"pods": n_pods, "classes": n_classes}
+    # each phase carries the count of the work it did; classify also how far
+    # the fast key carried it: one derivation per distinct key, no pod punted
+    assert _one(spans, "client.classify")["attrs"] == {
+        "pods": n_pods, "classes": n_classes, "fast_keys": n_classes, "punted": 0}
     request_bytes = _one(spans, "client.pack")["attrs"]["request_bytes"]
     reply_bytes = _one(spans, "service.pack")["attrs"]["reply_bytes"]
     assert _one(spans, "client.rpc")["attrs"] == {
