@@ -40,7 +40,7 @@ from karpenter_core_tpu.operator.settings import Settings
 from karpenter_core_tpu.scheduling import Requirement, Requirements
 from karpenter_core_tpu.solver.builder import NoProvisionersError, build_scheduler
 from karpenter_core_tpu.solver.scheduler import SchedulerOptions, SchedulingResults
-from karpenter_core_tpu.state.cluster import Cluster
+from karpenter_core_tpu.state.cluster import STATE_NODE_REBUILDS, Cluster
 from karpenter_core_tpu.utils import pod as pod_util
 from karpenter_core_tpu.utils import retry
 from karpenter_core_tpu.utils.clock import Clock
@@ -441,26 +441,29 @@ class ProvisioningController:
         return err
 
     def _reconcile_batch(self) -> Optional[str]:
-        state_nodes = []
-        deleting_nodes = []
-        for node in self.cluster.snapshot_nodes():
-            if not node.marked():
-                state_nodes.append(node)
-            else:
-                deleting_nodes.append(node)
+        with tracing.span("provisioning.pending") as sp:
+            state_nodes = []
+            deleting_nodes = []
+            for node in self.cluster.snapshot_nodes():
+                if not node.marked():
+                    state_nodes.append(node)
+                else:
+                    deleting_nodes.append(node)
 
-        pods = self.get_pending_pods()
-        # pods on deleting (cordoned) nodes also need homes (provisioner.go:137-144)
-        deleting_names = {n.node.name for n in deleting_nodes}
-        for pod in self.kube_client.list_pods():
-            if (
-                pod.spec.node_name in deleting_names
-                and not pod_util.is_terminal(pod)
-                and not pod_util.is_terminating(pod)
-                and not pod_util.is_owned_by_daemon_set(pod)
-                and not pod_util.is_owned_by_node(pod)
-            ):
-                pods.append(pod)
+            pods = self.get_pending_pods()
+            # pods on deleting (cordoned) nodes also need homes (provisioner.go:137-144)
+            deleting_names = {n.node.name for n in deleting_nodes}
+            listed = self.kube_client.list_pods()
+            for pod in listed:
+                if (
+                    pod.spec.node_name in deleting_names
+                    and not pod_util.is_terminal(pod)
+                    and not pod_util.is_terminating(pod)
+                    and not pod_util.is_owned_by_daemon_set(pod)
+                    and not pod_util.is_owned_by_node(pod)
+                ):
+                    pods.append(pod)
+            sp.set(pods=len(pods), listed=len(listed))
         if not pods:
             return None
 
@@ -819,11 +822,13 @@ class ProvisioningController:
                     "solving %d kernel-unsupported pods on the host path "
                     "(%d solved on tpu)", len(host_pods), len(tpu_pods),
                 )
-            host_results = self._solve_host_remainder(
-                host_pods + residual_pods, state_nodes, tpu_results,
-                results.new_nodes, daemonset_pods,
-                seed_topology=bool(residual_pods),
-            )
+            remainder = host_pods + residual_pods
+            with tracing.span("provisioning.remainder", pods=len(remainder)):
+                host_results = self._solve_host_remainder(
+                    remainder, state_nodes, tpu_results,
+                    results.new_nodes, daemonset_pods,
+                    seed_topology=bool(residual_pods),
+                )
             results.new_nodes.extend(host_results.new_nodes)
             results.failed_pods.extend(host_results.failed_pods)
             results.errors.update(host_results.errors)
@@ -884,54 +889,59 @@ class ProvisioningController:
         from karpenter_core_tpu.apis import codec
         from karpenter_core_tpu.solver.tpu import TPUSolveResults
 
-        client = self._solver_client
-        if client is None:
-            from karpenter_core_tpu.service.snapshot_channel import (
-                SnapshotSolverClient,
-            )
+        # everything this side does to the snapshot before the client has it
+        with tracing.span("provisioning.wire") as sp:
+            client = self._solver_client
+            if client is None:
+                from karpenter_core_tpu.service.snapshot_channel import (
+                    SnapshotSolverClient,
+                )
 
-            client = self._solver_client = SnapshotSolverClient(self.solver_endpoint)
+                client = self._solver_client = SnapshotSolverClient(self.solver_endpoint)
 
-        bound_by_node: Dict[str, List[Pod]] = {}
-        for pod in bound_pods:
-            if (
-                pod.spec.node_name
-                and not pod_util.is_terminal(pod)
-                and not pod_util.is_terminating(pod)
-            ):
-                bound_by_node.setdefault(pod.spec.node_name, []).append(pod)
-        nodes = [
-            {
-                "node": codec.node_to_dict(sn.node),
-                "pods": [
-                    codec.pod_to_dict(p)
-                    for p in bound_by_node.get(sn.node.name, [])
-                ],
-                "volumeLimits": dict(sn.volume_limits()),
-            }
-            for sn in (state_nodes or [])
-        ]
-        # resolve claims for the BOUND pods too: the server counts existing
-        # volume attachments from them, and an unresolvable claim reads as
-        # zero attachments (VolumeUsage.add drops resolution errors) — the
-        # node would look empty and over-admit new PVC pods
-        shipped_bound = [
-            p for sn in (state_nodes or [])
-            for p in bound_by_node.get(sn.node.name, [])
-        ]
-        # _split_batch laid tpu_pods out class-by-class: membership is the
-        # running offsets, no second O(pods) signature pass
-        members: List[List[int]] = []
-        offset = 0
-        for cls in tpu_classes:
-            members.append(list(range(offset, offset + len(cls.pods))))
-            offset += len(cls.pods)
+            bound_by_node: Dict[str, List[Pod]] = {}
+            for pod in bound_pods:
+                if (
+                    pod.spec.node_name
+                    and not pod_util.is_terminal(pod)
+                    and not pod_util.is_terminating(pod)
+                ):
+                    bound_by_node.setdefault(pod.spec.node_name, []).append(pod)
+            nodes = [
+                {
+                    "node": codec.node_to_dict(sn.node),
+                    "pods": [
+                        codec.pod_to_dict(p)
+                        for p in bound_by_node.get(sn.node.name, [])
+                    ],
+                    "volumeLimits": dict(sn.volume_limits()),
+                }
+                for sn in (state_nodes or [])
+            ]
+            # resolve claims for the BOUND pods too: the server counts existing
+            # volume attachments from them, and an unresolvable claim reads as
+            # zero attachments (VolumeUsage.add drops resolution errors) — the
+            # node would look empty and over-admit new PVC pods
+            shipped_bound = [
+                p for sn in (state_nodes or [])
+                for p in bound_by_node.get(sn.node.name, [])
+            ]
+            # _split_batch laid tpu_pods out class-by-class: membership is the
+            # running offsets, no second O(pods) signature pass
+            members: List[List[int]] = []
+            offset = 0
+            for cls in tpu_classes:
+                members.append(list(range(offset, offset + len(cls.pods))))
+                offset += len(cls.pods)
+            claim_drivers = self._claim_drivers(tpu_pods + shipped_bound)
+            sp.set(nodes=len(nodes), bound_pods=len(shipped_bound),
+                   claims=len(claim_drivers))
         try:
             response = client.solve_classes(
                 tpu_pods, provisioners,
                 nodes=nodes,
                 daemonset_pods=daemonset_pods,
-                claim_drivers=self._claim_drivers(tpu_pods + shipped_bound),
+                claim_drivers=claim_drivers,
                 members=members,
                 # the replica's resolved policy config rides the wire: the
                 # remote objective stage must select offerings exactly like
@@ -1026,10 +1036,11 @@ class ProvisioningController:
         pod-list rebuilds.  The wall cost lands on ``last_ingest_s`` (the
         soak runner's advisory ingest probe)."""
         t0 = time.perf_counter()
-        try:
-            return self._split_batch_impl(pods)
-        finally:
-            self.last_ingest_s = time.perf_counter() - t0
+        with tracing.span("provisioning.split", pods=len(pods)):
+            try:
+                return self._split_batch_impl(pods)
+            finally:
+                self.last_ingest_s = time.perf_counter() - t0
 
     def _split_batch_impl(self, pods: List[Pod]):
         from dataclasses import replace as dc_replace
@@ -1038,10 +1049,12 @@ class ProvisioningController:
         supported: Dict[tuple, List[Pod]] = {}
         unsupported: Dict[tuple, List[Pod]] = {}
         protos: Dict[tuple, object] = {}
+        interned = 0  # shapes of this batch the interner already knew
         for pod in pods:
             sig = interner.sig_of(pod)
             proto = protos.get(sig)
             if proto is None and sig not in protos:
+                interned += interner.knows(sig)
                 proto, _error = interner.ladder_of(sig, pod)
                 protos[sig] = proto
             (supported if proto is not None else unsupported).setdefault(
@@ -1058,6 +1071,9 @@ class ProvisioningController:
             cls = dc_replace(protos[sig], pods=group, interned_sig=sig)
             tpu_classes.append(cls)
             tpu_pods.extend(group)
+        tracing.set_attrs(
+            classes=len(tpu_classes), host_pods=len(host_pods), interned=interned
+        )
         if not host_pods:
             return tpu_classes, tpu_pods, []
         if not tpu_pods:
@@ -1283,11 +1299,26 @@ class ProvisioningController:
             names[i] = name or ""
             errs[i] = err
 
-        if len(machines) == 1:
-            one(0)
-        else:
-            with ThreadPoolExecutor(max_workers=min(len(machines), 32)) as pool:
-                list(pool.map(one, range(len(machines))))
+        with tracing.span("provisioning.launch", machines=len(machines)) as sp:
+            counted = tracing.enabled()  # off: the span is a flag check, so are its counts
+            rebuilds0 = STATE_NODE_REBUILDS.labels().value if counted else 0.0
+            if len(machines) == 1:
+                one(0)
+            else:
+                with ThreadPoolExecutor(max_workers=min(len(machines), 32)) as pool:
+                    list(pool.map(one, range(len(machines))))
+            if counted:
+                launched = [m for m, name in zip(machines, names) if name]
+                sp.set(
+                    created=len(launched),
+                    # one Nominated event per pod of a launched machine
+                    events=(
+                        sum(len(m.pods) for m in launched) if self.recorder is not None else 0
+                    ),
+                    # every rebuild of a state node while the launch ran, the
+                    # informer's and the node controller's beside launch's own
+                    state_rebuilds=int(STATE_NODE_REBUILDS.labels().value - rebuilds0),
+                )
         messages = [e for e in errs if e]
         return [n or "" for n in names], ("; ".join(messages) if messages else None)
 

@@ -341,6 +341,10 @@ class SignatureInterner:
             sig = self._sigs[fk] = _class_signature(pod)
         return sig
 
+    def knows(self, sig: tuple) -> bool:
+        """True when ``ladder_of(sig, ...)`` would be a hit."""
+        return sig in self._ladders
+
     def ladder_of(self, sig: tuple, pod: Pod):
         """(proto, error) for one shape: the ``build_pod_ladder`` prototype
         (pods list EMPTY — callers attach members via dataclasses.replace,

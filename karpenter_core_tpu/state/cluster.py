@@ -15,10 +15,17 @@ from typing import Callable, Dict, List, Optional, Tuple
 from karpenter_core_tpu.apis import labels as labels_api
 from karpenter_core_tpu.apis.objects import Node, Pod, Taint
 from karpenter_core_tpu.apis.v1alpha5 import Provisioner
+from karpenter_core_tpu.metrics import REGISTRY
 from karpenter_core_tpu.scheduling import HostPortUsage, VolumeCount, VolumeUsage
 from karpenter_core_tpu.utils import pod as pod_util
 from karpenter_core_tpu.utils import resources as resources_util
 from karpenter_core_tpu.utils.clock import Clock
+
+STATE_NODE_REBUILDS = REGISTRY.counter(
+    "karpenter_cluster_state_node_rebuilds_total",
+    "State nodes rebuilt from their API object (Cluster.update_node): each "
+    "is a LIST of the store's pods filtered to the node's.",
+)
 
 TAINT_NODE_NOT_READY = "node.kubernetes.io/not-ready"
 TAINT_NODE_UNREACHABLE = "node.kubernetes.io/unreachable"
@@ -295,6 +302,7 @@ class Cluster:
     def _new_state_from_node(
         self, node: Node, old: Optional[StateNode]
     ) -> Tuple[Optional[StateNode], Optional[str]]:
+        STATE_NODE_REBUILDS.inc()
         n = StateNode(node, self.kube_client)
         if old is not None:
             n.marked_for_deletion = old.marked_for_deletion

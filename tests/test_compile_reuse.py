@@ -46,7 +46,10 @@ class TestSteadyStateCompileReuse:
     def test_varied_batches_reuse_one_executable(self):
         provider = fake_cp.FakeCloudProvider(fake_cp.instance_types(24))
         solver = TPUSolver(provider, [make_provisioner()])
-        compilecache.reset_stats()
+        # a process's first reconcile: the memo and the slot / feature
+        # hysteresis are the process's, and an earlier test file of the same
+        # xdist worker that solved these shapes would leave nothing to build
+        compilecache.reset_memo()
 
         # first batch pays the build
         r = solver.solve(_mix(40, 8, [{"cpu": "500m"}, {"cpu": 1}]))
@@ -156,7 +159,7 @@ class TestSteadyStateCompileReuse:
                 np.shape(solver.prepare_encoded(snapshot).cls.member_idx)[1],
             ))
         assert widths == [(1, 8), (3, 8)]
-        compilecache.reset_stats()
+        compilecache.reset_memo()  # as above: the first solve must find nothing built
         solver.solve(batch(False))
         first = compilecache.stats()["builds"]
         assert first >= 1
