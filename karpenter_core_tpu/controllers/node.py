@@ -116,7 +116,7 @@ class EmptinessStamper:
         return 60.0
 
     def _is_empty(self, node: Node) -> bool:
-        for pod in self.kube_client.list_pods(selector=lambda p: p.spec.node_name == node.name):
+        for pod in self.kube_client.pods_on_node(node.name):
             if (
                 not pod_util.is_terminal(pod)
                 and not pod_util.is_owned_by_daemon_set(pod)

@@ -40,7 +40,11 @@ from karpenter_core_tpu.operator.settings import Settings
 from karpenter_core_tpu.scheduling import Requirement, Requirements
 from karpenter_core_tpu.solver.builder import NoProvisionersError, build_scheduler
 from karpenter_core_tpu.solver.scheduler import SchedulerOptions, SchedulingResults
-from karpenter_core_tpu.state.cluster import STATE_NODE_REBUILDS, Cluster
+from karpenter_core_tpu.state.cluster import (
+    STATE_NODE_REBUILD_PODS,
+    STATE_NODE_REBUILDS,
+    Cluster,
+)
 from karpenter_core_tpu.utils import pod as pod_util
 from karpenter_core_tpu.utils import retry
 from karpenter_core_tpu.utils.clock import Clock
@@ -1302,6 +1306,7 @@ class ProvisioningController:
         with tracing.span("provisioning.launch", machines=len(machines)) as sp:
             counted = tracing.enabled()  # off: the span is a flag check, so are its counts
             rebuilds0 = STATE_NODE_REBUILDS.labels().value if counted else 0.0
+            rebuild_pods0 = STATE_NODE_REBUILD_PODS.labels().value if counted else 0.0
             if len(machines) == 1:
                 one(0)
             else:
@@ -1318,6 +1323,11 @@ class ProvisioningController:
                     # every rebuild of a state node while the launch ran, the
                     # informer's and the node controller's beside launch's own
                     state_rebuilds=int(STATE_NODE_REBUILDS.labels().value - rebuilds0),
+                    # the pods those rebuilds read from the store: what is
+                    # bound to the launched nodes, 0 on a fresh fleet
+                    rebuild_pods=int(
+                        STATE_NODE_REBUILD_PODS.labels().value - rebuild_pods0
+                    ),
                 )
         messages = [e for e in errs if e]
         return [n or "" for n in names], ("; ".join(messages) if messages else None)

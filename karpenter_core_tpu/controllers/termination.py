@@ -157,7 +157,7 @@ class Terminator:
 
     def _get_pods(self, node: Node) -> List[Pod]:
         pods = []
-        for p in self.kube_client.list_pods(selector=lambda p: p.spec.node_name == node.name):
+        for p in self.kube_client.pods_on_node(node.name):
             if pod_util.is_terminal(p):
                 continue
             if self._is_stuck_terminating(p):

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -60,7 +61,8 @@ class Recorder:
     def __init__(self, sink: Optional[Callable[[Event], None]] = None, clock=time.monotonic):
         self.sink = sink
         self.clock = clock
-        self._seen: Dict[tuple, float] = {}
+        # dedupe key -> the stamp of its last publish, oldest stamp first
+        self._seen: "OrderedDict[tuple, float]" = OrderedDict()
         self._limiters: Dict[str, _TokenBucket] = {}
         self.events: List[Event] = []
         self._lock = threading.Lock()
@@ -88,7 +90,10 @@ class Recorder:
                 )
                 if not limiter.allow():
                     return
+            # a key published again after its TTL goes back to the end, so
+            # the map stays in the order of the stamps it holds
             self._seen[key] = now
+            self._seen.move_to_end(key)
             self._expire(now)
             self.events.append(event)
             if len(self.events) > self.MAX_RETAINED_EVENTS:
@@ -98,13 +103,16 @@ class Recorder:
 
     def _expire(self, now: float) -> None:
         """Evict dedupe entries past the TTL (the reference uses a 120s TTL
-        cache with a janitor; we sweep opportunistically on publish).
+        cache with a janitor; we expire opportunistically on publish).  The
+        map is in stamp order, so the expired entries are its front: the
+        cost follows what expires, not what the map holds.
         Caller holds ``_lock``."""
-        if len(self._seen) < 1024:
-            return
-        expired = [k for k, ts in self._seen.items() if now - ts >= DEDUPE_TTL_SECONDS]
-        for k in expired:
-            del self._seen[k]
+        seen = self._seen
+        while seen:
+            oldest = next(iter(seen))
+            if now - seen[oldest] < DEDUPE_TTL_SECONDS:
+                return
+            del seen[oldest]
 
     def reset(self) -> None:
         with self._lock:

@@ -366,6 +366,11 @@ class ApiServerClient:
     def list_pods(self, namespace: Optional[str] = None, selector=None) -> List[Pod]:
         return self.list(Pod, namespace=namespace, selector=selector)
 
+    def pods_on_node(self, node_name: str) -> List[Pod]:
+        """``list_pods`` filtered by ``spec.nodeName``, read from the pod
+        reflector's field index (KubeClient.pods_on_node's contract)."""
+        return self.reflector(Pod).pods_on_node(node_name)
+
     def get_pod(self, namespace: str, name: str) -> Optional[Pod]:
         return self.get(Pod, name, namespace)
 

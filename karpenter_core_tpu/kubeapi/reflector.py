@@ -29,9 +29,11 @@ import logging
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
+from karpenter_core_tpu.apis.objects import Pod
 from karpenter_core_tpu.chaos import plane as chaos
 from karpenter_core_tpu.kubeapi.resources import ResourceSpec
 from karpenter_core_tpu.metrics import REGISTRY
+from karpenter_core_tpu.operator.kubeclient import PodNodeIndex
 from karpenter_core_tpu.utils import retry
 
 log = logging.getLogger(__name__)
@@ -103,6 +105,10 @@ class Reflector:
         # self-delivery -> apply_event) on the same thread.
         self.dispatch_lock = threading.RLock()
         self.store: Dict[tuple, object] = {}  # key -> decoded object
+        # the pod reflector's spec.nodeName field index (client-go's
+        # AddIndexers on the pod informer); every write to ``store`` goes
+        # through apply_event, the relist's too, so it is kept there
+        self.by_node = PodNodeIndex() if spec.kind is Pod else None
         # per-key applied-resourceVersion high-water marks; deleted keys keep
         # a tombstone so a late watch replay of the pre-delete MODIFIED can't
         # resurrect the object (pruned on relist)
@@ -168,8 +174,12 @@ class Reflector:
                 self.applied_rv[key] = rv
                 if event_type == "DELETED":
                     self.store.pop(key, None)
+                    if self.by_node is not None:
+                        self.by_node.drop(key)
                 else:
                     self.store[key] = obj
+                    if self.by_node is not None:
+                        self.by_node.put(key, obj)
                 callbacks = list(self.callbacks)
             for cb in callbacks:
                 cb(event_type, obj)
@@ -318,6 +328,11 @@ class Reflector:
     def items(self) -> List[Tuple[tuple, object]]:
         with self.lock:
             return list(self.store.items())
+
+    def pods_on_node(self, node_name: str) -> List[object]:
+        """The pod reflector's read of its ``spec.nodeName`` index."""
+        with self.lock:
+            return self.by_node.pods_on_node(node_name)
 
 
 class _Gone(Exception):

@@ -96,17 +96,63 @@ class RateLimiter:
             self._sleep(wait)
 
 
+class PodNodeIndex:
+    """``spec.nodeName`` → the pods bound to it: the informer cache's field
+    index (operator.go:131), so a node's pods are read in O(pods on the node)
+    instead of a walk of every stored pod.  A pod with no node name is not
+    indexed.  Not locked: the owning store mutates and reads it under its own
+    lock (KubeClient._lock, Reflector.lock)."""
+
+    def __init__(self) -> None:
+        self._by_node: Dict[str, Dict[tuple, Pod]] = {}
+        # pod key → the node name it is indexed under.  Both stores hand out
+        # live references and callers bind in place, so by the time a write
+        # arrives the old node name is no longer on the object.
+        self._node_of: Dict[tuple, str] = {}
+
+    def put(self, key: tuple, pod: Pod) -> None:
+        node_name = pod.spec.node_name
+        if self._node_of.get(key) != node_name:
+            self.drop(key)
+        if node_name:
+            self._by_node.setdefault(node_name, {})[key] = pod
+            self._node_of[key] = node_name
+
+    def drop(self, key: tuple) -> None:
+        node_name = self._node_of.pop(key, None)
+        if node_name is None:
+            return
+        pods = self._by_node[node_name]
+        del pods[key]
+        if not pods:
+            del self._by_node[node_name]
+
+    def pods_on_node(self, node_name: str) -> List[Pod]:
+        return list(self._by_node.get(node_name, {}).values())
+
+
 class _Store:
     """One kind's storage: keyed by (namespace, name) or name for cluster scope."""
 
-    def __init__(self, namespaced: bool) -> None:
+    def __init__(self, namespaced: bool, by_node: Optional[PodNodeIndex] = None) -> None:
         self.namespaced = namespaced
         self.objects: Dict[tuple, object] = {}
         self.watchers: List[WatchFunc] = []
+        self.by_node = by_node
 
     def key(self, obj) -> tuple:
         meta = obj.metadata
         return (meta.namespace, meta.name) if self.namespaced else (meta.name,)
+
+    def put(self, key: tuple, obj) -> None:
+        self.objects[key] = obj
+        if self.by_node is not None:
+            self.by_node.put(key, obj)
+
+    def remove(self, key: tuple) -> None:
+        del self.objects[key]
+        if self.by_node is not None:
+            self.by_node.drop(key)
 
 
 class KubeClient:
@@ -118,7 +164,7 @@ class KubeClient:
         self._limiter = RateLimiter(qps, burst, now=self._now, sleep=self._sleep)
         self._lock = threading.RLock()
         self._stores: Dict[type, _Store] = {
-            Pod: _Store(True),
+            Pod: _Store(True, by_node=PodNodeIndex()),
             Node: _Store(False),
             Provisioner: _Store(False),
             Machine: _Store(False),
@@ -161,7 +207,7 @@ class KubeClient:
             obj.metadata.resource_version = self._resource_version
             if not obj.metadata.creation_timestamp:
                 obj.metadata.creation_timestamp = self._now()
-            store.objects[key] = obj
+            store.put(key, obj)
             watchers = list(store.watchers)
         for w in watchers:
             w("ADDED", obj)
@@ -194,7 +240,7 @@ class KubeClient:
                 )
             self._resource_version += 1
             obj.metadata.resource_version = self._resource_version
-            store.objects[key] = obj
+            store.put(key, obj)
             watchers = list(store.watchers)
         for w in watchers:
             w("MODIFIED", obj)
@@ -236,12 +282,15 @@ class KubeClient:
                     stored.metadata.deletion_timestamp = self._now()
                     self._resource_version += 1
                     stored.metadata.resource_version = self._resource_version
+                    # a write like any other: held by its finalizer, the pod
+                    # stays indexed under the node name it carries now
+                    store.put(key, stored)
                     watchers = list(store.watchers)
                     event = ("MODIFIED", stored)
                 else:
                     return
             else:
-                del store.objects[key]
+                store.remove(key)
                 watchers = list(store.watchers)
                 event = ("DELETED", stored)
         for w in watchers:
@@ -289,6 +338,13 @@ class KubeClient:
 
     def list_pods(self, namespace: Optional[str] = None, selector=None) -> List[Pod]:
         return self.list(Pod, namespace=namespace, selector=selector)
+
+    def pods_on_node(self, node_name: str) -> List[Pod]:
+        """The pods whose ``spec.nodeName`` is ``node_name`` as of their last
+        write through this client, terminal ones included, in no particular
+        order — ``list_pods`` filtered by node name, read from the index."""
+        with self._lock:
+            return self._stores[Pod].by_node.pods_on_node(node_name)
 
     def get_pod(self, namespace: str, name: str) -> Optional[Pod]:
         return self.get(Pod, name, namespace)

@@ -24,7 +24,12 @@ from karpenter_core_tpu.utils.clock import Clock
 STATE_NODE_REBUILDS = REGISTRY.counter(
     "karpenter_cluster_state_node_rebuilds_total",
     "State nodes rebuilt from their API object (Cluster.update_node): each "
-    "is a LIST of the store's pods filtered to the node's.",
+    "reads the node's pods through the store's spec.nodeName index.",
+)
+STATE_NODE_REBUILD_PODS = REGISTRY.counter(
+    "karpenter_cluster_state_node_rebuild_pods_total",
+    "Pods the state-node rebuilds read from the store (terminal ones "
+    "included): what a rebuild costs, 0 for a node nothing is bound to.",
 )
 
 TAINT_NODE_NOT_READY = "node.kubernetes.io/not-ready"
@@ -357,9 +362,8 @@ class Cluster:
         return None
 
     def _populate_resource_requests(self, n: StateNode) -> Optional[str]:
-        pods = self.kube_client.list_pods(
-            selector=lambda p: p.spec.node_name == n.node.name
-        )
+        pods = self.kube_client.pods_on_node(n.node.name)
+        STATE_NODE_REBUILD_PODS.inc(len(pods))
         for pod in pods:
             if pod_util.is_terminal(pod):
                 continue

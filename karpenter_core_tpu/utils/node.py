@@ -12,23 +12,17 @@ def get_node_pods(kube_client, *nodes: Node) -> List[Pod]:
     """Reschedulable pods on the nodes: excludes node-owned, daemonset,
     terminal, and terminating pods."""
     pods: List[Pod] = []
-    names = {n.name for n in nodes}
-    for pod in kube_client.list_pods():
-        if pod.spec.node_name not in names:
-            continue
-        if (
-            pod_util.is_owned_by_node(pod)
-            or pod_util.is_owned_by_daemon_set(pod)
-            or pod_util.is_terminal(pod)
-            or pod_util.is_terminating(pod)
-        ):
-            continue
-        pods.append(pod)
+    for name in dict.fromkeys(n.name for n in nodes):
+        for pod in kube_client.pods_on_node(name):
+            if (
+                pod_util.is_owned_by_node(pod)
+                or pod_util.is_owned_by_daemon_set(pod)
+                or pod_util.is_terminal(pod)
+                or pod_util.is_terminating(pod)
+            ):
+                continue
+            pods.append(pod)
     return pods
-
-
-def all_node_pods(kube_client, node: Node) -> List[Pod]:
-    return [p for p in kube_client.list_pods() if p.spec.node_name == node.name]
 
 
 def get_condition(node: Node, condition_type: str):

@@ -155,6 +155,9 @@ def test_each_phase_span_opens_once_a_reconcile_with_its_counts(deployed, traced
     # launch's own update_node and the informer's, a node; the node controller's
     # apply may land inside the span or after it
     assert 2 * nodes <= launch["state_rebuilds"] <= 4 * nodes
+    # ... each a read of the node's own pods through the store's index: a
+    # fresh node has none (the walk this replaced read PODS a rebuild)
+    assert launch["rebuild_pods"] == 0
     # the attribute is the counter's movement while the span was open: once the
     # node controller has caught up the counter stands at three a node
     _wait(deployed.quiet, "the watch controllers drain the launch")

@@ -1,8 +1,20 @@
 """Cluster state suite (modeled on /root/reference/pkg/controllers/state/suite_test.go)."""
 
 from karpenter_core_tpu.apis import labels as labels_api
-from karpenter_core_tpu.testing import make_node, make_pod, make_provisioner
+from karpenter_core_tpu.apis.objects import (
+    ObjectMeta,
+    PersistentVolumeClaim,
+    PersistentVolumeClaimSpec,
+    StorageClass,
+)
+from karpenter_core_tpu.state.cluster import (
+    STATE_NODE_REBUILD_PODS,
+    STATE_NODE_REBUILDS,
+    StateNode,
+)
+from karpenter_core_tpu.testing import make_daemonset_pod, make_node, make_pod, make_provisioner
 from karpenter_core_tpu.testing.harness import make_environment
+from karpenter_core_tpu.utils import pod as pod_util
 
 
 def owned_node(env, name=None, instance_type="default-instance-type", **kwargs):
@@ -276,6 +288,115 @@ class TestAntiAffinityTracking:
             lambda p, n: visited.append((p.name, n.name)) or True
         )
         assert visited == [(pod.name, "late-node")]
+
+
+class TestRebuildReadsTheNodesPodsThroughTheIndex:
+    """Cluster.update_node rebuilds a state node from the pods the store's
+    spec.nodeName index holds for it — the same node the walk of every stored
+    pod built, at the cost of the node's own pods."""
+
+    ELSEWHERE = 5_000
+
+    @staticmethod
+    def _from_the_scan(env, node) -> StateNode:
+        # the path this replaced: LIST every pod, keep the node's
+        expected = StateNode(node, env.kube)
+        for pod in env.kube.list_pods(selector=lambda p: p.spec.node_name == node.name):
+            if not pod_util.is_terminal(pod):
+                expected.update_for_pod(pod)
+        return expected
+
+    @staticmethod
+    def _view(n: StateNode):
+        return (
+            n.pod_requests, n.pod_limits, n.daemonset_requests, n.daemonset_limits,
+            n.host_port_usage().reserved, n.volume_usage().volumes,
+            n.volume_usage().pod_volumes,
+        )
+
+    def _rebuild(self, env, node):
+        """(state node, rebuilds, pods read) of one update_node."""
+        rebuilds0 = STATE_NODE_REBUILDS.labels().value
+        read0 = STATE_NODE_REBUILD_PODS.labels().value
+        assert env.cluster.update_node(node) is None
+        (state_node,) = [n for n in env.cluster.snapshot_nodes() if n.node.name == node.name]
+        return (
+            state_node,
+            STATE_NODE_REBUILDS.labels().value - rebuilds0,
+            STATE_NODE_REBUILD_PODS.labels().value - read0,
+        )
+
+    def test_a_rebuild_reads_the_nodes_pods_and_builds_what_the_scan_built(self):
+        env = make_environment()
+        env.kube.create(make_provisioner())
+        env.kube.create(StorageClass(metadata=ObjectMeta(name="sc", namespace=""), provisioner="ebs"))
+        env.kube.create(
+            PersistentVolumeClaim(
+                metadata=ObjectMeta(name="claim", namespace="default"),
+                spec=PersistentVolumeClaimSpec(storage_class_name="sc"),
+            )
+        )
+        others = [owned_node(env, name=f"other-{i}") for i in range(10)]
+        for i in range(self.ELSEWHERE):
+            env.kube.create(
+                make_pod(requests={"cpu": "10m"}, node_name=others[i % 10].name,
+                         unschedulable=False)
+            )
+        node = owned_node(env, name="the-node")
+        bound = [
+            make_pod(requests={"cpu": 1, "memory": "1Gi"}, limits={"cpu": 2}),
+            make_pod(requests={"cpu": "250m"}, host_ports=[8080, 9090]),
+            make_pod(requests={"cpu": "100m"}, pvcs=["claim"]),
+            make_daemonset_pod(requests={"cpu": "50m"}),
+            make_pod(requests={"memory": "2Gi"}),
+            make_pod(requests={"cpu": 3}, phase="Succeeded"),  # read, not counted
+        ]
+        for pod in bound:
+            pod.spec.node_name = node.name
+            env.kube.create(pod)
+        k = len(bound)
+
+        state_node, rebuilds, read = self._rebuild(env, node)
+        assert (rebuilds, read) == (1, k)  # not k + 5 000
+        assert self._view(state_node) == self._view(self._from_the_scan(env, node))
+        assert state_node.pod_count() == k - 1
+        assert state_node.host_port_usage().reserved[(bound[1].namespace, bound[1].name)]
+        assert state_node.volume_usage().volumes == {"ebs": {"default/claim"}}
+        live = {(p.namespace, p.name) for p in bound[:-1]}
+        assert {key for key, name in env.cluster.bindings.items() if name == node.name} == live
+
+        # a rebind on the live reference, written back: the pod leaves this node
+        bound[0].spec.node_name = others[0].name
+        env.kube.apply(bound[0])
+        state_node, _, read = self._rebuild(env, node)
+        assert read == k - 1
+        assert self._view(state_node) == self._view(self._from_the_scan(env, node))
+        assert (bound[0].namespace, bound[0].name) not in state_node.pod_requests
+        assert env.cluster.bindings[(bound[0].namespace, bound[0].name)] == others[0].name
+        other, _, read = self._rebuild(env, others[0])
+        assert read == self.ELSEWHERE // 10 + 1
+        assert self._view(other) == self._view(self._from_the_scan(env, others[0]))
+
+        # a deletion: the index forgets the pod with the store
+        env.kube.delete(bound[1], force=True)
+        state_node, _, read = self._rebuild(env, node)
+        assert read == k - 2
+        assert self._view(state_node) == self._view(self._from_the_scan(env, node))
+        assert state_node.host_port_usage().reserved == {
+            (p.namespace, p.name): [] for p in bound[2:-1]
+        }
+
+    def test_a_node_nothing_is_bound_to_reads_nothing(self):
+        env = make_environment()
+        env.kube.create(make_provisioner())
+        busy = owned_node(env, name="busy")
+        for _ in range(50):
+            env.kube.create(make_pod(node_name=busy.name, unschedulable=False))
+        read0 = STATE_NODE_REBUILD_PODS.labels().value
+        fresh = owned_node(env, name="fresh")  # the informer rebuilds it on ADDED
+        state_node, _, read = self._rebuild(env, fresh)
+        assert STATE_NODE_REBUILD_PODS.labels().value == read0 and read == 0
+        assert state_node.pod_count() == 0
 
 
 class TestConsolidationStateTriggers:
