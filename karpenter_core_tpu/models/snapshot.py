@@ -1039,6 +1039,10 @@ def encode_snapshot(
         or any(not g.is_zone for g in extra_groups),
         host_ports=bool(snap.cls_ports.any()),
         volume_limits=False,  # refined by TPUSolver.solve_encoded
+        zone_spread_nonself=bool(np.any(
+            (snap.cls_groups[:, 0] < len(snap.groups))
+            & ~snap.grp_member[np.arange(len(classes)), snap.cls_groups[:, 0]]
+        )),
     ).canonical()
 
     if cache_host is not None:
@@ -1305,7 +1309,18 @@ def _populate_class_planes(
             ):
                 if spec is not None:
                     snap.cls_groups[c, slot] = group_index[spec]
-        sp.set(groups=G, **cost)
+        # a member that owns none of its groups' constraints counts in them
+        # all the same: a generic pod in the spread group that selects its label
+        owned = np.zeros((C, G + 1), dtype=bool)
+        owned[np.arange(C)[:, None], snap.cls_groups] = True
+        sp.set(
+            groups=G, **cost,
+            foreign_members=int(np.sum(snap.grp_member[:, :G] & ~owned[:, :G])),
+            host_affinity_groups=sum(
+                1 for spec in group_index
+                if spec.gtype == GRP_AFFINITY and not spec.is_zone
+            ),
+        )
     for c, cls in enumerate(classes):
         requests = dict(cls.requests)
         requests[resources_util.PODS] = 1.0

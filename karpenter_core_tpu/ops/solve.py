@@ -71,6 +71,8 @@ class SnapshotFeatures(NamedTuple):
     inv_host_anti: bool = True
     host_ports: bool = True  # some class binds host ports
     volume_limits: bool = True  # existing nodes carry finite CSI attach limits
+    # some class owns a zonal spread whose selector its own pods do not match
+    zone_spread_nonself: bool = True
 
     def canonical(self) -> "SnapshotFeatures":
         """Normalize implications so equivalent requests share a cache key:
@@ -781,6 +783,7 @@ def _phase(
     max_new_nodes: Optional[int] = None,
     ft: SnapshotFeatures = ALL_FEATURES,
     pref: Optional[jnp.ndarray] = None,
+    open_restrict: Optional[jnp.ndarray] = None,
 ) -> Tuple[NodeState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Place up to ``quota`` pods of the class on nodes whose zone mask meets
     ``zone_restrict`` — first onto open nodes, then fresh nodes from the first
@@ -789,13 +792,16 @@ def _phase(
     for newly opened nodes; ``max_new_nodes`` caps node openings (hostname
     self-affinity bootstraps exactly one, target-fill phases open none);
     ``pref`` (warm repair only) the class's freed-hole counts per slot
-    (``_fill_with_pref``)."""
+    (``_fill_with_pref``); ``open_restrict`` ([N, Z]) gives each open node
+    a restriction of its own in place of ``zone_restrict``, which fresh nodes
+    keep."""
     n_slots = state.used.shape[0]
     n_tmpl = statics.tmpl_it.shape[0]
 
     merged = _merge_node_class(state, cls, statics)
     key_ok = _key_compat_node_class(state, cls, statics)  # [N]
-    zone_ok = state.zone & zone_restrict[None, :] & cls.zone[None, :]  # [N, Z]
+    node_restrict = zone_restrict[None, :] if open_restrict is None else open_restrict
+    zone_ok = state.zone & node_restrict & cls.zone[None, :]  # [N, Z]
     ct_ok = state.ct & cls.ct[None, :]  # [N, CT]
     tol_ok = cls.tol[state.tmpl_id]  # [N]
 
@@ -1225,10 +1231,12 @@ def _class_step(
     placed_total = jnp.int32(0)
 
     def run_phase(state, ex, remaining, quota, restrict, targets_ex=None,
-                  targets_new=None, single_node=False, max_new_nodes=None):
+                  targets_new=None, single_node=False, max_new_nodes=None,
+                  ex_restrict=None, open_restrict=None):
         """Wrapped in lax.cond so zero-quota phases (most of them: each class
         participates in 1-2 of the surviving phase kinds) cost nothing on
-        device."""
+        device.  ``ex_restrict`` / ``open_restrict`` ([N, Z]) stand in for
+        ``restrict`` on existing / open nodes; fresh nodes take ``restrict``."""
 
         def do(operand):
             state_i, ex_i, rem_i = operand
@@ -1236,7 +1244,8 @@ def _class_step(
             extra_new = _and_opt(ok_new, targets_new)
             with jax.named_scope("kc.existing"):
                 ex_o, a_ex, placed_ex = _phase_existing(
-                    ex_i, ex_prep, cls, quota, restrict,
+                    ex_i, ex_prep, cls, quota,
+                    restrict if ex_restrict is None else ex_restrict,
                     extra_elig=extra_ex, single_node=single_node, ft=ft,
                     pref=pref_ex,
                 )
@@ -1248,6 +1257,7 @@ def _class_step(
                     state_i, cls, statics, q_new, restrict,
                     host_cap_new, fresh_host_cap, rem_i, extra_elig=extra_new,
                     max_new_nodes=max_new_nodes, ft=ft, pref=pref_new,
+                    open_restrict=open_restrict,
                 )
             return state_o, ex_o, a_new, a_ex, placed_ex + placed_new, rem_o
 
@@ -1670,12 +1680,29 @@ def _class_step(
 
             # non-self-selecting zone spread: the pod never increments its own
             # group's counts, so the skew formula (count + 0 - min <= maxSkew,
-            # topologygroup.go:155-182) yields a STATIC admissible-zone mask — one
-            # plain phase over it, no per-zone quotas or committal needed
-            min_zs = jnp.min(jnp.where(cls.zone, counts_zs, jnp.int32(1 << 30)))
-            admissible_zs = allowed_zone & (counts_zs - min_zs <= statics.grp_skew[g_zs])
-            q_nm = jnp.where(has_zs & ~member_zs & jnp.any(admissible_zs), m, 0)
-            accumulate(run_phase(state, ex, remaining, q_nm, admissible_zs))
+            # topologygroup.go:155-182) admits a STATIC set of zones — and
+            # upstream narrows the pod's node to ONE of them, the least counted
+            # of the node's own.  A node left multi-zone could later be
+            # committed to a zone the pod was never admitted to (the pod is a
+            # member of OTHER groups, which count it there from then on), so
+            # each open node takes its least counted admissible zone, a fresh
+            # node the least counted of all, an existing node its own
+            if ft.zone_spread_nonself:
+                min_zs = jnp.min(jnp.where(cls.zone, counts_zs, BIGI))
+                admissible_zs = allowed_zone & fillable & (counts_zs - min_zs <= skew_zs)
+                z_nm = jnp.argmin(jnp.where(admissible_zs, counts_zs, BIGI))
+                q_nm = jnp.where(has_zs & ~member_zs & jnp.any(admissible_zs), m, 0)
+                own = state.zone & admissible_zs[None, :]  # [N, Z]
+                z_own = jnp.argmin(jnp.where(own, counts_zs[None, :], BIGI), axis=-1)
+                results_nm = run_phase(
+                    state, ex, remaining, q_nm, jnp.arange(n_zones) == z_nm,
+                    ex_restrict=admissible_zs,
+                    open_restrict=own & (jnp.arange(n_zones)[None, :] == z_own[:, None]),
+                )
+                accumulate(results_nm)
+                spread_suspect = spread_suspect | (
+                    has_zs & ~member_zs & (results_nm[4] < q_nm)
+                )
 
     # -- owned zone anti-affinity: zero-forward-count zones only --------------
     # self-members place one pod per currently-unpoisoned zone, each phase

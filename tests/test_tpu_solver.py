@@ -195,6 +195,23 @@ class TestKernelTopologyParity:
             zone_counts[node.zones[0]] = zone_counts.get(node.zones[0], 0) + len(node.pods)
         assert sorted(zone_counts.values()) == [3, 3, 3]
 
+    def test_zonal_spread_that_does_not_select_itself_commits_its_nodes(self):
+        # upstream narrows such a pod's node to one admissible zone; a node
+        # left multi-zone could be committed later to a zone the pod was never
+        # admitted to, where the groups its labels match count it
+        def pods():
+            watch = TopologySpreadConstraint(
+                max_skew=1, topology_key=ZONE,
+                label_selector=LabelSelector(match_labels={"app": "web"}))
+            return (make_pods(2, labels={"app": "other"}, requests={"cpu": "1"})
+                    + make_pods(3, labels={"app": "watch"}, requests={"cpu": "250m"},
+                                topology_spread=[watch]))
+
+        host, tpu = compare(pods)
+        held = [n for n in tpu.new_nodes
+                if any(p.metadata.labels["app"] == "watch" for p in n.pods)]
+        assert held and all(len(n.zones) == 1 for n in held)
+
     def test_zonal_spread_uneven(self):
         host, tpu = compare(lambda: spread_pods(7))
         zone_counts = {}
