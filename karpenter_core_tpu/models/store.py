@@ -30,7 +30,10 @@ skip the encode when nothing supply-side moved.
 
 from __future__ import annotations
 
+import copy
 import hashlib
+import operator
+from collections.abc import Sequence
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -230,6 +233,10 @@ class SnapshotDelta:
     touched_mask_words: int = 0
     pods_before: int = 0
     pods_after: int = 0
+    # how the diff was reached, not what it says: keys diffed from two
+    # counted memberships, and keys diffed as uid sets
+    counted_classes: int = field(default=0, compare=False)
+    uid_classes: int = field(default=0, compare=False)
 
     @property
     def added_count(self) -> int:
@@ -277,6 +284,181 @@ class SnapshotDelta:
         }
 
 
+class CountedUids(Sequence):
+    """The member uids ``<base>#<j>`` for ``start <= j < stop``: a class's
+    membership carried as its count.  Members of a tenant class are
+    fungible copies of one representative, named by their class digest and
+    their index (``service.snapshot_channel``), so the membership of a class
+    of ``count`` pods IS ``CountedUids(base, count)`` — a prefix — and the
+    diff of two counts is a slice of one of them.  Immutable; equal to the
+    tuple it stands for, and it builds no string until one is asked for."""
+
+    __slots__ = ("base", "_range")
+
+    def __init__(self, base: str, stop: int, start: int = 0) -> None:
+        self.base = base
+        self._range = range(start, max(start, stop))
+
+    @property
+    def start(self) -> int:
+        return self._range.start
+
+    @property
+    def stop(self) -> int:
+        return self._range.stop
+
+    @property
+    def prefix(self) -> bool:
+        """The whole membership of a class (``#0 … #n-1``), not a slice."""
+        return self._range.start == 0 or not self._range
+
+    def position(self, uid) -> Optional[int]:
+        """Where ``uid`` stands in this sequence, or None."""
+        if not isinstance(uid, str):
+            return None
+        base, _, j = uid.rpartition("#")
+        if base != self.base or not (j.isascii() and j.isdigit()):
+            return None
+        n = int(j)
+        if str(n) != j or n not in self._range:
+            return None
+        return n - self._range.start
+
+    def __len__(self) -> int:
+        return len(self._range)
+
+    def __getitem__(self, index):
+        if isinstance(index, slice):
+            r = self._range[index]
+            if r.step != 1:
+                return tuple(f"{self.base}#{j}" for j in r)
+            return CountedUids(self.base, r.stop, r.start)
+        return f"{self.base}#{self._range[index]}"
+
+    def __iter__(self):
+        base = self.base
+        return (f"{base}#{j}" for j in self._range)
+
+    def __eq__(self, other):
+        if isinstance(other, CountedUids):
+            if not self._range and not other._range:
+                return True
+            return self.base == other.base and self._range == other._range
+        if isinstance(other, tuple):
+            return len(other) == len(self) and all(
+                a == b for a, b in zip(self, other)
+            )
+        return NotImplemented
+
+    def __hash__(self) -> int:
+        return hash(tuple(self))
+
+    def __repr__(self) -> str:
+        return f"CountedUids({self.base!r}, {self.stop}, {self.start})"
+
+
+def member_copy(rep, uid: str):
+    """One member of a class: a copy of the representative with its own
+    metadata, differing from it in the uid alone."""
+    pod = copy.copy(rep)
+    pod.metadata = copy.copy(rep.metadata)
+    pod.metadata.uid = uid
+    return pod
+
+
+class CountedPods(Sequence):
+    """A class's ``count`` members as a list-like sequence over its
+    representative: member ``j`` is ``member_copy(rep, "<base>#<j>")``.  A
+    member is copied the first time it is asked for — by index, slice or
+    iteration; a slice or an iteration copies them all — and the same object
+    is handed out after that.  ``uids`` reads the membership without copying
+    anything; ``copied`` counts the copies made."""
+
+    __slots__ = ("rep", "uids", "copied", "_made", "_all")
+
+    def __init__(self, rep, base: str, count: int) -> None:
+        self.rep = rep
+        self.uids = CountedUids(base, count)
+        self.copied = 0
+        self._made: Optional[Dict[int, object]] = {}
+        self._all: Optional[list] = None
+
+    def _copy(self, uid: str):
+        self.copied += 1
+        return member_copy(self.rep, uid)
+
+    def materialize(self) -> list:
+        """Every member, copied once."""
+        if self._all is None:
+            made = self._made
+            self._all = [
+                made[j] if j in made else self._copy(uid)
+                for j, uid in enumerate(self.uids)
+            ]
+            self._made = None
+        return self._all
+
+    def __len__(self) -> int:
+        return len(self.uids)
+
+    def __getitem__(self, index):
+        if self._all is not None or isinstance(index, slice):
+            return self.materialize()[index]
+        index = operator.index(index)
+        uid = self.uids[index]  # IndexError as a list would raise it
+        j = index % len(self.uids)
+        pod = self._made.get(j)
+        if pod is None:
+            pod = self._made[j] = self._copy(uid)
+        return pod
+
+    def __iter__(self):
+        return iter(self.materialize())
+
+
+def members_without(uids, gone):
+    """``uids`` less ``gone``, in order — counted where what is left is
+    still a prefix of a counted membership (``gone`` was its tail), a
+    tuple otherwise."""
+    if isinstance(uids, CountedUids) and uids.prefix:
+        at = {uids.position(u) for u in gone} - {None}
+        left = len(uids) - len(at)
+        if not at or min(at) == left:
+            return uids[:left]
+    drop = set(gone)
+    return tuple(u for u in uids if u not in drop)
+
+
+def members_with(uids, added):
+    """``uids`` then ``added`` — counted where ``added`` continues a counted
+    membership (or starts an empty one) from its count, a tuple otherwise."""
+    if (
+        isinstance(added, CountedUids)
+        and added.start == len(uids)
+        and (not uids or (isinstance(uids, CountedUids) and uids.prefix
+                          and uids.base == added.base))
+    ):
+        return CountedUids(added.base, added.stop)
+    return tuple(uids) + tuple(added)
+
+
+def _counted_diff(prev, cur):
+    """(added, evicted) of one key from two counts, or None where either
+    side is not a counted prefix (an absent or empty side counts 0): a
+    class going from a to b members gains ``#a … #b-1`` or loses ``#b …
+    #a-1`` — what the set arithmetic finds, element for element and in
+    order."""
+    if not (isinstance(cur, CountedUids) and cur.prefix):
+        return None
+    if isinstance(prev, CountedUids):
+        if not (prev.prefix and (not prev or prev.base == cur.base)):
+            return None
+    elif len(prev):
+        return None
+    a, b = len(prev), len(cur)
+    return cur[a:], (prev[b:] if a > b else ())
+
+
 def diff_members(
     prev_members: Dict[tuple, Tuple[str, ...]],
     cur_members: Dict[tuple, Tuple[str, ...]],
@@ -288,16 +470,25 @@ def diff_members(
     delta reconcile uses (class key -> member uids, straight off
     PodIngest.class_members or a prebuilt class list).  Plane-level fields
     (extents, mask words) stay empty: nothing was encoded to measure them;
-    ``supply_changed`` carries the input-digest verdict instead."""
+    ``supply_changed`` carries the input-digest verdict instead.  A key
+    whose two sides are counted memberships (``CountedUids``) is diffed from
+    its two counts; every other key as two uid sets."""
     added: Dict[tuple, Tuple[str, ...]] = {}
     evicted: Dict[tuple, Tuple[str, ...]] = {}
     new_classes = tuple(k for k in cur_members if k not in prev_members)
     removed_classes = tuple(k for k in prev_members if k not in cur_members)
+    counted = 0
     for key, uids in cur_members.items():
-        before = set(prev_members.get(key, ()))
-        now = set(uids)
-        plus = tuple(u for u in uids if u not in before)
-        minus = tuple(u for u in prev_members.get(key, ()) if u not in now)
+        prev = prev_members.get(key, ())
+        by_count = _counted_diff(prev, uids)
+        if by_count is not None:
+            counted += 1
+            plus, minus = by_count
+        else:
+            before = set(prev)
+            now = set(uids)
+            plus = tuple(u for u in uids if u not in before)
+            minus = tuple(u for u in prev if u not in now)
         if plus:
             added[key] = plus
         if minus:
@@ -315,6 +506,8 @@ def diff_members(
         changed_planes=tuple(supply_changed),
         pods_before=sum(len(u) for u in prev_members.values()),
         pods_after=sum(len(u) for u in cur_members.values()),
+        counted_classes=counted,
+        uid_classes=len(cur_members) - counted,
     )
 
 

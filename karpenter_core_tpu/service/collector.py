@@ -100,8 +100,12 @@ class CollectorPolicy:
         """Install on the first holder; returns this holder's one-shot release."""
         with self._lock:
             self._holders += 1
-            if self._holders == 1:
-                self._install()
+            housekeeping = self._install() if self._holders == 1 else None
+        if housekeeping is not None:
+            # started with the lock free: ``start()`` waits for the new
+            # thread, and a collection there may finalize a sidecar dropped
+            # unstopped, whose release takes the lock — held, a deadlock
+            housekeeping.start()
         held = True
 
         def release() -> None:
@@ -122,16 +126,18 @@ class CollectorPolicy:
         with self._lock:
             return self._holders > 0
 
-    def _install(self) -> None:
+    def _install(self) -> threading.Thread:
+        """Thresholds and hook; returns the housekeeping thread, unstarted
+        (a release before it starts has already set its stop event)."""
         young, middle, self._found_full_threshold = gc.get_threshold()
         gc.set_threshold(young, middle, FULL_THRESHOLD_OUT_OF_REACH)
         gc.callbacks.append(self._on_pass)
         self._mark_full()
         self._stop = threading.Event()
-        threading.Thread(
+        return threading.Thread(
             target=self._housekeeping, args=(self._stop,),
             name="kc-collector", daemon=True,
-        ).start()
+        )
 
     def _uninstall(self) -> None:
         self._stop.set()

@@ -39,7 +39,6 @@ controller's solver breaker + degraded mode absorb it.
 
 from __future__ import annotations
 
-import copy
 import json
 import logging
 import os
@@ -1091,38 +1090,35 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
 
     # -- the tenant path (docs/SERVICE.md) ------------------------------------
 
-    @staticmethod
-    def _materialize_class(rep, count: int, uid_base: str) -> List:
-        """``count`` bookkeeping copies of the class representative with
-        distinct, REQUEST-STABLE uids (``<class-digest>#<j>``).  Pods within
-        an equivalence class are fungible, so synthetic member identities
-        capture count deltas exactly — the per-tenant incremental session
-        diffs successive requests' memberships without per-pod uids ever
-        crossing the wire (the O(classes) win stays)."""
-        pods = []
-        for j in range(count):
-            pod = copy.copy(rep)
-            pod.metadata = copy.copy(rep.metadata)
-            pod.metadata.uid = f"{uid_base}#{j}"
-            pods.append(pod)
-        return pods
-
     @classmethod
     def _decode_tenant_classes(cls_, req):
         """(classes, uid_base -> request class index, decode_common tail).
+
+        A class's ``pods`` is a ``models.store.CountedPods``: ``count``
+        bookkeeping members of the representative with distinct,
+        REQUEST-STABLE uids (``<class-digest>#<j>``), copied only when one is
+        asked for.  Pods within an equivalence class are fungible, so
+        synthetic member identities capture count deltas exactly — the
+        per-tenant incremental session diffs successive requests' counted
+        memberships without per-pod uids ever crossing the wire, and copies
+        only the members a delta places.
 
         A classmethod so the fleet checkpoint restore (fleet/checkpoint.py
         restore_session) can re-decode a checkpointed anchor request without
         a service instance — the adopting replica re-derives classes and
         synthetic uids from the same bytes the serving replica encoded."""
         from karpenter_core_tpu.models.snapshot import build_pod_ladder
-        from karpenter_core_tpu.models.store import class_key, stable_digest
+        from karpenter_core_tpu.models.store import (
+            CountedPods,
+            class_key,
+            stable_digest,
+        )
 
         entries = req.get("podClasses", [])
         classes = []
         copies: List[tuple] = []  # (count, uid base) per class, for pass two
         uid_class: Dict[str, int] = {}
-        # two passes, so that ONE span covers every class's copies: the
+        # two passes, so that ONE span covers every class's members: the
         # representatives, ladders, digests and the duplicate check first
         for i, entry in enumerate(entries):
             rep = codec.pod_from_dict(entry["pod"])
@@ -1133,16 +1129,27 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
             # membership bookkeeping — keyed by these synthetic uids — reads
             # back identically on the replica that adopts the tenant
             # (fleet/checkpoint.py); same-process lineages see no change
-            uid_base = stable_digest(class_key(cls))[:16]
+            key = class_key(cls)
+            uid_base = stable_digest(key)[:16]
             if uid_base in uid_class:
                 raise ValueError(f"duplicate pod class at index {i}")
             uid_class[uid_base] = i
-            copies.append((int(entry["count"]), uid_base))
+            count = int(entry["count"])
+            if count:
+                # a member's signature is its representative's (they differ
+                # in the uid alone), so the session keys the class without
+                # copying member #0; an empty class has no member to key by,
+                # as before
+                cls.interned_sig = key
+            copies.append((count, uid_base))
             classes.append(cls)
+        # copies: the pods the classes stand for; materialized: the pods
+        # copied here (none: a member is copied where it is asked for)
         with tracing.span("service.materialize", classes=len(classes),
-                          copies=sum(count for count, _ in copies)):
+                          copies=sum(count for count, _ in copies)) as sp:
             for cls, (count, uid_base) in zip(classes, copies):
-                cls.pods = cls_._materialize_class(cls.pods[0], count, uid_base)
+                cls.pods = CountedPods(cls.pods[0], uid_base, count)
+            sp.set(materialized=sum(cls.pods.copied for cls in classes))
         provisioners, daemonset_pods, state_nodes, bound, resolver, _ = (
             cls_._decode_common(req)
         )

@@ -356,7 +356,10 @@ class IncrementalSolveSession:
         """(class key -> uids, uid -> Pod getter, classes-or-None) from a
         PodIngest or a prebuilt PodClass list — riding the ingest's
         bookkeeping, no signature re-derivation per pod and no per-pod
-        materialization (the getter resolves only the delta's uids)."""
+        materialization (the getter resolves only the delta's uids).  A
+        class whose pods are counted (``CountedPods``, the tenant wire)
+        gives its ``CountedUids`` and copies a member only when the getter
+        asks for it."""
         from karpenter_core_tpu.models.columnar import PodIngest
 
         if isinstance(pods_or_classes, PodIngest):
@@ -364,13 +367,20 @@ class IncrementalSolveSession:
         classes = list(pods_or_classes)
         members = {}
         by_uid = {}
+        counted = {}
         for cls in classes:
             if getattr(cls, "is_ladder_variant", False):
                 continue
             key = class_key(cls)
+            if isinstance(cls.pods, store_mod.CountedPods):
+                members[key] = cls.pods.uids
+                counted[cls.pods.uids.base] = cls.pods
+                continue
             members[key] = tuple(p.uid for p in cls.pods)
             for p in cls.pods:
                 by_uid[p.uid] = p
+        if counted:
+            return members, _MemberResolver(by_uid, counted), classes
         return members, by_uid.get, classes
 
     # -- the solve entry -------------------------------------------------------
@@ -481,6 +491,8 @@ class IncrementalSolveSession:
                 diff_sp.set(
                     arrivals=delta.added_count, departures=delta.evicted_count,
                     dirty_classes=len(set(delta.added) | set(delta.evicted)),
+                    counted_classes=delta.counted_classes,
+                    uid_classes=delta.uid_classes,
                 )
 
         try:
@@ -546,6 +558,8 @@ class IncrementalSolveSession:
         them so their binding doesn't read as a supply change.  Genuinely
         foreign bound pods still flip the supply digest ⇒ full solve."""
         w = self._warm
+        if not bound_uids:
+            return
         moved = [uid for uid in w.pod_loc if uid in bound_uids]
         if not moved:
             return
@@ -557,8 +571,9 @@ class IncrementalSolveSession:
                 trimmed.setdefault(key, []).append(uid)
             w.materialized.add(uid)
         for key, uids in trimmed.items():
-            gone = set(uids)
-            left = tuple(u for u in w.members.get(key, ()) if u not in gone)
+            # counted while the bound pods were the class's tail; a pod that
+            # bound out of the middle leaves a uid tuple
+            left = store_mod.members_without(w.members.get(key, ()), uids)
             if left:
                 w.members[key] = left
             else:
@@ -762,7 +777,7 @@ class IncrementalSolveSession:
                 # a pod that joined on a DELTA tick after the anchor: absent
                 # from the anchor snapshot, but class members are fungible
                 # copies of the representative differing only in uid
-                # (service tenant path, _materialize_class) — rebuild it
+                # (service tenant path, models.store.CountedPods) — rebuild it
                 root = root_of[int(row)] if int(row) < len(root_of) else -1
                 reps = (snapshot.classes[root].pods
                         if 0 <= root < len(snapshot.classes) else ())
@@ -771,9 +786,7 @@ class IncrementalSolveSession:
                         f"checkpointed failed pod {uid!r} has no class "
                         f"representative in the re-encoded anchor snapshot"
                     )
-                pod = copy.copy(reps[0])
-                pod.metadata = copy.copy(reps[0].metadata)
-                pod.metadata.uid = uid
+                pod = store_mod.member_copy(reps[0], uid)
             failed_pods[uid] = (int(row), pod)
         member_rows, own_inv_rows = _topology_rows(prep)
         if pipeline_mod.pipeline_enabled():
@@ -906,17 +919,18 @@ class IncrementalSolveSession:
         for row, pods in pods_by_root.items():
             counts[row] = len(pods)
 
-        # membership after this tick lands: previous minus evicted plus added
-        members = {k: list(v) for k, v in w.members.items()}
+        # membership after this tick lands: previous minus evicted plus added,
+        # a counted membership staying counted while it is a prefix
+        members = dict(w.members)
         for key, uids in delta.evicted.items():
-            gone = set(uids)
             if key in members:
-                members[key] = [u for u in members[key] if u not in gone]
+                members[key] = store_mod.members_without(members[key], uids)
         for key, uids in delta.added.items():
-            members.setdefault(key, []).extend(uids)
-        members_after = {k: tuple(v) for k, v in members.items() if v}
+            members[key] = store_mod.members_with(members.get(key, ()), uids)
+        members_after = {k: v for k, v in members.items() if v}
         tracing.set_attrs(evictions=len(evicted_locs), dirty_rows=len(pods_by_root),
-                          placements=int(counts.sum()))
+                          placements=int(counts.sum()),
+                          resolved=getattr(by_uid, "resolved", 0))
         return {
             "delta": delta, "free_new": free_new, "free_ex": free_ex,
             "evicted_locs": evicted_locs, "pods_by_root": pods_by_root,
@@ -1373,6 +1387,31 @@ class IncrementalSolveSession:
         return node_signature_of(w.assign, keys) + node_signature_of(
             w.assign_ex, keys
         )
+
+
+class _MemberResolver:
+    """uid -> Pod over one request's classes: a listed class answers from
+    its pods, a counted class (``CountedPods``) copies the member
+    ``<base>#<j>`` asked for and nothing else.  ``resolved`` counts the
+    members taken from counted classes."""
+
+    __slots__ = ("by_uid", "counted", "resolved")
+
+    def __init__(self, by_uid, counted) -> None:
+        self.by_uid = by_uid  # uid -> Pod of the listed classes
+        self.counted = counted  # uid base -> CountedPods
+        self.resolved = 0
+
+    def __call__(self, uid):
+        pod = self.by_uid.get(uid)
+        if pod is not None:
+            return pod
+        pods = self.counted.get(uid.rpartition("#")[0])
+        j = pods.uids.position(uid) if pods is not None else None
+        if j is None:
+            return None
+        self.resolved += 1
+        return pods[j]
 
 
 _WINDOW_MIN = 256

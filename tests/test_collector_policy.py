@@ -380,6 +380,34 @@ def test_the_root_span_carries_the_full_passes_that_began_inside_it(
     assert root["attrs"]["request_bytes"] > 0
 
 
+def test_the_housekeeping_thread_starts_with_the_lock_free(own_collector, monkeypatch):
+    """``Thread.start()`` waits for the new thread, and a collection inside
+    that thread may finalize a sidecar dropped unstopped, whose release takes
+    the policy's lock: started under the lock, the two wait on each other."""
+    import threading
+
+    policy, _, _ = own_collector
+    free_at_start = []
+    start = threading.Thread.start
+
+    def checked_start(thread):
+        if thread.name == "kc-collector":
+            other = threading.Thread(target=lambda: free_at_start.append(
+                policy._lock.acquire(timeout=5) and policy._lock.release() is None))
+            start(other)
+            other.join(10)
+        return start(thread)
+
+    monkeypatch.setattr(threading.Thread, "start", checked_start)
+    release = policy.acquire()
+    try:
+        assert free_at_start == [True]
+        assert policy.installed
+    finally:
+        release()
+    assert not policy.installed
+
+
 def test_a_sidecar_dropped_unstopped_is_released_by_the_pass_that_frees_it(own_collector):
     """``weakref.finalize`` on the server: the paced pass that collects it runs
     the release from inside the pass, under the policy's own lock."""

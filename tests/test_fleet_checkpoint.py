@@ -271,6 +271,47 @@ class TestRestore:
         assert strip(r3) == strip(u3)
         _stop(server2, client2)
 
+    def test_counted_lineage_checkpoints_the_materialized_rows(self, tmp_path):
+        """The tenant path keeps a class's members as a count; its
+        checkpoint still writes every member uid ``<base>#<j>``, sorted, as
+        the lineage of copied members did — and the lineage restored from
+        those rows (uid tuples) shrinks on its next delta tick exactly as
+        the uninterrupted, counted one does."""
+        provider = FakeCloudProvider()
+        fleet1 = _fleet(tmp_path, rid="r1", ckpt_every=1)
+        server1, client1 = _serve(provider, fleet=fleet1)
+        r1 = _solve(client1, "acme", count=8)
+        r2 = _solve(client1, "acme", count=10,
+                    version=r1["tenant"]["sessionVersion"])
+        assert r2["tenant"]["solveMode"] == "delta"
+        ckpt, status = server1.kc_service._ckpt.load("acme")
+        assert status == ckpt_mod.STATUS_OK
+        (base,) = ckpt.header["uid_bases"]
+        assert [[int(row), list(uids)]
+                for row, uids in ckpt.tensors["members_rows"]] == [
+            [0, sorted(f"{base}#{j}" for j in range(10))]]
+        server1.kc_service.drain(timeout_s=5.0)
+        _stop(server1, client1)
+
+        server_u, client_u = _serve(provider)
+        u1 = _solve(client_u, "acme", count=8)
+        u2 = _solve(client_u, "acme", count=10,
+                    version=u1["tenant"]["sessionVersion"])
+        u3 = _solve(client_u, "acme", count=9,
+                    version=u2["tenant"]["sessionVersion"])
+        _stop(server_u, client_u)
+
+        server2, client2 = _serve(
+            provider, fleet=_fleet(tmp_path, rid="r2", ckpt_every=1))
+        r3 = _solve(client2, "acme", count=9,
+                    version=r2["tenant"]["sessionVersion"])
+        assert r3["tenant"]["solveMode"] == "delta"
+        assert r3["tenant"]["recovered"] == "warm"
+        strip = lambda r: {k: v for k, v in r.items() if k != "tenant"}  # noqa: E731
+        assert strip(r3) == strip(u3)
+        assert r3["tenant"]["sessionVersion"] == u3["tenant"]["sessionVersion"]
+        _stop(server2, client2)
+
     def test_stale_checkpoint_downgrades_to_replay(self, tmp_path):
         """A checkpoint older than the journal tail must NOT restore — the
         recovery rung demands full lineage-state equality and falls to

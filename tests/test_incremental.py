@@ -124,6 +124,96 @@ class TestSnapshotStore:
             assert delta.pods_before == sum(len(u) for u in prev.values())
             assert delta.pods_after == sum(len(u) for u in cur.values())
 
+    @pytest.mark.parametrize("prev_spec, cur_spec, by_count", [
+        (("c", 3), ("c", 7), True),     # counted up
+        (("c", 7), ("c", 2), True),     # counted down
+        (("c", 5), ("c", 5), True),     # counted, equal
+        (("c", 3), ("c", 0), True),     # counted down to nothing
+        (None, ("c", 4), True),         # from an absent key
+        (("c", 4), None, False),        # to an absent key: removed, not diffed
+        (("t", 3), ("c", 5), False),    # tuple then counted
+        (("c", 5), ("t", 3), False),    # counted then tuple
+        (("t", 4), ("t", 4), False),    # tuple vs tuple, unchanged
+        (("gap", 4), ("c", 4), False),  # a tuple that is no prefix
+    ])
+    def test_diff_members_by_count_matches_the_set_diff(
+            self, prev_spec, cur_spec, by_count):
+        """A key whose two sides are counted memberships is diffed from the
+        two counts; the delta is the one the set arithmetic finds on the
+        materialized tuples, uid for uid and in order."""
+        key, other = (("k",),), (("z",),)
+
+        def side(spec):
+            out = {other: ("x#0", "x#1")}  # a listed class beside it
+            if spec is None:
+                return out
+            kind, n = spec
+            counted = store_mod.CountedUids("b", n)
+            out[key] = {"c": counted, "t": tuple(counted),
+                        "gap": tuple(counted)[:1] + tuple(counted)[2:]}[kind]
+            return out
+
+        prev, cur = side(prev_spec), side(cur_spec)
+        as_tuples = lambda m: {k: tuple(v) for k, v in m.items()}  # noqa: E731
+        got = diff_members(prev, cur, from_version=3)
+        want = diff_members(as_tuples(prev), as_tuples(cur), from_version=3)
+        assert got == want
+        for field in ("added", "evicted"):
+            assert [(k, tuple(v)) for k, v in getattr(got, field).items()] == \
+                [(k, tuple(v)) for k, v in getattr(want, field).items()]
+        assert (got.counted_classes, got.uid_classes) == (
+            int(by_count), len(cur) - int(by_count))
+        assert want.counted_classes == 0
+        if by_count:
+            assert all(isinstance(v, store_mod.CountedUids)
+                       for d in (got.added, got.evicted)
+                       for k, v in d.items() if k == key)
+        assert got.apply(as_tuples(prev)) == want.apply(as_tuples(prev))
+
+    @pytest.mark.parametrize("n, gone, added, counted_after", [
+        (6, [5, 4], [], True),       # the tail leaves: still a prefix
+        (6, [2], [], False),         # one out of the middle: a tuple
+        (6, [], [6, 7], True),       # arrivals continue the count
+        (6, [5], [6], False),        # a gap below the arrival: a tuple
+        (0, [], [0, 1], True),       # an empty membership starts counting
+        (6, [0, 1, 2, 3, 4, 5], [], True),  # everything leaves
+        (6, [9], [], True),          # a uid that was never a member
+    ])
+    def test_counted_membership_stays_counted_while_a_prefix(
+            self, n, gone, added, counted_after):
+        uids = store_mod.CountedUids("b", n)
+        names = lambda js: tuple(f"b#{j}" for j in js)  # noqa: E731
+        left = store_mod.members_without(uids, names(gone))
+        arrivals = (store_mod.CountedUids("b", added[-1] + 1, added[0])
+                    if added else ())
+        after = store_mod.members_with(left, arrivals) if added else left
+        drop = set(names(gone))
+        assert after == tuple(u for u in uids if u not in drop) + names(added)
+        assert isinstance(after, store_mod.CountedUids) == counted_after
+
+    def test_counted_uids_read_as_the_tuple_they_stand_for(self):
+        uids = store_mod.CountedUids("b", 5)
+        plain = tuple(f"b#{j}" for j in range(5))
+        assert uids == plain and plain == uids and hash(uids) == hash(plain)
+        assert list(uids) == list(plain) and len(uids) == 5
+        assert uids[1] == "b#1" and uids[-1] == "b#4"
+        assert uids[2:] == plain[2:] and uids[::2] == plain[::2]
+        assert isinstance(uids[2:], store_mod.CountedUids)
+        assert uids[3:1] == () and not uids[3:1]
+        assert "b#4" in uids and "b#5" not in uids and "b#04" not in uids
+        assert "c#1" not in uids and uids.index("b#3") == 3
+        assert uids != plain[:4] and uids != list(plain)
+        with pytest.raises(IndexError):
+            uids[5]
+        pods = store_mod.CountedPods(make_pod(requests={"cpu": "1"}), "b", 3)
+        assert pods.copied == 0 and len(pods) == 3
+        first = pods[0]
+        assert first.uid == "b#0" and pods[0] is first and pods.copied == 1
+        assert [p.uid for p in pods] == list(store_mod.CountedUids("b", 3))
+        assert pods[0] is first and pods.copied == 3
+        with pytest.raises(IndexError):
+            store_mod.CountedPods(first, "b", 0)[0]
+
     def test_diff_snapshots_structure(self):
         solver = _solver()
         store = SnapshotStore()

@@ -6,6 +6,7 @@ its class count — each carrying the count of the work it did; and the trace
 context stamped into the tenant envelope stays the CALLER's."""
 
 import collections
+import copy
 
 import msgpack
 import pytest
@@ -140,13 +141,18 @@ def test_tenant_ticks_open_the_same_spans_whatever_their_class_count(
     assert _phase_counts(full) == TENANT_FULL
     assert _phase_counts(delta) == TENANT_DELTA
     assert _one(full, "service.materialize")["attrs"] == {
-        "classes": n_classes, "copies": 10 * n_classes, "tenant": "acme"}
+        "classes": n_classes, "copies": 10 * n_classes, "materialized": 0,
+        "tenant": "acme"}
     assert _one(delta, "service.materialize")["attrs"]["copies"] == 9 * n_classes
     diff = _one(delta, "session.diff")["attrs"]
     assert (diff["arrivals"], diff["departures"], diff["dirty_classes"]) == (
         0, n_classes, n_classes)
     assert diff["solve.mode"] == "delta" and diff["classes"] == n_classes
+    # both ticks' memberships are counts: every class diffed by count
+    assert (diff["counted_classes"], diff["uid_classes"]) == (n_classes, 0)
     assert _one(delta, "session.plan")["attrs"]["evictions"] == n_classes
+    # departures only: no member was copied for the tick
+    assert _one(delta, "session.plan")["attrs"]["resolved"] == 0
     assert _one(delta, "session.adopt")["attrs"]["evicted"] == n_classes
     assert _one(full, "session.adopt")["attrs"]["placed"] == 10 * n_classes
     # the phases nest where the code does: the session's under solve.tenant,
@@ -209,8 +215,21 @@ def test_a_callers_span_still_reaches_the_envelope(traced, channel):
         assert by_name[name]["parentId"] == caller.span_id
 
 
+def _materialize_class(rep, count: int, uid_base: str) -> list:
+    """The service's copies of a class representative as they were made
+    before members became counted: ``count`` copies, uid ``<base>#<j>``."""
+    pods = []
+    for j in range(count):
+        pod = copy.copy(rep)
+        pod.metadata = copy.copy(rep.metadata)
+        pod.metadata.uid = f"{uid_base}#{j}"
+        pods.append(pod)
+    return pods
+
+
 def _one_pass_decode(req):
-    """``_decode_tenant_classes`` as it stood before it became two passes."""
+    """``_decode_tenant_classes`` as it stood before it became two passes,
+    every member copied."""
     from karpenter_core_tpu.models.snapshot import build_pod_ladder
     from karpenter_core_tpu.models.store import class_key, stable_digest
     from karpenter_core_tpu.apis import codec
@@ -224,8 +243,7 @@ def _one_pass_decode(req):
         if uid_base in uid_class:
             raise ValueError(f"duplicate pod class at index {i}")
         uid_class[uid_base] = i
-        cls.pods = SnapshotSolverService._materialize_class(
-            rep, int(entry["count"]), uid_base)
+        cls.pods = _materialize_class(rep, int(entry["count"]), uid_base)
         classes.append(cls)
     return classes, uid_class
 
@@ -246,12 +264,27 @@ def test_two_pass_tenant_decode_returns_what_one_pass_did():
     assert got_uids == want_uids and list(got_uids) == list(want_uids)
     assert len(got_classes) == len(want_classes) == 8
     for got, want in zip(got_classes, want_classes):
-        assert [p.uid for p in got.pods] == [p.uid for p in want.pods]
+        pods = list(got.pods)
+        assert [p.uid for p in pods] == [p.uid for p in want.pods]
         assert got.requests == want.requests
         # copies, never the representative itself, each with its own metadata
-        assert len({id(p) for p in got.pods}) == len(got.pods)
-        assert len({id(p.metadata) for p in got.pods}) == len(got.pods)
-    assert got_classes[-1].pods == []
+        assert len({id(p) for p in pods}) == len(pods)
+        assert len({id(p.metadata) for p in pods}) == len(pods)
+        assert all(p is not got.pods.rep for p in pods)
+    assert list(got_classes[-1].pods) == []
+
+
+def test_tenant_decode_copies_nothing(traced):
+    """The decode stands every class's members for their count: the span
+    says how many pods the classes represent, and that it copied none."""
+    counts = [3, 0, 5, 1]
+    req = _tenant_request([(make_pod(requests={"cpu": f"{100 + c}m"}), n)
+                           for c, n in enumerate(counts)])
+    classes = SnapshotSolverService._decode_tenant_classes(req)[0]
+    assert _one(_drain(), "service.materialize")["attrs"] == {
+        "classes": 4, "copies": sum(counts), "materialized": 0}
+    assert [len(cls.pods) for cls in classes] == counts
+    assert [cls.pods.copied for cls in classes] == [0] * 4
 
 
 @pytest.mark.parametrize("bad, error", [
