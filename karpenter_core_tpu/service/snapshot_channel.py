@@ -50,6 +50,7 @@ from typing import Dict, List, Optional
 
 import grpc
 import msgpack
+import numpy as np
 
 from karpenter_core_tpu import chaos, tracing
 from karpenter_core_tpu import metrics as metrics_mod
@@ -58,7 +59,7 @@ from karpenter_core_tpu.models.snapshot import KernelUnsupported
 from karpenter_core_tpu.service import collector
 from karpenter_core_tpu.service import journal as journal_mod
 from karpenter_core_tpu.service import tenant as tenant_mod
-from karpenter_core_tpu.solver.tpu import TPUSolver
+from karpenter_core_tpu.solver.tpu import TPUNodeDecision, TPUSolver
 from karpenter_core_tpu.state.cluster import StateNode
 from karpenter_core_tpu.utils.watchdog import SolveTimeout
 
@@ -132,6 +133,114 @@ class _WireVolumeResolver:
                 metadata=ObjectMeta(name=name), provisioner=name[len(self._PREFIX):]
             )
         return None
+
+
+class _Packed:
+    """A reply value msgpack-encoded already: ``_packb`` writes its bytes
+    where the value stands.  Not ``bytes`` itself, so one left where the
+    encoder does not look raises in ``Packer.pack`` instead of going out as
+    a bin."""
+
+    __slots__ = ("data", "names")
+
+    def __init__(self, data: bytes, names: int) -> None:
+        self.data = data
+        self.names = names
+
+
+class _Axis:
+    """One snapshot axis (``it_names``, ``zones``, ``capacity_types``) as a
+    reply writes it: each name packed once, each distinct (row, first name)
+    joined once — rows repeat across nodes, the zone and capacity-type rows
+    nearly always."""
+
+    __slots__ = ("names", "packer", "packed", "position", "joined")
+
+    def __init__(self, names, packer) -> None:
+        self.names = names
+        self.packer = packer
+        self.packed = np.empty(len(names), dtype=object)
+        self.packed[:] = [packer.pack(name) for name in names]
+        self.position: Optional[Dict[str, int]] = None
+        self.joined: Dict[tuple, _Packed] = {}
+
+    def splice(self, row, first: Optional[str] = None) -> _Packed:
+        key = (row.tobytes(), first)
+        spliced = self.joined.get(key)
+        if spliced is None:
+            spliced = self.joined[key] = self._join(row, first)
+        return spliced
+
+    def _join(self, row, first: Optional[str]) -> _Packed:
+        picked = np.flatnonzero(row)
+        parts = self.packed[picked].tolist()
+        if first is not None:
+            if self.position is None:
+                self.position = {name: i for i, name in enumerate(self.names)}
+            at = self.position.get(first)
+            if at is not None and row[at]:
+                parts = [self.packed[at]] + self.packed[picked[picked != at]].tolist()
+        header = self.packer.pack_array_header(len(parts))
+        return _Packed(header + b"".join(parts), len(parts))
+
+
+class _NameLists:
+    """One reply's new-node name lists, written from the decisions' plane
+    rows: a node's list is an array header and one join of the packed names
+    its bool row selects, the policy's chosen type first — byte for byte what
+    ``msgpack.packb`` gives for ``TPUNodeDecision.instance_type_names`` /
+    ``zones`` / ``capacity_types``, with no ``str`` made per name."""
+
+    def __init__(self) -> None:
+        self._packer = msgpack.Packer()
+        self._axes: Dict[int, _Axis] = {}  # id(names) -> its axis
+        self.spliced_nodes = 0
+        self.type_names = 0
+        self.type_rows = 0
+
+    def _axis(self, names) -> _Axis:
+        axis = self._axes.get(id(names))
+        if axis is None:
+            axis = self._axes[id(names)] = _Axis(names, self._packer)
+        return axis
+
+    def of(self, node) -> tuple:
+        """(instanceTypes, zones, capacityTypes) of one new node: spliced
+        from its plane rows where it has them, its plain lists otherwise."""
+        if not isinstance(node, TPUNodeDecision):
+            return node.instance_type_names, node.zones, node.capacity_types
+        (it_names, viable), (zones, zone), (cts, ct) = node.name_rows()
+        selected = node.selected
+        axis = self._axis(it_names)
+        rows = len(axis.joined)
+        types = axis.splice(
+            viable, selected["instance_type"] if selected is not None else None)
+        self.spliced_nodes += 1
+        self.type_names += types.names
+        self.type_rows += len(axis.joined) - rows
+        if selected is not None:
+            return types, [selected["zone"]], [selected["capacity_type"]]
+        return types, self._axis(zones).splice(zone), self._axis(cts).splice(ct)
+
+
+def _packb(response: Dict) -> bytes:
+    """``msgpack.packb(response)`` byte for byte, each ``_Packed`` written as
+    it stands.  The payloads put one only in the dicts of a list that is a
+    value of ``response`` (``newNodes``); every other value is packed whole."""
+    packer = msgpack.Packer()
+    out = [packer.pack_map_header(len(response))]
+    for key, value in response.items():
+        out.append(packer.pack(key))
+        if type(value) is not list or not all(type(e) is dict for e in value):
+            out.append(packer.pack(value))
+            continue
+        out.append(packer.pack_array_header(len(value)))
+        for entry in value:
+            out.append(packer.pack_map_header(len(entry)))
+            for k, v in entry.items():
+                out.append(packer.pack(k))
+                out.append(v.data if type(v) is _Packed else packer.pack(v))
+    return b"".join(out)
 
 
 class SnapshotSolverService(grpc.GenericRpcHandler):
@@ -963,19 +1072,26 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
         """The SolveClasses response body for one TPUSolveResults;
         ``class_counts(pods) -> [(class_index, count)]`` supplies the
         caller's pod→request-class mapping (identity-based on the stateless
-        path, uid-based on the tenant path)."""
+        path, uid-based on the tenant path).  A new node's three name lists
+        are ``_Packed`` where the node has plane rows (``_NameLists``), so
+        the body goes out through ``_pack_reply``; the counts of the work
+        land on the span the call runs under."""
+        lists = _NameLists()
+        new_nodes = []
+        for n in results.new_nodes:
+            types, zones, capacity_types = lists.of(n)
+            new_nodes.append({
+                "provisioner": n.provisioner_name,
+                "instanceTypes": types,
+                "zones": zones,
+                "capacityTypes": capacity_types,
+                "requests": n.requests,
+                "classCounts": class_counts(n.pods),
+            })
+        tracing.set_attrs(nodes=len(new_nodes), spliced_nodes=lists.spliced_nodes,
+                          type_names=lists.type_names, type_rows=lists.type_rows)
         return {
-            "newNodes": [
-                {
-                    "provisioner": n.provisioner_name,
-                    "instanceTypes": n.instance_type_names,
-                    "zones": n.zones,
-                    "capacityTypes": n.capacity_types,
-                    "requests": n.requests,
-                    "classCounts": class_counts(n.pods),
-                }
-                for n in results.new_nodes
-            ],
+            "newNodes": new_nodes,
             "existingAssignments": {
                 name: class_counts(placed)
                 for name, placed in results.existing_assignments.items()
@@ -1025,7 +1141,7 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
     @staticmethod
     def _pack_reply(response: Dict) -> bytes:
         with tracing.span("service.pack") as sp:
-            reply = msgpack.packb(response)
+            reply = _packb(response)
             sp.set(reply_bytes=len(reply))
         return reply
 
@@ -1076,9 +1192,8 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     counts[i] = counts.get(i, 0) + 1
                 return sorted(counts.items())
 
-            with tracing.span("service.payload") as sp:
+            with tracing.span("service.payload"):
                 response = self._classes_payload(results, class_counts)
-                sp.set(nodes=len(response["newNodes"]))
             return self._pack_reply(response)
         except KernelUnsupported as e:
             context.abort(grpc.StatusCode.FAILED_PRECONDITION, f"kernel unsupported: {e}")
@@ -1375,7 +1490,7 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     counts[i] = counts.get(i, 0) + 1
                 return sorted(counts.items())
 
-            with tracing.span("service.payload") as sp:
+            with tracing.span("service.payload"):
                 response = self._classes_payload(results, class_counts)
                 response["tenant"] = {
                     "id": tid,
@@ -1388,7 +1503,6 @@ class SnapshotSolverService(grpc.GenericRpcHandler):
                     response["tenant"]["recovered"] = recovered
                     with entry.lock:
                         entry.recovered = None
-                sp.set(nodes=len(response["newNodes"]))
             verdict = True
             plane.record_ok(entry)
             plane.observe_latencies(

@@ -173,6 +173,15 @@ class TPUNodeDecision:
         row = self._planes.ct[self._slot]
         return [self._snapshot.capacity_types[c] for c in np.nonzero(row)[0]]
 
+    def name_rows(self) -> tuple:
+        """``(names, bool row)`` of the instance types, zones and capacity
+        types, in that order: the snapshot axes and this node's plane rows
+        the three lists above are read from (the reply's encoder,
+        service.snapshot_channel, writes the lists from these)."""
+        snap, planes, n = self._snapshot, self._planes, self._slot
+        return ((snap.it_names, planes.viable[n]), (snap.zones, planes.zone[n]),
+                (snap.capacity_types, planes.ct[n]))
+
     @property
     def requests(self) -> resources_util.ResourceList:
         row = self._planes.used[self._slot]

@@ -105,8 +105,11 @@ def test_stateless_request_opens_the_same_spans_whatever_its_class_count(
     (objects,) = [s for s in spans
                   if s["name"] == "service.decode" and "classes" in s["attrs"]]
     assert objects["attrs"]["classes"] == n_classes and objects["attrs"]["pods"] == n_pods
-    nodes = _one(spans, "service.payload")["attrs"]["nodes"]
+    payload = _one(spans, "service.payload")["attrs"]
+    nodes = payload["nodes"]
     assert nodes > 0
+    # every new node's name lists were written from its plane rows
+    assert payload["spliced_nodes"] == nodes
     # the served path's host assembly stands under ``prepare``, as the
     # library path's does: none of it is left to the handler's self time
     prepare = _one(spans, "prepare")
