@@ -666,7 +666,6 @@ def kernel_leg(smoke: Smoke, args, pods, provisioners, catalog) -> None:
                 and not a[:c0, n:].any() and not b[:c0, n:].any(),
                 "catalog-sharded solve is not bit-identical to mesh_axes=None",
             )
-    smoke.solve_modes.add(solver.last_solve_mode)
 
     # an UNHOOKED session: its churn repairs donate the warm carry
     donation0 = pipeline_mod.stats()
@@ -763,10 +762,7 @@ def oracle_leg(smoke: Smoke, args, rng) -> None:
     pods = pod_mix(args.oracle_pods, rng)
 
     def kernel():
-        solver = TPUSolver(FakeCloudProvider(catalog), provisioners)
-        results = solver.solve(pods)
-        smoke.solve_modes.add(solver.last_solve_mode)
-        return results
+        return TPUSolver(FakeCloudProvider(catalog), provisioners).solve(pods)
 
     def host():
         kube = KubeClient()

@@ -97,14 +97,10 @@ class FleetRestoreError(Exception):
 def _nt_registry() -> Dict[str, type]:
     from karpenter_core_tpu.ops import masks as mask_ops
     from karpenter_core_tpu.ops import solve as solve_ops
-    from karpenter_core_tpu.policy.planes import ObjectivePlanes
     from karpenter_core_tpu.solver.tpu import SolvePrep
 
     classes = (
         SolvePrep,
-        # SolvePrep.pol (the relax family's objective sheet) rides the
-        # checkpointed prep when present
-        ObjectivePlanes,
         mask_ops.ReqTensor,
         solve_ops.ClassTensors,
         solve_ops.StaticArrays,
@@ -174,6 +170,13 @@ def dec(x, registry: Optional[Dict[str, type]] = None):
         cls = registry.get(x["c"])
         if cls is None:
             raise FleetRestoreError(f"unknown checkpoint tuple {x['c']!r}")
+        if len(x["f"]) != len(cls._fields):
+            # a writer whose tuple had other fields: positional decode would
+            # misalign them, or fill the missing ones from the class defaults
+            raise FleetRestoreError(
+                f"checkpoint tuple {x['c']!r} has {len(x['f'])} fields,"
+                f" this reader's has {len(cls._fields)}"
+            )
         return cls(*(dec(v, registry) for v in x["f"]))
     if tag == "tu":
         return tuple(dec(v, registry) for v in x["v"])

@@ -61,12 +61,6 @@ class PolicyConfig:
     # per-instance-type throughput weights, as a hashable sorted tuple of
     # (instance-type name, weight); types absent default to 0.0
     throughput: Tuple[Tuple[str, float], ...] = ()
-    # solver-family routing (solver/modes.py): "" = defer to KC_SOLVER_MODE
-    # env / scan; "scan" | "relax" | "auto" pins the family for this config
-    # (spec wins over env).  Deliberately OUTSIDE digest(): the mode changes
-    # which program runs, not the objective inputs — the incremental session
-    # escalates on a flip via its own "mode-changed" reason instead.
-    solver_mode: str = ""
 
     # -- construction ----------------------------------------------------------
 
@@ -92,7 +86,6 @@ class PolicyConfig:
             spot_preference=_b("KC_POLICY_SPOT_PREFERENCE", True),
             counter_proposals=_b("KC_POLICY_COUNTER_PROPOSALS", False),
             max_resize_fraction=_f("KC_POLICY_MAX_RESIZE_FRACTION", 0.5),
-            solver_mode=os.environ.get("KC_SOLVER_MODE", ""),
         )
 
     def merged(self, spec: Optional[dict]) -> "PolicyConfig":
@@ -109,7 +102,6 @@ class PolicyConfig:
             "spotPreference": ("spot_preference", bool),
             "counterProposals": ("counter_proposals", bool),
             "maxResizeFraction": ("max_resize_fraction", float),
-            "solverMode": ("solver_mode", str),
         }
         for wire_key, (attr, cast) in mapping.items():
             if wire_key in spec:
@@ -157,7 +149,6 @@ class PolicyConfig:
             "counterProposals": bool(self.counter_proposals),
             "maxResizeFraction": float(self.max_resize_fraction),
             "throughput": {name: weight for name, weight in self.throughput},
-            "solverMode": str(self.solver_mode),
         }
 
     @classmethod

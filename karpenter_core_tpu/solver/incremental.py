@@ -71,14 +71,6 @@ def incremental_enabled() -> bool:
     return os.environ.get("KC_SOLVER_INCREMENTAL", "1") != "0"
 
 
-def _resolve_solve_mode(solver) -> str:
-    """The solver family this session's anchors are configured to route
-    through (solver.modes.resolve_mode over the solver's policy config)."""
-    from karpenter_core_tpu.solver import modes as modes_mod
-
-    return modes_mod.resolve_mode(getattr(solver, "policy", None))
-
-
 @dataclass
 class FallbackPolicy:
     """Per-reconcile full-vs-delta decision (module docstring)."""
@@ -113,8 +105,8 @@ class FallbackPolicy:
         )
 
     def decide(self, delta, delta_ticks: int, prev_slots_used: int,
-               known_classes=None, mesh_changed: bool = False,
-               mode_changed: bool = False) -> Tuple[str, str]:
+               known_classes=None, mesh_changed: bool = False
+               ) -> Tuple[str, str]:
         """(mode, reason).  ``delta`` is a models.store.SnapshotDelta (or None
         on the first solve); ``delta_ticks`` counts repairs since the last
         full solve; ``prev_slots_used`` the slots the previous solve opened;
@@ -127,20 +119,13 @@ class FallbackPolicy:
         longer matches the one the warm prep was built for — the carry's
         planes are sharded for the OLD layout and the catalog pad multiple
         moved with it, so the lineage re-anchors with a full solve on the
-        new topology.  ``mode_changed``: the configured solver family
-        (solver.modes.resolve_mode — env flip or spec change) no longer
-        matches the one the anchor solved under; a relax anchor IS a valid
-        lineage anchor (its outputs are scan-shaped and exactly audited),
-        but repairs always run the scan, so a family flip re-anchors the
-        same way a mesh flip does."""
+        new topology."""
         if not self.enabled:
             return MODE_FULL, "disabled"
         if delta is None:
             return MODE_FULL, "first"
         if mesh_changed:
             return MODE_FULL, "mesh-changed"
-        if mode_changed:
-            return MODE_FULL, "mode-changed"
         if delta.node_side_changed:
             return MODE_FULL, "supply-changed:" + ",".join(delta.changed_planes)
         unknown = tuple(
@@ -180,11 +165,6 @@ class _WarmState:
     state_nodes: list = field(default_factory=list)
     delta_ticks: int = 0
     initial_slots_used: int = 0  # slots open at full-solve time
-    # solver family the anchor was CONFIGURED to run under
-    # (solver.modes.resolve_mode at adopt time — the routing intent, not the
-    # per-batch relax-fallback outcome): a later config flip scan<->relax
-    # escalates with reason "mode-changed"
-    solve_mode: str = "scan"
     # lineage-placed pods that have since BOUND: physically on their node now,
     # still counted by the carry, excluded from the membership and supply
     # views (IncrementalSolveSession._absorb_bound)
@@ -464,12 +444,6 @@ class IncrementalSolveSession:
                 getattr(self._warm.prep, "mesh_axes", None)
                 != mesh_mod.solve_mesh_axes()
             )
-            # solver-family watch (solver/modes.py): same contract as the mesh —
-            # the anchor records which family it was configured for, a flip
-            # re-anchors so the lineage's carry matches the routed program
-            mode_changed = self._warm is not None and (
-                _resolve_solve_mode(self.solver) != self._warm.solve_mode
-            )
             mode, reason = self.policy.decide(
                 delta,
                 self._warm.delta_ticks if self._warm is not None else 0,
@@ -478,7 +452,6 @@ class IncrementalSolveSession:
                 known_classes=self._warm.class_index
                 if self._warm is not None else None,
                 mesh_changed=mesh_changed,
-                mode_changed=mode_changed,
             )
             forced = self._forced_reason
             if forced is not None:
@@ -728,10 +701,6 @@ class IncrementalSolveSession:
             supply=supply,
             state_nodes=list(state_nodes or []),
             initial_slots_used=0,
-            # the CONFIGURED family (routing intent), not the per-batch
-            # outcome: a relax-fallback batch still anchors as "relax" so a
-            # steady config doesn't thrash full solves on transient fallbacks
-            solve_mode=_resolve_solve_mode(self.solver),
         )
         if carry is None:
             self._warm = None  # outputs predate the carry fields
@@ -811,10 +780,6 @@ class IncrementalSolveSession:
             delta_ticks=int(delta_ticks),
             initial_slots_used=int(initial_slots_used),
             materialized=set(materialized),
-            # record THIS replica's resolved family: the restored carry is
-            # scan state either way, and an immediate family mismatch should
-            # escalate on the next reconcile exactly like a live flip
-            solve_mode=_resolve_solve_mode(self.solver),
         )
 
     def export_lineage(self) -> Optional[Dict[str, object]]:

@@ -31,7 +31,6 @@ from karpenter_core_tpu.models.snapshot import (
 from karpenter_core_tpu.apis import labels as labels_api
 from karpenter_core_tpu.ops import solve as solve_ops
 from karpenter_core_tpu.scheduling import Requirement, Requirements
-from karpenter_core_tpu.solver import modes as modes_mod
 from karpenter_core_tpu.solver.machinetemplate import MachineTemplate
 from karpenter_core_tpu.solver.scheduler import _daemon_overhead
 from karpenter_core_tpu.utils import resources as resources_util
@@ -208,34 +207,6 @@ def _scan_axes(cls, statics_arrays) -> dict:
     }
 
 
-def _attach_pol(snapshot, statics_arrays):
-    """The snapshot's policy objective planes (policy.planes.planes_of),
-    catalog-padded to the prep's instance-type extent.  The pol planes share
-    the snapshot's I axis (attach_planes stamps them at encode time, after any
-    mesh alignment), so the pad is a no-op in production — it guards planes
-    prepared outside that path (pad value +inf price = never-selected, the
-    same sentinel the encode uses for absent offerings)."""
-    from karpenter_core_tpu.policy import planes as planes_mod
-
-    pol = planes_mod.planes_of(snapshot)
-    if pol is None:
-        return None
-    n_it = int(np.asarray(statics_arrays.it_alloc).shape[0])
-    if int(np.asarray(pol.price).shape[0]) != n_it:
-        pol = pol._replace(
-            price=solve_ops._pad_axis(
-                np.asarray(pol.price, dtype=np.float32), 0, n_it, np.inf
-            ),
-            risk=solve_ops._pad_axis(
-                np.asarray(pol.risk, dtype=np.float32), 0, n_it, 0.0
-            ),
-            throughput=solve_ops._pad_axis(
-                np.asarray(pol.throughput, dtype=np.float32), 0, n_it, 0.0
-            ),
-        )
-    return pol
-
-
 def _signature_index(table: Dict[tuple, tuple], signature: tuple, row) -> int:
     """The index of ``signature`` in ``table`` (signature -> (index, the first
     row seen with it)), entered on first sight: how ``encode_existing`` groups
@@ -265,12 +236,6 @@ class SolvePrep(NamedTuple):
     # incremental session escalates to a full solve when the live topology
     # moves (solver.incremental "mesh-changed")
     mesh_axes: object = None
-    # policy objective planes (policy.planes.ObjectivePlanes) for the relax
-    # solver family's linear cost — attached FRESH on every prepare (prices
-    # move while the shape anchors stay identical, so the warm-prep fast path
-    # must never serve a cached sheet); None when the snapshot predates the
-    # policy encode.  The scan variants never read it.
-    pol: object = None
 
 
 @dataclass
@@ -335,10 +300,6 @@ class TPUSolver:
         # provider handle stays on the solver so the risk planes can read its
         # live capacity-error state at encode time (policy.planes).
         self.policy = policy
-        # the last cold solve's solver-family outcome ("scan" | "relax" |
-        # "relax-fallback:<reason>") — observability convenience mirroring the
-        # solve.mode span / karpenter_solve_mode_total counter
-        self.last_solve_mode = "scan"
         self.cloud_provider = cloud_provider
         self.provisioners = order_by_weight(
             [p for p in provisioners if p.metadata.deletion_timestamp is None]
@@ -1048,9 +1009,6 @@ class TPUSolver:
                         mesh_mod.solve_mesh_axes(),
                         solve_ops.StaticArrays(*prev.statics_arrays),
                     ),
-                    pol=_attach_pol(
-                        snapshot, solve_ops.StaticArrays(*prev.statics_arrays)
-                    ),
                 )
         cls, statics_arrays, key_has_bounds = solve_ops.prepare_host(snapshot)
         if pad:
@@ -1066,9 +1024,6 @@ class TPUSolver:
             n_passes=snapshot.scan_passes, features=features,
             mesh_axes=compilecache.resolve_mesh_axes(
                 mesh_mod.solve_mesh_axes(), solve_ops.StaticArrays(*statics_arrays)
-            ),
-            pol=_attach_pol(
-                snapshot, solve_ops.StaticArrays(*statics_arrays)
             ),
         )
         if anchors is not None:
@@ -1111,40 +1066,6 @@ class TPUSolver:
         cls = prep.cls
         if count is not None:
             cls = cls._replace(count=np.asarray(count, dtype=np.int32))
-        # -- solver-mode dispatch (solver/modes.py, docs/RELAX.md) ------------
-        # Cold solves only: a warm-carry repair resumes SCAN state and a
-        # repair_plan means this call IS the relax family's own cleanup pass
-        # (relax.solve.run_relax re-enters run_prepared with both set, which
-        # is also what makes this hook non-recursive).
-        if warm_carry is None and repair_plan is None:
-            mode = modes_mod.resolve_mode(self.policy)
-            if mode != modes_mod.MODE_SCAN:
-                n_pods = int(np.asarray(cls.count, dtype=np.int64).sum())
-                if modes_mod.relax_selected(mode, n_pods):
-                    from karpenter_core_tpu.relax import solve as relax_solve
-                    from karpenter_core_tpu.solver.incremental import SOLVE_MODE
-
-                    with tracing.span("solve.mode", mode=mode,
-                                      pods=n_pods) as sp:
-                        try:
-                            out = relax_solve.run_relax(
-                                self, prep, cls=cls, n_slots=n_slots
-                            )
-                        except relax_solve.RelaxFallback as fb:
-                            # the scan below runs as if relax never existed;
-                            # only the structured reason is left behind
-                            sp.set(selected="relax-fallback", reason=fb.reason)
-                            SOLVE_MODE.labels("relax-fallback").inc()
-                            self.last_solve_mode = f"relax-fallback:{fb.reason}"
-                        else:
-                            sp.set(selected="relax")
-                            SOLVE_MODE.labels("relax").inc()
-                            self.last_solve_mode = "relax"
-                            return out
-                else:
-                    self.last_solve_mode = "scan"
-            else:
-                self.last_solve_mode = "scan"
         ex_static = prep.ex_static
         if warm_carry is not None and ex_static is None:
             # the warm variant always takes the ex-static planes (its tol/vol

@@ -32,12 +32,11 @@ their cache stays off.
 
 from __future__ import annotations
 
-import functools
 import hashlib
 import logging
 import os
 import threading
-from typing import Dict, Optional
+from typing import Dict
 
 log = logging.getLogger(__name__)
 
@@ -294,26 +293,6 @@ def _kernel_src_hash(*also) -> str:
                 digest.update(f.read())
         _kernel_hash[key] = digest.hexdigest()[:16]
     return _kernel_hash[key]
-
-
-_relax_hash: Optional[str] = None
-
-
-def _relax_src_hash() -> str:
-    global _relax_hash
-    if _relax_hash is None:
-        # relax_core traces solve.py helpers (_it_intersects/_capacity) and
-        # masks.py, so all three modules invalidate the relax memo
-        from karpenter_core_tpu.ops import masks as mask_ops
-        from karpenter_core_tpu.ops import solve as solve_ops
-        from karpenter_core_tpu.relax import kernel as relax_kernel
-
-        digest = hashlib.sha256()
-        for module in (relax_kernel, solve_ops, mask_ops):
-            with open(module.__file__, "rb") as f:
-                digest.update(f.read())
-        _relax_hash = digest.hexdigest()[:16]
-    return _relax_hash
 
 
 def leaf_sig(tree) -> tuple:
@@ -601,61 +580,6 @@ def sweep_callable(
     return _memoized(key, lambda: _build_and_memo(
         key, "sweep", base, struct_args, sharded=sharded,
     ))
-
-
-def relax_callable(
-    cls,
-    statics_arrays,
-    pol,
-    n_slots: int,
-    key_has_bounds,
-    mesh_axes=None,
-):
-    """The relax-family executable (karpenter_core_tpu/relax): the
-    module-level ``relax/kernel._relax_jit`` partially applied with its static
-    config, memoized in ``_memo`` under a ``"relax"``-prefixed key exactly
-    like every scan variant, so the compile-reuse ledger (builds/memo_hits)
-    and reset_memo cover both families uniformly.
-
-    No new jit is constructed here — ``_relax_jit`` is a single module-level
-    ``functools.partial(jax.jit, static_argnames=...)`` wrap (the same idiom
-    as ``ops.solve._solve_jit``), so the retrace-budget analyzer's
-    uncached-jit and static-args cross-checks see one cached entry and zero
-    new baseline rows.  ``mesh_axes`` is key-only: the relax program is a
-    plain jit whose inputs arrive sharded (GSPMD propagates the catalog
-    partition), so topology changes re-key without rebuilding the wrapper.
-    The exported-StableHLO disk cache is not used (the while_loop program
-    traces in milliseconds at these shapes — the memo and XLA's persistent
-    cache are enough)."""
-    import jax
-
-    from karpenter_core_tpu.relax import kernel as relax_kernel
-
-    key = (
-        "relax",
-        _relax_src_hash(),
-        _resolved_backend(),
-        n_slots,
-        tuple(key_has_bounds),
-        mesh_axes,
-        leaf_sig(cls),
-        leaf_sig(statics_arrays),
-        leaf_sig(pol),
-    )
-    with _lock:
-        fn = _memo.get(key)
-        if fn is not None:
-            _stats["memo_hits"] += 1
-            return fn
-    fn = functools.partial(
-        relax_kernel._relax_jit,
-        n_slots=int(n_slots),
-        key_has_bounds=tuple(key_has_bounds),
-    )
-    with _lock:
-        _memo[key] = fn
-        _stats["builds"] += 1
-    return fn
 
 
 def batched_solve_callable(

@@ -32,9 +32,6 @@ FLAGS: Dict[str, str] = {
     "KC_DELTA_MAX_FRACTION": "churn fraction above which a delta solve falls back to full",
     "KC_DELTA_AUDIT_INTERVAL": "full-solve audit cadence for long delta chains",
     "KC_DEGRADED_MAX_PODS": "pod-count ceiling for the degraded (host fallback) solve path",
-    "KC_SOLVER_MODE": "solver family routing: scan | relax | auto (PolicyConfig/provisioner spec wins over env)",
-    "KC_RELAX_MAX_ITERS": "projected-gradient iteration cap for the relax solver family",
-    "KC_RELAX_MIN_PODS": "pod-count threshold above which auto mode picks the relax family",
     # -- watchdog --------------------------------------------------------------
     "KC_WATCHDOG": "adaptive watchdog over every blocking device interaction (0 = legacy unguarded waits)",
     "KC_WATCHDOG_FLOOR_S": "watchdog deadline floor",

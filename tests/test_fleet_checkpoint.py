@@ -408,6 +408,60 @@ class TestRestore:
         ) == replay_before + 1
         _stop(server2, client2)
 
+    def test_prep_with_other_fields_refuses_and_falls_to_replay(self, tmp_path):
+        """A checkpoint whose SolvePrep carries a field this reader's does
+        not (a writer one schema apart, mid rolling upgrade) must refuse as
+        FleetRestoreError rather than decode positionally into a misaligned
+        prep, and the adopting replica must fall to the journal rung."""
+        from karpenter_core_tpu import fleet as fleet_mod
+        from karpenter_core_tpu.solver.incremental import (
+            IncrementalSolveSession,
+        )
+
+        provider = FakeCloudProvider()
+        fleet1 = _fleet(tmp_path, rid="r1", ckpt_every=1)
+        jdir1 = tmp_path / "fleet" / "journals" / "r1"
+        server1, client1 = _serve(provider, fleet=fleet1, journal_dir=jdir1)
+        r1 = _solve(client1, "acme", count=8)
+        r2 = _solve(client1, "acme", count=10,
+                    version=r1["tenant"]["sessionVersion"])
+        assert r2["tenant"]["solveMode"] == "delta"
+        path = server1.kc_service._ckpt.path_for("acme")
+        import time
+
+        time.sleep(0.3)  # the journal writer drains asynchronously
+        _stop(server1, client1, abandon=True)
+
+        ckpt, status = load_checkpoint(path)
+        assert status == ckpt_mod.STATUS_OK
+        assert ckpt.tensors["prep"]["c"] == "SolvePrep"
+        ckpt.tensors["prep"]["f"].append(None)  # one trailing field more
+        with open(path, "wb") as f:
+            f.write(ckpt_mod.checkpoint_bytes(
+                ckpt.header, ckpt.anchor, ckpt.tensors))
+        ckpt, status = load_checkpoint(path)
+        assert status == ckpt_mod.STATUS_OK  # the frames and digest verify
+        with pytest.raises(FleetRestoreError):
+            ckpt_mod.restore_session(
+                ckpt, IncrementalSolveSession(), provider)
+
+        warm_before = _counter_value(fleet_mod.FAILOVER_TOTAL, outcome="warm")
+        replay_before = _counter_value(
+            fleet_mod.FAILOVER_TOTAL, outcome="replay")
+        server2, client2 = _serve(
+            provider, fleet=_fleet(tmp_path, rid="r2", ckpt_every=1),
+            journal_dir=tmp_path / "fleet" / "journals" / "r2",
+        )
+        r3 = _solve(client2, "acme", count=12,
+                    version=r2["tenant"]["sessionVersion"])
+        assert r3["tenant"]["solveMode"] == "delta"
+        assert r3["tenant"]["recovered"] == "warm"
+        assert _counter_value(
+            fleet_mod.FAILOVER_TOTAL, outcome="warm") == warm_before
+        assert _counter_value(
+            fleet_mod.FAILOVER_TOTAL, outcome="replay") == replay_before + 1
+        _stop(server2, client2)
+
     def test_no_artifact_reanchors(self, tmp_path):
         """Nothing to adopt from: the ladder bottoms out at the existing
         session-lost full solve (outcome ``reanchor``) — never an error."""

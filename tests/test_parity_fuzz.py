@@ -24,7 +24,8 @@ from karpenter_core_tpu.apis.objects import (
     Toleration,
     TopologySpreadConstraint,
 )
-from karpenter_core_tpu.testing import make_pod, make_provisioner
+from karpenter_core_tpu.cloudprovider import fake as fake_cp
+from karpenter_core_tpu.testing import make_pod, make_pods, make_provisioner
 from karpenter_core_tpu.testing.harness import expect_provisioned, make_environment
 from karpenter_core_tpu.testing.validator import expect_valid_placements
 
@@ -385,3 +386,32 @@ def test_fuzzed_batch_parity_with_existing_nodes(seed):
             assert tpu.get(cls, 0) == host.get(cls, 0), (
                 f"seed {seed} {cls}: warm tpu={dict(tpu)} host={dict(host)}"
             )
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_no_wrong_placements(seed):
+    """Random mixed fleets on a small catalog, through the controller's
+    kernel path: every pod of two to four classes of four sizes, a third of
+    them zone-spread, lands, and every binding the controller commits passes
+    the host validator's full constraint audit."""
+    rng = random.Random(20260807 + seed)
+    env = make_environment(instance_types=fake_cp.instance_types(8))
+    env.kube.create(make_provisioner(name="default"))
+    env.provisioning.use_tpu_kernel = True
+    env.provisioning.tpu_kernel_min_pods = 1
+    pods = []
+    sizes = ({"cpu": "100m"}, {"cpu": "500m"}, {"cpu": 1},
+             {"cpu": "250m", "memory": "512Mi"})
+    for cls_i in range(rng.randint(2, 4)):
+        labels = {"app": f"mixed-fuzz-{cls_i}"}
+        kwargs = dict(labels=labels, requests=rng.choice(sizes))
+        if rng.random() < 0.3:
+            kwargs["topology_spread"] = [TopologySpreadConstraint(
+                max_skew=1,
+                topology_key=ZONE,
+                label_selector=LabelSelector(match_labels=dict(labels)),
+            )]
+        pods.extend(make_pods(rng.randint(8, 48), **kwargs))
+    result = expect_provisioned(env, *pods)
+    assert all(node is not None for node in result.values())
+    expect_valid_placements(env, pods)

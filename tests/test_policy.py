@@ -306,6 +306,30 @@ class TestDecodeSelection:
         rendered = REGISTRY.render()
         assert 'karpenter_policy_fleet_cost{view="price"}' in rendered
 
+    def test_retired_solver_mode_key_parses_and_solves_on_the_scan(self):
+        """A provisioner manifest that still carries the retired
+        ``solverMode`` key parses to the same config as one without it, and
+        its batch solves on the scan exactly as that config's does."""
+        policy = {"enabled": True, "solverMode": "relax"}
+        prov = make_provisioner(name="default", policy=policy)
+        config = PolicyConfig.resolve([prov])
+        assert config == PolicyConfig.resolve(
+            [make_provisioner(name="default", policy={"enabled": True})]
+        )
+        assert "solverMode" not in config.to_wire()
+        pods = make_pods(8, requests={"cpu": "500m"})
+        placements = []
+        for resolved in (config, PolicyConfig(enabled=True)):
+            _, solver = self._solver(resolved, skew_prices=True)
+            results = solver.solve(pods)
+            assert not results.failed_pods
+            assert all(d.zones == ["test-zone-2"] for d in results.new_nodes)
+            placements.append(sorted(
+                (d.instance_type_names[0], tuple(sorted(p.uid for p in d.pods)))
+                for d in results.new_nodes
+            ))
+        assert placements[0] == placements[1]
+
     def test_disabled_policy_stamps_nothing(self):
         _, solver = self._solver(policy=None, skew_prices=True)
         results = solver.solve(make_pods(6, requests={"cpu": "500m"}))
