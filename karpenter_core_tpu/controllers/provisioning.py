@@ -1049,17 +1049,25 @@ class ProvisioningController:
     def _split_batch_impl(self, pods: List[Pod]):
         from dataclasses import replace as dc_replace
 
+        from karpenter_core_tpu.solver.incremental import incremental_enabled
+
         interner = self._sig_interner
         supported: Dict[tuple, List[Pod]] = {}
         unsupported: Dict[tuple, List[Pod]] = {}
         protos: Dict[tuple, object] = {}
         interned = 0  # shapes of this batch the interner already knew
+        # the in-process incremental session keeps no capacity-type counts
+        # (models.snapshot.refuse_ct_spread): its batches send such classes
+        # to the host, as every path did before the kernel served them
+        ct_to_host = not self.solver_endpoint and incremental_enabled()
         for pod in pods:
             sig = interner.sig_of(pod)
             proto = protos.get(sig)
             if proto is None and sig not in protos:
                 interned += interner.knows(sig)
                 proto, _error = interner.ladder_of(sig, pod)
+                if ct_to_host and proto is not None and proto.ct_spread is not None:
+                    proto = None
                 protos[sig] = proto
             (supported if proto is not None else unsupported).setdefault(
                 sig, []

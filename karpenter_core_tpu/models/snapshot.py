@@ -42,6 +42,15 @@ GRP_SPREAD = 0
 GRP_AFFINITY = 1
 GRP_ANTI = 2
 
+# the topology keys the kernel counts domains of, with the PodClass slot a
+# spread on each fills; capacity type for spreads alone (an affinity or anti
+# term on it routes to the host)
+SPREAD_SLOTS = {
+    labels_api.LABEL_TOPOLOGY_ZONE: "zone_spread",
+    labels_api.LABEL_HOSTNAME: "host_spread",
+    labels_api.LABEL_CAPACITY_TYPE: "ct_spread",
+}
+
 
 @dataclass(frozen=True)
 class GroupSpec:
@@ -54,10 +63,14 @@ class GroupSpec:
     the owner's namespace (topology.go:287-320 buildNamespaceList)."""
 
     gtype: int  # GRP_SPREAD | GRP_AFFINITY | GRP_ANTI
-    is_zone: bool  # zone key vs hostname key
+    key: str  # a SPREAD_SLOTS key: zone, hostname, or capacity type (spreads only)
     selector_sig: tuple
     skew: int
     namespaces: frozenset = frozenset()
+
+    @property
+    def is_zone(self) -> bool:
+        return self.key == labels_api.LABEL_TOPOLOGY_ZONE
 
 
 @dataclass(frozen=True)
@@ -145,6 +158,7 @@ class PodClass:
     host_affinity: Optional[GroupSpec] = None
     zone_anti: Optional[GroupSpec] = None
     host_anti: Optional[GroupSpec] = None
+    ct_spread: Optional[GroupSpec] = None  # spread on karpenter.sh/capacity-type
     # GroupScope (selector + namespace set) per owned group, for
     # membership evaluation
     selectors: Dict[GroupSpec, "GroupScope"] = field(default_factory=dict)
@@ -180,6 +194,7 @@ class PodClass:
                 self.host_affinity,
                 self.zone_anti,
                 self.host_anti,
+                self.ct_spread,
             )
             if g is not None
         ]
@@ -242,8 +257,9 @@ class EncodedSnapshot:
     grp_is_zone: np.ndarray = None  # bool[G1]
     grp_is_anti: np.ndarray = None  # bool[G1]
     grp_member: np.ndarray = None  # bool[C, G1] selector matches class labels
-    cls_groups: np.ndarray = None  # i32[C, 6] owned group per kind (G = none):
-    #   [zone_spread, host_spread, zone_aff, host_aff, zone_anti, host_anti]
+    cls_groups: np.ndarray = None  # i32[C, 7] owned group per kind (G = none):
+    #   [zone_spread, host_spread, zone_aff, host_aff, zone_anti, host_anti,
+    #    ct_spread]
 
     # vocabulary statics
     valid: np.ndarray = None  # bool[K, V+1]
@@ -622,6 +638,14 @@ def affinity_scan_passes(classes: List[PodClass]) -> int:
     raise KernelUnsupported("cyclic cross-group affinity not kernel-supported")
 
 
+def refuse_ct_spread(classes: List[PodClass], path: str) -> None:
+    """Raise KernelUnsupported when a class spreads on capacity type: the
+    family places a class pod by pod from the counts of a whole solve, which
+    ``path`` (a warm repair window, a sweep's vmapped lanes) does not keep."""
+    if any(cls.ct_spread is not None for cls in classes):
+        raise KernelUnsupported(f"capacity-type spread not kernel-supported on {path}")
+
+
 def classify_pods(pods: List[Pod]) -> List[PodClass]:
     """Group pods into equivalence classes and derive each class's owned
     topology groups.  Groups are shared across classes by identity (type, key,
@@ -645,14 +669,12 @@ def classify_pods(pods: List[Pod]) -> List[PodClass]:
 def _group_spec(
     gtype: int, topology_key: str, selector, skew: int, namespaces: frozenset
 ) -> GroupSpec:
-    if topology_key == labels_api.LABEL_TOPOLOGY_ZONE:
-        is_zone = True
-    elif topology_key == labels_api.LABEL_HOSTNAME:
-        is_zone = False
-    else:
+    if topology_key not in SPREAD_SLOTS or (
+        topology_key == labels_api.LABEL_CAPACITY_TYPE and gtype != GRP_SPREAD
+    ):
         raise KernelUnsupported(f"topology on {topology_key} not kernel-supported")
     return GroupSpec(
-        gtype=gtype, is_zone=is_zone, selector_sig=_selector_sig(selector), skew=skew,
+        gtype=gtype, key=topology_key, selector_sig=_selector_sig(selector), skew=skew,
         namespaces=namespaces,
     )
 
@@ -689,7 +711,7 @@ def _derive_topology_spec(pod: Pod, cls: PodClass) -> None:
             GRP_SPREAD, constraint.topology_key, constraint.label_selector,
             constraint.max_skew, own_ns,
         )
-        set_slot("zone_spread" if spec.is_zone else "host_spread", spec, constraint.label_selector)
+        set_slot(SPREAD_SLOTS[spec.key], spec, constraint.label_selector)
     affinity = pod.spec.affinity
     if affinity is not None:
         if affinity.pod_affinity is not None:
@@ -737,6 +759,16 @@ def _derive_topology_spec(pod: Pod, cls: PodClass) -> None:
         raise KernelUnsupported("combined zone spread + zone anti-affinity not kernel-supported")
     if cls.host_affinity is not None and (cls.zone_spread is not None or cls.zone_anti is not None):
         raise KernelUnsupported("combined hostname affinity + zonal spread/anti not kernel-supported")
+    # a capacity-type spread places its class pod by pod (ops/solve.py
+    # kc.phase.ct_spread) beside a zone and a hostname spread; no family of
+    # the affinity terms runs that loop
+    if cls.ct_spread is not None and any(
+        g is not None
+        for g in (cls.zone_affinity, cls.host_affinity, cls.zone_anti, cls.host_anti)
+    ):
+        raise KernelUnsupported(
+            "capacity-type spread + pod (anti-)affinity not kernel-supported"
+        )
     # required zonal anti-affinity IS kernel-supported (since round 5): the
     # scan derives per-zone counts from nodes' CURRENT zone masks at every
     # class step (ops/solve.TopoCounts) and the owned-anti phases are
@@ -1043,6 +1075,7 @@ def encode_snapshot(
             (snap.cls_groups[:, 0] < len(snap.groups))
             & ~snap.grp_member[np.arange(len(classes)), snap.cls_groups[:, 0]]
         )),
+        ct_spread=owns("ct_spread"),
     ).canonical()
 
     if cache_host is not None:
@@ -1294,7 +1327,7 @@ def _populate_class_planes(
         snap.grp_is_zone = np.zeros(G + 1, dtype=bool)
         snap.grp_is_anti = np.zeros(G + 1, dtype=bool)
         snap.grp_member = np.zeros((C, G + 1), dtype=bool)
-        snap.cls_groups = np.full((C, 6), G, dtype=np.int32)
+        snap.cls_groups = np.full((C, 7), G, dtype=np.int32)
         for spec, g in group_index.items():
             snap.grp_skew[g] = spec.skew
             snap.grp_is_zone[g] = spec.is_zone
@@ -1305,7 +1338,7 @@ def _populate_class_planes(
         for c, cls in enumerate(classes):
             for slot, spec in enumerate(
                 (cls.zone_spread, cls.host_spread, cls.zone_affinity,
-                 cls.host_affinity, cls.zone_anti, cls.host_anti)
+                 cls.host_affinity, cls.zone_anti, cls.host_anti, cls.ct_spread)
             ):
                 if spec is not None:
                     snap.cls_groups[c, slot] = group_index[spec]
@@ -1318,7 +1351,10 @@ def _populate_class_planes(
             foreign_members=int(np.sum(snap.grp_member[:, :G] & ~owned[:, :G])),
             host_affinity_groups=sum(
                 1 for spec in group_index
-                if spec.gtype == GRP_AFFINITY and not spec.is_zone
+                if spec.gtype == GRP_AFFINITY and spec.key == labels_api.LABEL_HOSTNAME
+            ),
+            ct_groups=sum(
+                1 for spec in group_index if spec.key == labels_api.LABEL_CAPACITY_TYPE
             ),
         )
     for c, cls in enumerate(classes):

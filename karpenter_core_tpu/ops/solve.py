@@ -73,6 +73,8 @@ class SnapshotFeatures(NamedTuple):
     volume_limits: bool = True  # existing nodes carry finite CSI attach limits
     # some class owns a zonal spread whose selector its own pods do not match
     zone_spread_nonself: bool = True
+    # some class owns a spread on karpenter.sh/capacity-type (kc.phase.ct_spread)
+    ct_spread: bool = True
 
     def canonical(self) -> "SnapshotFeatures":
         """Normalize implications so equivalent requests share a cache key:
@@ -111,6 +113,10 @@ class NodeState(NamedTuple):
     tmpl_id: jnp.ndarray  # i32[N]
     open_: jnp.ndarray  # bool[N]
     n_next: jnp.ndarray  # i32[] next free slot
+    # where the slot stands in upstream's list of new nodes among slots of
+    # its pod count (``_fill_in_list_order``); kept only where
+    # SnapshotFeatures.ct_spread, zeros everywhere else
+    order: jnp.ndarray  # i32[N]
 
 
 class ExistingState(NamedTuple):
@@ -532,6 +538,39 @@ def _fill_with_pref(quota, cap, priority, pref):
         return a0 + a1
 
 
+def _next_event(order: jnp.ndarray) -> jnp.ndarray:
+    """The time of the next placement: every placement stamps its slot's
+    ``order`` with its own time, so the latest is the largest magnitude."""
+    return jnp.max(jnp.abs(order)) + 1
+
+
+def _fill_in_list_order(quota, cap, count, order):
+    """(i32[N] pods each node takes, the ``order`` plane after): ``quota``
+    pods one at a time, each to the first node with room (``cap`` > the pods
+    it took) of upstream's list of new nodes, which it sorts stably by pod
+    count before every pod and appends a fresh node to (scheduler.py
+    ``_add``): ascending (count, order), where ``order`` holds a slot's
+    creation time while it has its first pod and minus the time of its
+    latest pod after — a node that just took a pod leads the nodes of its
+    new count, a node with one pod ranks by creation."""
+    with jax.named_scope("kc.fill"):
+        clock = _next_event(order)
+        imax = jnp.iinfo(jnp.int32).max
+
+        def take(carry):
+            k, a, order_k = carry
+            live = cap > a
+            c = jnp.where(live, count + a, imax)
+            n = jnp.argmin(jnp.where(c == jnp.min(c), order_k, imax))
+            return k + 1, a.at[n].add(1), order_k.at[n].set(-(clock + k))
+
+        _, assigned, order = jax.lax.while_loop(
+            lambda carry: (carry[0] < quota) & jnp.any(cap > carry[1]),
+            take, (jnp.int32(0), jnp.zeros_like(cap), order),
+        )
+        return assigned, order
+
+
 class Statics(NamedTuple):
     """Trace-time constants bundled for the kernel.  The requirement mask
     planes (``it``, ``tmpl``, ``valid``) are uint32 words (ops/masks.py
@@ -601,8 +640,9 @@ class ClassTensors(NamedTuple):
     count: jnp.ndarray
     tol: jnp.ndarray
     ports: jnp.ndarray  # bool[C, P] host ports each pod of the class binds
-    groups: jnp.ndarray  # i32[C, 6]: owned group per kind (G = none):
-    # [zone_spread, host_spread, zone_aff, host_aff, zone_anti, host_anti]
+    groups: jnp.ndarray  # i32[C, 7]: owned group per kind (G = none):
+    # [zone_spread, host_spread, zone_aff, host_aff, zone_anti, host_anti,
+    #  ct_spread]
     relax_next: jnp.ndarray  # i32[C] preference-ladder successor (-1 none):
     # failed counts roll to the successor class between scan passes
     anti_soft: jnp.ndarray  # bool[C, 2] (zone, host) anti slot came from a
@@ -784,6 +824,7 @@ def _phase(
     ft: SnapshotFeatures = ALL_FEATURES,
     pref: Optional[jnp.ndarray] = None,
     open_restrict: Optional[jnp.ndarray] = None,
+    list_order: Optional[jnp.ndarray] = None,
 ) -> Tuple[NodeState, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Place up to ``quota`` pods of the class on nodes whose zone mask meets
     ``zone_restrict`` — first onto open nodes, then fresh nodes from the first
@@ -794,7 +835,10 @@ def _phase(
     ``pref`` (warm repair only) the class's freed-hole counts per slot
     (``_fill_with_pref``); ``open_restrict`` ([N, Z]) gives each open node
     a restriction of its own in place of ``zone_restrict``, which fresh nodes
-    keep."""
+    keep; ``list_order`` (a traced bool, where SnapshotFeatures.ct_spread)
+    is true where the batch holds a capacity-type spread class: open nodes
+    then take their pods in the order of upstream's list
+    (``_fill_in_list_order``) and every node taken keeps ``NodeState.order``."""
     n_slots = state.used.shape[0]
     n_tmpl = statics.tmpl_it.shape[0]
 
@@ -839,7 +883,17 @@ def _phase(
     # slot count both stay far below 2^15 so the packed key fits int32
     priority = state.pod_count * n_slots + jnp.arange(n_slots, dtype=jnp.int32)
     priority = jnp.where(cap_n > 0, priority, jnp.iinfo(jnp.int32).max)
-    assigned = _fill_with_pref(quota, cap_n, priority, pref)
+    order = state.order
+    if list_order is not None and pref is None:
+        # beside the capacity-type family, which places pod by pod, every
+        # class takes the open nodes in the order upstream's list gives them
+        assigned, order = jax.lax.cond(
+            list_order,
+            lambda: _fill_in_list_order(quota, cap_n, state.pod_count, order),
+            lambda: (_fill_with_pref(quota, cap_n, priority, None), order),
+        )
+    else:
+        assigned = _fill_with_pref(quota, cap_n, priority, pref)
     placed_existing = jnp.sum(assigned)
 
     # -- commit to existing nodes --------------------------------------------
@@ -968,9 +1022,17 @@ def _phase(
     # surviving instance type (scheduler.go:273-290 subtractMax)
     remaining = remaining.at[t_star].add(-n_new.astype(jnp.float32) * max_cap_star)
 
+    if list_order is not None:
+        # a fresh node takes its pods one after another: its creation time
+        # while it holds one, the time of its last pod after
+        first = _next_event(order) + rank * per_node
+        order = jnp.where(
+            list_order & is_new, jnp.where(a_new > 1, -(first + a_new - 1), first), order
+        )
+
     new_state = NodeState(
         used, kmask, kdef, kneg, kgt, klt, new_zone, new_ct, viable,
-        ports_plane, pod_count, tmpl_id, open_, n_next,
+        ports_plane, pod_count, tmpl_id, open_, n_next, order,
     )
     return new_state, assigned + a_new, placed_existing + placed_new, remaining
 
@@ -1015,6 +1077,7 @@ def _class_step(
     features: SnapshotFeatures = ALL_FEATURES,
     pref: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     topo_base: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
+    list_order: Optional[jnp.ndarray] = None,
 ):
     """One scan step: schedule every pod of one class — existing nodes first,
     then new nodes, per phase.  Topology lives in shared group counts (the
@@ -1028,7 +1091,9 @@ def _class_step(
     ``topo_base`` (windowed warm repair only) is the
     ``(fwd_sing, fwd_full, inv_full)`` [G1, Z] zone-count contribution of
     new-node slots outside the repair window (RepairPlan docstring), added as
-    constants into the zone derivations below.
+    constants into the zone derivations below.  ``list_order`` (a traced
+    bool, where ``features.ct_spread``): the batch holds a capacity-type
+    spread class, so open nodes are taken in upstream's list order (``_phase``).
 
     The step reads and records the topology planes by ROW where the member
     lists are kept and short beside the group axis (``by_row`` below: the K
@@ -1060,7 +1125,7 @@ def _class_step(
     g1 = statics.grp_skew.shape[0]
     g_dummy = g1 - 1
 
-    g_zs, g_hs, g_zaf, g_haf, g_zan, g_han = (cls.groups[i] for i in range(6))
+    g_zs, g_hs, g_zaf, g_haf, g_zan, g_han, g_cs = (cls.groups[i] for i in range(7))
     member_row = statics.grp_member[cls_index]  # [G1]
     # the groups this class is a member of lead its member list; the rest of
     # the list names the dummy group.  Where the lists are kept (M > 0,
@@ -1078,6 +1143,10 @@ def _class_step(
     has_zaf = g_zaf < g_dummy
     has_haf = g_haf < g_dummy
     has_zan = g_zan < g_dummy
+    has_cs = g_cs < g_dummy
+    # a class that spreads on capacity type places all its pods in
+    # kc.phase.ct_spread, its zone and hostname spreads with them
+    zs_family = has_zs & ~has_cs if ft.ct_spread else has_zs
 
     with jax.named_scope("kc.step.derive"):
         # -- derived per-zone counts (TopoCounts docstring): positive groups
@@ -1127,6 +1196,17 @@ def _class_step(
                 own_is_anti = jnp.stack([statics.grp_is_anti[g] for g in own_rows])
                 own_zone_fwd = jnp.where(own_is_anti[:, None], own_zone_full, own_zone_fwd)
             zone_fwd = {kind: own_zone_fwd[k] for k, (kind, _) in enumerate(own_zone)}
+        if ft.ct_spread:
+            # forward counts per capacity type of the capacity-type spread the
+            # class owns: pods on nodes committed to one capacity type, as the
+            # zone rows above count committed zones
+            ex_ct_i = ex.ct.astype(jnp.int32) * ex.open_.astype(jnp.int32)[:, None]
+            new_ct_i = state.ct.astype(jnp.int32) * state.open_.astype(jnp.int32)[:, None]
+            counts_cs = topo.fwd_ex[g_cs] @ jnp.where(
+                jnp.sum(ex_ct_i, axis=-1, keepdims=True) == 1, ex_ct_i, 0
+            ) + topo.fwd_new[g_cs] @ jnp.where(
+                jnp.sum(new_ct_i, axis=-1, keepdims=True) == 1, new_ct_i, 0
+            )  # [CT]
 
         # -- inverse anti-affinity blocks (topology.go:44-47): members of anti
         # groups avoid every domain the group's owners could occupy
@@ -1230,6 +1310,103 @@ def _class_step(
     assigned_ex_total = jnp.zeros_like(ex.pod_count)
     placed_total = jnp.int32(0)
 
+    def skip(operand):
+        """A block's skipping side: the carry as it came, nothing placed."""
+        state_i, ex_i, rem_i = operand
+        return (
+            state_i,
+            ex_i,
+            jnp.zeros_like(state_i.pod_count),
+            jnp.zeros_like(ex_i.pod_count),
+            jnp.int32(0),
+            rem_i,
+        )
+
+    def commit(state_i, ex_i, merged, tmpl_merged, a_ex, ex_zone, ex_ct, a_open,
+               fresh_t, fresh_a, zone, ct, viable_open, viable_fresh, n_next,
+               order=None):
+        """One write of every node a block touched.  Existing nodes that took
+        ``a_ex`` pods take the step's merge and ``ex_zone`` / ``ex_ct`` rows;
+        open slots that took ``a_open`` pods the block's ``merged`` masks and
+        ``zone`` / ``ct`` / ``viable_open`` rows; fresh slots (``fresh_t`` >= 0,
+        their template) ``tmpl_merged``, ``fresh_a`` pods and ``zone`` /
+        ``ct`` / ``viable_fresh`` rows.  Every other row stays as it came.
+        ``order`` is the list order the block kept (``_fill_in_list_order``);
+        where it kept none, the nodes it touched take their places in index
+        order."""
+        sel_e = (a_ex > 0)[:, None]
+        mex = ex_prep.merged
+        ex_o = ExistingState(
+            used=ex_i.used + a_ex[:, None].astype(jnp.float32) * cls.requests[None, :],
+            kmask=jnp.where(sel_e[..., None], mex.mask, ex_i.kmask),
+            kdef=jnp.where(sel_e, mex.defined, ex_i.kdef),
+            kneg=jnp.where(sel_e, mex.negative, ex_i.kneg),
+            kgt=jnp.where(sel_e, mex.gt, ex_i.kgt),
+            klt=jnp.where(sel_e, mex.lt, ex_i.klt),
+            zone=jnp.where(sel_e, ex_zone, ex_i.zone),
+            ct=jnp.where(sel_e, ex_ct, ex_i.ct),
+            ports=jnp.where(sel_e, ex_i.ports | cls.ports[None, :], ex_i.ports)
+            if ft.host_ports else ex_i.ports,
+            vol_used=jnp.where(
+                sel_e,
+                ex_i.vol_used + ex_prep.vol_add
+                + a_ex[:, None] * ex_prep.vol_per_pod[None, :],
+                ex_i.vol_used,
+            )
+            if ft.volume_limits else ex_i.vol_used,
+            pod_count=ex_i.pod_count + a_ex,
+            open_=ex_i.open_,
+        )
+        is_fresh = fresh_t >= 0
+        tmpl_idx = jnp.maximum(fresh_t, 0)
+        sel_o = (a_open > 0)[:, None]
+        sel_f = is_fresh[:, None]
+        used = state_i.used + a_open[:, None].astype(jnp.float32) * cls.requests[None, :]
+        used = jnp.where(
+            sel_f,
+            statics.tmpl_daemon[tmpl_idx]
+            + fresh_a[:, None].astype(jnp.float32) * cls.requests[None, :],
+            used,
+        )
+        kmask = jnp.where(sel_o[..., None], merged.mask, state_i.kmask)
+        kmask = jnp.where(sel_f[..., None], tmpl_merged.mask[tmpl_idx], kmask)
+        kdef = jnp.where(sel_o, merged.defined, state_i.kdef)
+        kdef = jnp.where(sel_f, tmpl_merged.defined[tmpl_idx], kdef)
+        kneg = jnp.where(sel_o, merged.negative, state_i.kneg)
+        kneg = jnp.where(sel_f, tmpl_merged.negative[tmpl_idx], kneg)
+        kgt = jnp.where(sel_o, merged.gt, state_i.kgt)
+        kgt = jnp.where(sel_f, tmpl_merged.gt[tmpl_idx], kgt)
+        klt = jnp.where(sel_o, merged.lt, state_i.klt)
+        klt = jnp.where(sel_f, tmpl_merged.lt[tmpl_idx], klt)
+        sel_n = sel_o | sel_f
+        viable = jnp.where(sel_o, viable_open, state_i.viable)
+        viable = jnp.where(sel_f, viable_fresh, viable)
+        if ft.host_ports:
+            ports_pl = jnp.where(
+                sel_o, state_i.ports | cls.ports[None, :], state_i.ports
+            )
+            ports_pl = jnp.where(
+                sel_f, (fresh_a > 0)[:, None] & cls.ports[None, :], ports_pl
+            )
+        else:
+            ports_pl = state_i.ports
+        pod_count = state_i.pod_count + a_open
+        pod_count = jnp.where(is_fresh, fresh_a, pod_count)
+        tmpl_id = jnp.where(is_fresh, tmpl_idx, state_i.tmpl_id)
+        if order is None:
+            order = state_i.order
+            if list_order is not None:
+                touched = list_order & sel_n[:, 0]
+                at = _next_event(order) + jnp.cumsum(touched) - 1
+                order = jnp.where(touched, jnp.where(pod_count > 1, -at, at), order)
+        state_o = NodeState(
+            used, kmask, kdef, kneg, kgt, klt,
+            jnp.where(sel_n, zone, state_i.zone), jnp.where(sel_n, ct, state_i.ct),
+            viable, ports_pl, pod_count, tmpl_id, state_i.open_ | is_fresh, n_next,
+            order,
+        )
+        return state_o, ex_o
+
     def run_phase(state, ex, remaining, quota, restrict, targets_ex=None,
                   targets_new=None, single_node=False, max_new_nodes=None,
                   ex_restrict=None, open_restrict=None):
@@ -1257,20 +1434,9 @@ def _class_step(
                     state_i, cls, statics, q_new, restrict,
                     host_cap_new, fresh_host_cap, rem_i, extra_elig=extra_new,
                     max_new_nodes=max_new_nodes, ft=ft, pref=pref_new,
-                    open_restrict=open_restrict,
+                    open_restrict=open_restrict, list_order=list_order,
                 )
             return state_o, ex_o, a_new, a_ex, placed_ex + placed_new, rem_o
-
-        def skip(operand):
-            state_i, ex_i, rem_i = operand
-            return (
-                state_i,
-                ex_i,
-                jnp.zeros_like(state_i.pod_count),
-                jnp.zeros_like(ex_i.pod_count),
-                jnp.int32(0),
-                rem_i,
-            )
 
         return jax.lax.cond(_any_lane(quota > 0, statics), do, skip, (state, ex, remaining))
 
@@ -1465,81 +1631,17 @@ def _class_step(
             )
 
             # ---- one-shot commit (each node took pods in at most one zone) --
-            took_e = a_ex > 0
-            sel_e = took_e[:, None]
-            zhot_e = (jnp.arange(n_zones)[None, :] == zex[:, None]) & sel_e
-            mex = ex_prep.merged
-            ex_o = ExistingState(
-                used=ex_i.used + a_ex[:, None].astype(jnp.float32) * cls.requests[None, :],
-                kmask=jnp.where(sel_e[..., None], mex.mask, ex_i.kmask),
-                kdef=jnp.where(sel_e, mex.defined, ex_i.kdef),
-                kneg=jnp.where(sel_e, mex.negative, ex_i.kneg),
-                kgt=jnp.where(sel_e, mex.gt, ex_i.kgt),
-                klt=jnp.where(sel_e, mex.lt, ex_i.klt),
-                zone=jnp.where(sel_e, zhot_e, ex_i.zone),
-                ct=jnp.where(sel_e, ex_prep.ct_ok, ex_i.ct),
-                ports=jnp.where(sel_e, ex_i.ports | cls.ports[None, :], ex_i.ports)
-                if ft.host_ports else ex_i.ports,
-                vol_used=jnp.where(
-                    sel_e,
-                    ex_i.vol_used + ex_prep.vol_add
-                    + a_ex[:, None] * ex_prep.vol_per_pod[None, :],
-                    ex_i.vol_used,
-                )
-                if ft.volume_limits else ex_i.vol_used,
-                pod_count=ex_i.pod_count + a_ex,
-                open_=ex_i.open_,
-            )
-            took_o = a_open > 0
-            is_fresh = fresh_t >= 0
-            tmpl_idx = jnp.maximum(fresh_t, 0)
-            sel_o = took_o[:, None]
-            sel_f = is_fresh[:, None]
-            zhot_o = (jnp.arange(n_zones)[None, :] == zopen[:, None]) & sel_o
-            zhot_f = (jnp.arange(n_zones)[None, :] == fresh_z[:, None]) & sel_f
-            used = state_i.used + a_open[:, None].astype(jnp.float32) * cls.requests[None, :]
-            used = jnp.where(
-                sel_f,
-                statics.tmpl_daemon[tmpl_idx]
-                + fresh_a[:, None].astype(jnp.float32) * cls.requests[None, :],
-                used,
-            )
-            kmask = jnp.where(sel_o[..., None], merged.mask, state_i.kmask)
-            kmask = jnp.where(sel_f[..., None], tmpl_merged.mask[tmpl_idx], kmask)
-            kdef = jnp.where(sel_o, merged.defined, state_i.kdef)
-            kdef = jnp.where(sel_f, tmpl_merged.defined[tmpl_idx], kdef)
-            kneg = jnp.where(sel_o, merged.negative, state_i.kneg)
-            kneg = jnp.where(sel_f, tmpl_merged.negative[tmpl_idx], kneg)
-            kgt = jnp.where(sel_o, merged.gt, state_i.kgt)
-            kgt = jnp.where(sel_f, tmpl_merged.gt[tmpl_idx], kgt)
-            klt = jnp.where(sel_o, merged.lt, state_i.klt)
-            klt = jnp.where(sel_f, tmpl_merged.lt[tmpl_idx], klt)
-            zone = jnp.where(sel_o, zhot_o, state_i.zone)
-            zone = jnp.where(sel_f, zhot_f, zone)
-            ct = jnp.where(sel_o, ct_ok, state_i.ct)
-            ct = jnp.where(sel_f, t_ct[tmpl_idx], ct)
+            sel_f = (fresh_t >= 0)[:, None]
+            zhot_e = jnp.arange(n_zones)[None, :] == zex[:, None]
+            zone = jnp.arange(n_zones)[None, :] == jnp.where(fresh_t >= 0, fresh_z, zopen)[:, None]
             v_open = jnp.take_along_axis(
                 viable_nzi, jnp.maximum(zopen, 0)[:, None, None], axis=1
             )[:, 0, :]
-            viable = jnp.where(
-                sel_o, v_open & (cap_ni >= a_open[:, None]), state_i.viable
-            )
-            viable = jnp.where(sel_f, fresh_viable, viable)
-            if ft.host_ports:
-                ports_pl = jnp.where(
-                    sel_o, state_i.ports | cls.ports[None, :], state_i.ports
-                )
-                ports_pl = jnp.where(
-                    sel_f, (fresh_a > 0)[:, None] & cls.ports[None, :], ports_pl
-                )
-            else:
-                ports_pl = state_i.ports
-            pod_count = state_i.pod_count + a_open
-            pod_count = jnp.where(is_fresh, fresh_a, pod_count)
-            tmpl_id = jnp.where(is_fresh, tmpl_idx, state_i.tmpl_id)
-            state_o = NodeState(
-                used, kmask, kdef, kneg, kgt, klt, zone, ct, viable, ports_pl,
-                pod_count, tmpl_id, state_i.open_ | is_fresh, n_next,
+            state_o, ex_o = commit(
+                state_i, ex_i, merged, tmpl_merged, a_ex, zhot_e, ex_prep.ct_ok,
+                a_open, fresh_t, fresh_a, zone,
+                jnp.where(sel_f, t_ct[jnp.maximum(fresh_t, 0)], ct_ok),
+                v_open & (cap_ni >= a_open[:, None]), fresh_viable, n_next,
             )
             return state_o, ex_o, a_open + fresh_a, a_ex, placed, rem_o
 
@@ -1547,20 +1649,213 @@ def _class_step(
             with jax.named_scope("kc.committal"):
                 return block(operand)
 
-        def skip(operand):
-            state_i, ex_i, rem_i = operand
-            return (
-                state_i,
-                ex_i,
-                jnp.zeros_like(state_i.pod_count),
-                jnp.zeros_like(ex_i.pod_count),
-                jnp.int32(0),
-                rem_i,
-            )
-
         return jax.lax.cond(
             _any_lane(jnp.sum(quota_z) > 0, statics), do, skip, (state, ex, remaining)
         )
+
+    def ct_spread_block(operand):
+        """Place the class's ``m`` pods one a turn (kc.phase.ct_spread below),
+        then write every node it touched once (``commit``)."""
+        state_i, ex_i, rem_i = operand
+        i32 = jnp.int32
+        bigi = i32(1 << 30)
+        n_ct = statics.tmpl_ct.shape[-1]
+        zone_ids = jnp.arange(n_zones)
+        ct_ids = jnp.arange(n_ct)
+        skew_z, member_z = statics.grp_skew[g_zs], member_row[g_zs]
+        skew_c, member_c = statics.grp_skew[g_cs], member_row[g_cs]
+        # the minimum of each key is taken over the domains the POD may use
+        # (its own requirement), frozen or not (topologygroup.go:184-200)
+        pod_zone = cls.zone & allowed_zone
+        # ---- open nodes: merge and capacity planes, once ------------------
+        merged = _merge_node_class(state_i, cls, statics)
+        key_ok = _key_compat_node_class(state_i, cls, statics)
+        it_base = state_i.viable & cls.it[None, :] & _it_intersects(merged, statics)
+        cap_ni = _capacity(state_i.used, cls.requests, statics)  # [N, I]
+        elig = state_i.open_ & key_ok & cls.tol[state_i.tmpl_id]
+        if ok_new is not None:
+            elig = elig & ok_new
+        port_cap = UNLIMITED
+        if ft.host_ports:
+            has_ports = jnp.any(cls.ports)
+            elig = elig & ~jnp.any(state_i.ports & cls.ports[None, :], axis=-1)
+            port_cap = jnp.where(has_ports, 1, UNLIMITED)
+        # pods of the class a node takes as each (zone, capacity type): the
+        # roomiest type left to it that offers the pair
+        cap_nzc = _imax(jnp.stack([
+            jnp.stack([
+                jnp.max(jnp.where(it_base & statics.it_avail[None, :, z, c], cap_ni, 0),
+                        axis=-1)
+                for c in range(n_ct)
+            ], axis=-1)
+            for z in range(n_zones)
+        ], axis=1), statics)  # [N, Z, CT]
+        node_cap = jnp.minimum(host_cap_new, port_cap)
+        cap_nzc = jnp.where(
+            elig[:, None, None], jnp.minimum(cap_nzc, node_cap[:, None, None]), 0
+        )
+        # ---- templates: merge, fit and offerings, once --------------------
+        cls_t = mask_ops.ReqTensor(
+            cls.mask[None], cls.defined[None], cls.negative[None],
+            cls.gt[None], cls.lt[None],
+        )
+        tmpl_key_ok = mask_ops.compatible(
+            statics.tmpl, cls_t, statics.is_custom, statics.vocab_ints, v=statics.mask_v,
+        )
+        tmpl_merged = mask_ops.add(
+            statics.tmpl, cls_t, statics.valid, statics.vocab_ints,
+            v=statics.mask_v, key_has_bounds=statics.key_has_bounds,
+        )
+        t_fits = _capacity(statics.tmpl_daemon, cls.requests, statics)  # [T, I]
+        t_base = (
+            statics.tmpl_it & cls.it[None, :] & _it_intersects(tmpl_merged, statics)
+            & (t_fits > 0)
+        )
+        t_open = cls.tol & tmpl_key_ok  # [T]
+        t_zone = statics.tmpl_zone & pod_zone[None, :]  # [T, Z]
+        t_ct = statics.tmpl_ct & cls.ct[None, :]  # [T, CT]
+        ex_cap = ex_prep.cap if ok_ex is None else jnp.where(ok_ex, ex_prep.cap, 0)
+        fresh_cap = jnp.minimum(fresh_host_cap, port_cap)
+
+        def least(domains, counts):
+            """Index of the least counted domain of each row (first on ties)."""
+            return jnp.argmin(jnp.where(domains, counts, bigi), axis=-1)
+
+        def pick(zdom, cdom, counts_z, counts_c):
+            """Per row: whether both keys admit a domain, and the pair taken;
+            a class without a zone spread leaves the zone to the node."""
+            ok = jnp.any(zdom, axis=-1) & jnp.any(cdom, axis=-1)
+            return ok, least(zdom, counts_z), least(cdom, counts_c)
+
+        def narrow(zrow, z):
+            """A node's zone mask once it takes a pod of the class."""
+            return jnp.where(has_zs, zone_ids == z, zrow & pod_zone)
+
+        def body(carry):
+            (k, _, a_ex, zme, cme, a_new, zm, cm, cap_took, fresh_t, fresh_viable,
+             n_next, rem, counts_z, counts_c, order) = carry
+            min_z = jnp.min(jnp.where(cls.zone, counts_z, bigi))
+            min_c = jnp.min(jnp.where(cls.ct, counts_c, bigi))
+            adm_z = jnp.where(
+                has_zs, counts_z + member_z.astype(i32) - min_z <= skew_z, True
+            )
+            adm_c = counts_c + member_c.astype(i32) - min_c <= skew_c
+            # existing nodes, in index order
+            ok_e, z_e, c_e = pick(
+                zme & pod_zone[None, :] & adm_z[None, :], cme & cls.ct[None, :] & adm_c[None, :],
+                counts_z, counts_c,
+            )
+            ok_e = ok_e & (ex_cap > a_ex)
+            e_star = jnp.argmax(ok_e)
+            found_e = ok_e[e_star]
+            # open nodes; a node this step already took a pod of the class on
+            # keeps the pair and the room that pod left it
+            ok_n, z_n, c_n = pick(
+                zm & pod_zone[None, :] & adm_z[None, :], cm & cls.ct[None, :] & adm_c[None, :],
+                counts_z, counts_c,
+            )
+            at_c = jnp.take_along_axis(cap_nzc, c_n[:, None, None], axis=2)[:, :, 0]  # [N, Z]
+            cap_pair = jnp.where(
+                has_zs,
+                jnp.take_along_axis(at_c, z_n[:, None], axis=1)[:, 0],
+                jnp.max(jnp.where(zm & pod_zone[None, :], at_c, 0), axis=-1),
+            )
+            cap_sel = jnp.where(a_new > 0, cap_took, cap_pair)
+            ok_n = ok_n & (elig | (fresh_t >= 0)) & (cap_sel > a_new)
+            # the first of upstream's list that accepts: fewest pods, then
+            # the list's own order among equals (_fill_in_list_order)
+            imax = jnp.iinfo(i32).max
+            count = jnp.where(ok_n, state_i.pod_count + a_new, imax)
+            n_star = jnp.argmin(jnp.where(count == jnp.min(count), order, imax))
+            found_n = ok_n[n_star]
+            # a fresh node: the first template whose pair offers a type it fits
+            within = jnp.all(
+                statics.it_capacity[None, :, :] <= rem[:, None, :] + 1e-4, axis=-1
+            )  # [T, I]
+            ok_t, z_t, c_t = pick(
+                t_zone & adm_z[None, :], t_ct & adm_c[None, :], counts_z, counts_c
+            )
+            zsel_t = jnp.where(has_zs, zone_ids[None, :] == z_t[:, None], t_zone)  # [T, Z]
+            offers = jnp.any(
+                statics.it_avail[None, :, :, :]
+                & zsel_t[:, None, :, None]
+                & (ct_ids[None, :] == c_t[:, None])[:, None, None, :],
+                axis=(2, 3),
+            )  # [T, I]
+            t_ok_i = t_base & within & offers
+            t_cap = _imax(jnp.max(jnp.where(t_ok_i, t_fits, 0), axis=-1), statics)
+            ok_t = ok_t & t_open & (t_cap > 0)
+            t_star = jnp.argmax(ok_t)
+            found_t = ok_t[t_star] & (n_next < n_new_slots)
+
+            take_e = found_e
+            take_n = ~take_e & found_n
+            take_f = ~take_e & ~found_n & found_t
+            placed = take_e | take_n | take_f
+            z_pick = jnp.where(take_e, z_e[e_star], jnp.where(take_n, z_n[n_star], z_t[t_star]))
+            c_pick = jnp.where(take_e, c_e[e_star], jnp.where(take_n, c_n[n_star], c_t[t_star]))
+            c_row = ct_ids == c_pick
+
+            a_ex = a_ex.at[e_star].add(take_e.astype(i32))
+            zme = zme.at[e_star].set(jnp.where(take_e, narrow(zme[e_star], z_pick), zme[e_star]))
+            cme = cme.at[e_star].set(jnp.where(take_e, c_row, cme[e_star]))
+            slot = jnp.where(take_n, n_star, n_next)
+            onto = take_n | take_f
+            z_row = jnp.where(take_f, zsel_t[t_star], narrow(zm[slot], z_pick))
+            zm = zm.at[slot].set(jnp.where(onto, z_row, zm[slot]))
+            cm = cm.at[slot].set(jnp.where(onto, c_row, cm[slot]))
+            room = jnp.where(
+                take_f, jnp.maximum(jnp.minimum(t_cap[t_star], fresh_cap), 1), cap_sel[slot]
+            )
+            cap_took = cap_took.at[slot].set(jnp.where(onto, room, cap_took[slot]))
+            a_new = a_new.at[slot].add(onto.astype(i32))
+            fresh_t = fresh_t.at[slot].set(jnp.where(take_f, t_star, fresh_t[slot]))
+            fresh_viable = fresh_viable.at[slot].set(
+                jnp.where(take_f, t_ok_i[t_star], fresh_viable[slot])
+            )
+            # pessimistic limit tracking (scheduler.go:273-290 subtractMax)
+            max_cap = _imax(jnp.max(
+                jnp.where(t_ok_i[t_star][:, None], statics.it_capacity, 0.0), axis=0
+            ), statics)
+            rem = rem.at[t_star].add(-take_f.astype(jnp.float32) * max_cap)
+            n_next = n_next + take_f.astype(i32)
+            counts_z = counts_z.at[z_pick].add((placed & has_zs & member_z).astype(i32))
+            counts_c = counts_c.at[c_pick].add((placed & member_c).astype(i32))
+            now = _next_event(order)
+            order = order.at[slot].set(
+                jnp.where(take_f, now, jnp.where(take_n, -now, order[slot]))
+            )
+            return (k + placed.astype(i32), ~placed, a_ex, zme, cme, a_new, zm, cm,
+                    cap_took, fresh_t, fresh_viable, n_next, rem, counts_z, counts_c,
+                    order)
+
+        n_it = state_i.viable.shape[-1]
+        carry0 = (
+            i32(0), jnp.array(False),
+            jnp.zeros(n_ex, i32), ex_i.zone, ex_i.ct,
+            jnp.zeros(n_new_slots, i32), state_i.zone, state_i.ct,
+            jnp.zeros(n_new_slots, i32), jnp.full(n_new_slots, -1, i32),
+            jnp.zeros((n_new_slots, n_it), bool),
+            state_i.n_next, rem_i,
+            zone_fwd["zs"] if ft.zone_spread else jnp.zeros(n_zones, jnp.int32), counts_cs,
+            state_i.order,
+        )
+        (placed, _, a_ex, zme, cme, a_new, zm, cm, _, fresh_t, fresh_viable, n_next,
+         rem_o, _, _, order) = jax.lax.while_loop(
+            lambda c: (c[0] < m) & ~c[1], body, carry0
+        )
+
+        # ---- one commit: each touched node's masks, planes and counts -----
+        is_fresh = fresh_t >= 0
+        a_open = jnp.where(is_fresh, 0, a_new)
+        state_o, ex_o = commit(
+            state_i, ex_i, merged, tmpl_merged, a_ex, zme, cme, a_open,
+            fresh_t, jnp.where(is_fresh, a_new, 0), zm, cm,
+            it_base & _offering_ok(zm, cm, statics) & (cap_ni >= a_open[:, None]),
+            fresh_viable & (t_fits[jnp.maximum(fresh_t, 0)] >= a_new[:, None]), n_next,
+            order,
+        )
+        return state_o, ex_o, a_new, a_ex, placed, rem_o
 
     def accumulate(results):
         nonlocal state, ex, remaining, assigned_total, assigned_ex_total, placed_total
@@ -1665,7 +1960,7 @@ def _class_step(
             fill_residual = (m_rem > 0) & jnp.any(
                 allowed_zone & fillable & ~sat & skew_headroom & cap_headroom
             )
-            quotas_gated = jnp.where(has_zs, quotas, 0)
+            quotas_gated = jnp.where(zs_family, quotas, 0)
             results_zs = committal_block(
                 state, ex, remaining, quotas_gated, jnp.int32(UNLIMITED)
             )
@@ -1676,7 +1971,7 @@ def _class_step(
             # capacity counts into every zone of its mask — so a phase can place
             # fewer pods than its quota with no later round to redistribute them
             quota_shortfall = placed_zs < jnp.sum(quotas)
-            spread_suspect = has_zs & member_zs & (fill_residual | quota_shortfall)
+            spread_suspect = zs_family & member_zs & (fill_residual | quota_shortfall)
 
             # non-self-selecting zone spread: the pod never increments its own
             # group's counts, so the skew formula (count + 0 - min <= maxSkew,
@@ -1691,7 +1986,7 @@ def _class_step(
                 min_zs = jnp.min(jnp.where(cls.zone, counts_zs, BIGI))
                 admissible_zs = allowed_zone & fillable & (counts_zs - min_zs <= skew_zs)
                 z_nm = jnp.argmin(jnp.where(admissible_zs, counts_zs, BIGI))
-                q_nm = jnp.where(has_zs & ~member_zs & jnp.any(admissible_zs), m, 0)
+                q_nm = jnp.where(zs_family & ~member_zs & jnp.any(admissible_zs), m, 0)
                 own = state.zone & admissible_zs[None, :]  # [N, Z]
                 z_own = jnp.argmin(jnp.where(own, counts_zs[None, :], BIGI), axis=-1)
                 results_nm = run_phase(
@@ -1701,8 +1996,29 @@ def _class_step(
                 )
                 accumulate(results_nm)
                 spread_suspect = spread_suspect | (
-                    has_zs & ~member_zs & (results_nm[4] < q_nm)
+                    zs_family & ~member_zs & (results_nm[4] < q_nm)
                 )
+
+    # -- capacity-type spread: the class placed pod by pod --------------------
+    # upstream picks each pod's domains one key at a time
+    # (topologygroup.go:155-182 nextDomainTopologySpread): a key admits the
+    # domains whose count, the pod's own included, stays within maxSkew of
+    # the least counted domain the pod may use; the node's candidate domains
+    # meet the admitted ones, the least counted is taken per key, and the node
+    # is committed to that capacity type (and zone, where the class spreads on
+    # zone too) with an offering left for the pair.  Existing nodes first, in
+    # index order, then open nodes in the order of upstream's list
+    # (_fill_in_list_order), then a fresh node from the first template whose
+    # pair has a type the pod fits (scheduler.py _add).
+    # Two capacity types and maxSkew 1 let no run of pods share one choice,
+    # so the loop takes one pod a turn: [N, Z, CT] and [T, I] work a turn,
+    # the merge and offering planes once a step.
+    if ft.ct_spread:
+        with jax.named_scope("kc.phase.ct_spread"):
+            accumulate(jax.lax.cond(
+                _any_lane(has_cs & (m > 0), statics), ct_spread_block, skip,
+                (state, ex, remaining),
+            ))
 
     # -- owned zone anti-affinity: zero-forward-count zones only --------------
     # self-members place one pod per currently-unpoisoned zone, each phase
@@ -1801,7 +2117,10 @@ def _class_step(
 
     # -- unconstrained phase for plain classes --------------------------------
     with jax.named_scope("kc.phase.plain"):
-        any_quota = jnp.where(has_zs | has_zan | has_zaf | has_haf, 0, m)
+        constrained = has_zs | has_zan | has_zaf | has_haf
+        if ft.ct_spread:
+            constrained = constrained | has_cs
+        any_quota = jnp.where(constrained, 0, m)
         accumulate(run_phase(state, ex, remaining, any_quota, allowed_zone))
 
     # -- record (topology.go:120-143): update shared PER-NODE counts ----------
@@ -1812,7 +2131,8 @@ def _class_step(
     # whole record step prunes away with them.
     with jax.named_scope("kc.step.record"):
         if (ft.zone_spread or ft.host_spread or ft.zone_affinity or ft.host_affinity
-                or ft.zone_anti or ft.host_anti or ft.inv_zone_anti or ft.inv_host_anti):
+                or ft.zone_anti or ft.host_anti or ft.inv_zone_anti or ft.inv_host_anti
+                or ft.ct_spread):
             a_ex_f = assigned_ex_total.astype(jnp.int32)
             a_new_f = assigned_total.astype(jnp.int32)
             # preferred-anti owners register no inverse counts (the reference skips
@@ -1979,6 +2299,7 @@ def solve_core(
                 tmpl_id=jnp.zeros(n_slots, dtype=jnp.int32),
                 open_=jnp.zeros(n_slots, dtype=bool),
                 n_next=jnp.int32(0),
+                order=jnp.zeros(n_slots, dtype=jnp.int32),
             )
             if existing_state is None:
                 existing_state = empty_existing_state(n_res, n_keys, width, n_zones, n_ct, n_ports)
@@ -2001,6 +2322,13 @@ def solve_core(
                 fwd_new=jnp.zeros((g1, n_slots), dtype=jnp.int32),
                 inv_new=jnp.zeros((g1, n_slots), dtype=jnp.int32),
             )
+
+    # the capacity-type family's batches take open nodes in upstream's list
+    # order (_phase); a program traced with the family serves any other batch
+    # as one without it would
+    list_order = (
+        jnp.any(class_tensors.groups[:, 6] < g1 - 1) if ft.ct_spread else None
+    )
 
     def step(carry, cls_with_index):
         # the whole class step is masked behind count > 0: a zero-count class
@@ -2027,7 +2355,7 @@ def solve_core(
         def do(carry_in):
             return _class_step(
                 statics, existing_static, n_zones, carry_in, (cls, cls_index),
-                features=ft, pref=pref, topo_base=base,
+                features=ft, pref=pref, topo_base=base, list_order=list_order,
             )
 
         def skip(carry_in):
@@ -2278,6 +2606,7 @@ def gather_repair_window(warm_carry: WarmCarry, idx: jnp.ndarray, n_open_w):
             tmpl_id=state.tmpl_id[idx],
             open_=state.open_[idx],
             n_next=jnp.asarray(n_open_w, dtype=jnp.int32),
+            order=state.order[idx],
         )
         w_topo = TopoCounts(
             fwd_ex=topo.fwd_ex,
@@ -2321,6 +2650,7 @@ def _scatter_repair_window_impl(
             tmpl_id=gs.tmpl_id.at[idx].set(ws.tmpl_id),
             open_=gs.open_.at[idx].set(ws.open_),
             n_next=gs.n_next + (ws.n_next - jnp.asarray(n_open_w, dtype=jnp.int32)),
+            order=gs.order.at[idx].set(ws.order),
         )
         topo = TopoCounts(
             fwd_ex=wt.fwd_ex,
@@ -2600,6 +2930,7 @@ def estimate_slots(snapshot: EncodedSnapshot) -> int:
       next class placed in z.  So what zone committal strands is one partial
       node a zone *per pool*, not per class nor per topology group: ``Z`` for
       each pool that holds a class with a zone phase (spread, affinity, anti).
+      A capacity-type spread strands one a (zone, capacity type): ``Z x CT``.
     - **hostname caps**: a hostname spread (``skew`` a node) or anti-affinity
       (one a node) caps the group's own members (classes that own the group
       and match its selector), so group g needs ``ceil(members / cap)`` nodes
@@ -2662,6 +2993,12 @@ def estimate_slots(snapshot: EncodedSnapshot) -> int:
         total += int(np.ceil(np.bincount(pool, weights=share) - 1e-6).sum())
         zoned = live & (groups[:, [0, 2, 4]] < n_groups).any(axis=1)
         total += snapshot.cls_zone.shape[1] * np.unique(pool[zoned]).size
+        # a capacity-type spread strands a partial node per (zone, capacity
+        # type) of a pool the same way
+        ct_spread = live & (groups[:, 6] < n_groups)
+        total += snapshot.cls_zone.shape[1] * snapshot.cls_ct.shape[1] * np.unique(
+            pool[ct_spread]
+        ).size
 
         need = np.zeros(n_groups + 1)
         capped = np.zeros((n_classes, n_groups + 1), dtype=bool)

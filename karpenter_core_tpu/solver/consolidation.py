@@ -30,7 +30,7 @@ from karpenter_core_tpu.controllers.deprovisioning import (
 )
 from karpenter_core_tpu import tracing
 from karpenter_core_tpu.metrics import REGISTRY
-from karpenter_core_tpu.models.snapshot import KernelUnsupported
+from karpenter_core_tpu.models.snapshot import KernelUnsupported, refuse_ct_spread
 from karpenter_core_tpu.ops import consolidate as consolidate_ops
 from karpenter_core_tpu.ops import solve as solve_ops
 from karpenter_core_tpu.scheduling import Requirement, Requirements
@@ -227,6 +227,7 @@ class TPUConsolidationSearch:
                 # trivially valid (the simulation would open zero new nodes)
                 return Command(Action.DELETE, [c.node for c in candidates])
             snapshot = self.solver.encode(all_pods, state_nodes, bound_pods)
+            refuse_ct_spread(snapshot.classes, "the consolidation sweep")
             with tracing.span(
                 "encode.existing", state_nodes=len(state_nodes),
                 bound_pods=len(bound_pods or ()), classes=len(snapshot.classes),

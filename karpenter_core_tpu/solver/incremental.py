@@ -42,6 +42,7 @@ import numpy as np
 from karpenter_core_tpu import tracing
 from karpenter_core_tpu.metrics import REGISTRY
 from karpenter_core_tpu.models import store as store_mod
+from karpenter_core_tpu.models.snapshot import refuse_ct_spread
 from karpenter_core_tpu.models.store import (
     SnapshotStore,
     VersionedSnapshot,
@@ -565,6 +566,7 @@ class IncrementalSolveSession:
                 )
             else:
                 snapshot = solver.encode(pods_or_classes, state_nodes, bound_pods)
+            refuse_ct_spread(snapshot.classes, "the incremental path")
             versioned = self.store.commit(snapshot, supply=supply)
             prep = solver.prepare_encoded(
                 snapshot, state_nodes, bound_pods,

@@ -255,6 +255,9 @@ class TPUSolveResults:
     # onto zone-less nodes so both engines see one consistent commitment
     existing_committed_zones: Dict[str, str] = field(default_factory=dict)
     n_slots_used: int = 0
+    # new nodes holding a pod of a capacity-type spread class: each committed
+    # to one capacity type by the kernel (ops/solve.py kc.phase.ct_spread)
+    ct_committed_nodes: int = 0
     # policy objective results (ops.objective, set when the policy stage ran):
     # the summed selected-offering price over this solve's open slots, raw and
     # risk-weighted.  None when policy is disabled — the planes never ran.
@@ -1281,6 +1284,7 @@ class TPUSolver:
                 # beside ``n_slots`` on ``prepare``: occupancy = used / allocated
                 slots_used=results.n_slots_used,
                 n_slots=int(outputs.assign.shape[1]),
+                ct_committed_nodes=results.ct_committed_nodes,
             )
             return results
 
@@ -1386,6 +1390,7 @@ class TPUSolver:
                     ex_idx = np.nonzero(on_ex[c])[0]
                     placed_ex[c] = (ex_idx.tolist(), assign_ex[c][ex_idx].tolist())
                 sp.set(pods_on_existing=sum(sum(t) for _, t in placed_ex.values()))
+        ct_nodes: set = set()
         for c, cls in enumerate(snapshot.classes):
             r = root_of[c]
             pods, cursor = snapshot.classes[r].pods, cursors[r]
@@ -1400,6 +1405,8 @@ class TPUSolver:
                 cursor += take
             node_idx = np.nonzero(assign[c] > 0)[0]
             counts = assign[c][node_idx]
+            if cls.ct_spread is not None:
+                ct_nodes.update(node_idx.tolist())
             for n, take in zip(node_idx.tolist(), counts.tolist()):
                 nodes[n].pods.extend(pods[cursor : cursor + take])
                 cursor += take
@@ -1451,6 +1458,7 @@ class TPUSolver:
                         snapshot.zones[z]
                     )
         results.new_nodes = [nodes[n] for n in sorted(nodes)]
+        results.ct_committed_nodes = len(ct_nodes)
         return results
 
     def to_launchable(self, decision: TPUNodeDecision) -> LaunchableNode:

@@ -33,7 +33,8 @@ from karpenter_core_tpu.utils import compilecache
 STEP = {
     "kc.init", "kc.scan", "kc.step.derive", "kc.step.prep_existing", "kc.step.zone_intake",
     "kc.phase.zone_spread", "kc.phase.zone_anti", "kc.phase.zone_affinity",
-    "kc.phase.host_affinity", "kc.phase.plain", "kc.existing", "kc.new", "kc.committal",
+    "kc.phase.host_affinity", "kc.phase.plain", "kc.phase.ct_spread", "kc.existing",
+    "kc.new", "kc.committal",
     "kc.fill", "kc.step.record", "kc.finish",
 }
 # build -> (test_topo_rows seed, the names its lowering must hold); every

@@ -202,8 +202,14 @@ class TestTwoSliceDCN:
         sizes = jnp.arange(1, n_prefixes + 1, dtype=jnp.int32)
         rank = jnp.full(1, 1 << 30, dtype=jnp.int32)
         counts = jnp.zeros((n_classes, 1), dtype=jnp.int32)
+        # every family but the capacity-type one: its pod loop is a
+        # `while` run for a class's pods, which differ by cell (the displaced
+        # pods), so under the grid's vmap its test reduces over the batch — a
+        # collective; a batch without such a class (the studies trace the
+        # snapshot's own features) never carries it
         fn = mesh_ops._crossed_grid_fn(
-            mesh, key_has_bounds, 16, snapshot.scan_passes, avail_idx
+            mesh, key_has_bounds, 16, snapshot.scan_passes, avail_idx,
+            solve_ops.ALL_FEATURES._replace(ct_spread=False),
         )
         return fn, (avail_r, sizes, cls, statics_arrays, ex_state, ex_static,
                     rank, counts), len(pods)

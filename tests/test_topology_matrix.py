@@ -243,14 +243,22 @@ class TestCapacityTypeAndArchSpread:
     route to the host path, and the host must honor them."""
 
     def test_capacity_type_spread_routes_to_host(self):
-        pods = [
+        """A capacity-type SPREAD is the kernel's
+        (``kc.phase.ct_spread``; the in-process incremental session still
+        sends it to the host, tests/test_tpu_provisioning.py); a pod
+        (anti-)affinity term on capacity type still routes to the host."""
+        (cls,) = classify_pods([
             make_pod(
                 labels={"app": "web"},
                 topology_spread=[spread(key=CT)],
             )
-        ]
+        ])
+        assert cls.ct_spread is not None and cls.ct_spread.key == CT
         with pytest.raises(KernelUnsupported):
-            classify_pods(pods)
+            classify_pods([
+                make_pod(labels={"app": "web"}, pod_anti_affinity=[PodAffinityTerm(
+                    topology_key=CT, label_selector=LabelSelector(match_labels={"app": "web"}))])
+            ])
 
     def test_capacity_type_spread_host_balances(self):
         def pods():
